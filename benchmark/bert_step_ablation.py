@@ -59,9 +59,9 @@ def main():
     from mxnet_tpu.parallel import DataParallelTrainer, make_mesh
     from mxnet_tpu.parallel.data_parallel import _make_apply_fn
     from benchmark.bench_util import measure_stabilized
-    from bench import _enable_compile_cache, _loss_tokens
+    from bench import _loss_tokens
 
-    _enable_compile_cache()
+    mx.engine.enable_compile_cache()
     rng = np.random.RandomState(0)
     x_np = rng.randint(1, VOCAB, (BATCH, SEQ)).astype(np.int32)
     y_np = rng.randint(1, VOCAB, (BATCH, SEQ)).astype(np.int32)

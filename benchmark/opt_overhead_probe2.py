@@ -41,9 +41,9 @@ def main():
     from mxnet_tpu.parallel import DataParallelTrainer, make_mesh
     from mxnet_tpu.parallel.data_parallel import _make_apply_fn
     from benchmark.bench_util import measure_stabilized
-    from bench import _enable_compile_cache, _loss_tokens
+    from bench import _loss_tokens
 
-    _enable_compile_cache()
+    mx.engine.enable_compile_cache()
     with mx.cpu():
         net = resnet50_v1()
         net.initialize(ctx=mx.cpu())

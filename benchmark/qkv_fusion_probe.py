@@ -70,13 +70,8 @@ def bench_variant(fused: bool):
 
 
 def main():
-    try:
-        import jax
-        jax.config.update("jax_compilation_cache_dir",
-                          os.path.join(os.path.expanduser("~"), ".cache",
-                                       "mxnet_tpu_bench"))
-    except Exception:
-        pass
+    from mxnet_tpu import engine
+    engine.enable_compile_cache()
     results = {}
     for fused in (True, False):
         tok_s = bench_variant(fused)

@@ -1,0 +1,648 @@
+"""chip_smoke.py: does today's code start on the chip and give right answers?
+
+One process, public API only, full width of the models the repo supports
+(ResNet-50, BERT-base), random weights from a seed, a few steps each:
+
+  1. ResNet-50 training    DataParallelTrainer + DeviceFeed, bf16/f32 master
+  2. BERT-base training    published width, vocab 30522, AdamW
+  3. flash attention       Pallas kernels through Mosaic vs an f32 reference,
+                           and BERT at T=1024, which takes that branch
+  4. the other kernels     fused SGD/Adam, rtc.PallasModule
+  5. imperative gluon      autograd.record / backward / Trainer.step on tpu(0)
+  6. serving               export -> serving.Server -> mixed-size predicts
+  7. four chips            phases 1-2 on a dp=4 mesh (when there are four)
+
+Every phase is fatal: a failed check raises and the process exits non-zero
+without the final result line. Exits non-zero at once when jax's default
+platform is not 'tpu'. Prints one line per phase (what it checked and its
+wall seconds, which are set-up facts, not metrics) and, as its last line of
+standard output, {"ok": true, "device": {...}} with the device as jax
+reports it.
+
+The phase functions take their sizes as arguments so that
+tests/test_smoke_phases.py can drive the same code on the CPU at toy sizes
+with the kernels in interpret mode; this file has no size switch.
+"""
+from __future__ import annotations
+
+import gc
+import json
+import math
+import os
+import sys
+import tempfile
+import time
+
+import numpy as np
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
+
+
+class SmokeFailure(Exception):
+    """A check of the smoke did not hold."""
+
+
+def check(cond, what):
+    if not cond:
+        raise SmokeFailure(what)
+
+
+class Smoke:
+    """Shared state of one smoke run: jax's own compile and persistent-cache
+    event counts (jax.monitoring), and whether kernels are expected to go
+    through Mosaic (true on the chip, false in the CPU toy run)."""
+
+    def __init__(self, mosaic: bool):
+        import jax.monitoring
+        self.mosaic = mosaic
+        self.compiles = 0       # XLA executables built or loaded from cache
+        self.cache_hits = 0
+        self.cache_misses = 0
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _duration(self, name, secs, **kw):
+        if name == COMPILE_EVENT:
+            self.compiles += 1
+
+    def _event(self, name, **kw):
+        if name == CACHE_HIT_EVENT:
+            self.cache_hits += 1
+        elif name == CACHE_MISS_EVENT:
+            self.cache_misses += 1
+
+    def close(self):
+        import jax.monitoring
+        jax.monitoring.unregister_event_duration_listener(self._duration)
+        jax.monitoring.unregister_event_listener(self._event)
+
+    def new_audits(self, before, kind):
+        """HLO-audit fingerprints (engine.hlo_audit, captured with telemetry
+        on) of the given kind that appeared since `before`."""
+        from mxnet_tpu import engine
+        return [fp for region, fp in engine.hlo_audit.fingerprints().items()
+                if region not in before and fp["kind"] == kind]
+
+
+def token_loss(logits, labels):
+    """Mean cross-entropy in f32 (the loss bench.py and the examples use)."""
+    import jax
+    import jax.numpy as jnp
+    logits = logits.astype(jnp.float32)
+    logz = jax.nn.logsumexp(logits, axis=-1)
+    gold = jnp.take_along_axis(logits, labels[..., None].astype(jnp.int32),
+                               axis=-1)[..., 0]
+    return jnp.mean(logz - gold)
+
+
+def _on_mesh(arr, mesh):
+    return set(arr.devices()) <= set(mesh.devices.flat)
+
+
+def _release():
+    """Free a finished trainer's device state. The engine's process-wide
+    executable cache keeps each compiled step alive, and through the step's
+    closure its trainer; this process runs several trainers back to back."""
+    from mxnet_tpu import engine
+    engine.clear_compilation_cache()
+    gc.collect()
+
+
+# ---------------------------------------------------------------------------
+# Phases 1, 2 and 7: a fused trainer takes a few steps
+# ---------------------------------------------------------------------------
+
+def train_steps(smoke, net, sample, batches, n_classes, optimizer,
+                optimizer_params, mesh, first_loss_tol):
+    """Three `trainer.step` calls fed by a DeviceFeed over `batches` (host
+    numpy), then two `run_steps(x, y, 5)` calls on the last batch.
+
+    Checks: parameters and batch live on the mesh's devices and the batch is
+    split over its dp axis; the first loss is ln(n_classes) within
+    `first_loss_tol`; every loss is finite and the repeated-batch losses end
+    lower than they start; only the first call of each entry compiles; the
+    compiled step aliases its donated inputs. Returns the loss trajectory.
+
+    Random logits of standard deviation s give a first loss of about
+    ln(n_classes) + s^2/2, so `first_loss_tol` is the head's init scale, not
+    noise: ResNet-50's default init starts near ln(1000) + 1.9, BERT-base
+    within 0.7 of ln(30522) (both as seen on the v5e)."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import engine
+    from mxnet_tpu.engine import DeviceFeed
+    from mxnet_tpu.parallel import DataParallelTrainer
+
+    # deferred shape inference on the host: the accelerator sees exactly the
+    # fused-step compiles (bench.py, examples/train_imagenet.py)
+    with mx.cpu():
+        net.initialize(ctx=mx.cpu())
+        net(sample)
+    audits_before = set(engine.hlo_audit.fingerprints())
+    trainer = DataParallelTrainer(
+        net, token_loss, optimizer=optimizer,
+        optimizer_params=optimizer_params, mesh=mesh, dtype="bfloat16")
+    ndp = mesh.shape["dp"]
+
+    losses = []
+    feed = DeviceFeed.for_trainer(batches, trainer)
+    try:
+        for i, (x, y) in enumerate(feed):
+            if i == 0:
+                check(_on_mesh(x, mesh) and _on_mesh(y, mesh),
+                      f"batch not on the mesh: {x.devices()}")
+                shards = x.addressable_shards
+                check(len({s.device for s in shards}) == ndp
+                      and all(s.data.shape[0] == x.shape[0] // ndp
+                              for s in shards),
+                      f"batch not split {ndp} ways: "
+                      f"{[(s.device, s.data.shape) for s in shards]}")
+            losses.append(float(trainer.step(x, y)))
+            if i == 0:
+                after_first = smoke.compiles
+        check(smoke.compiles == after_first,
+              f"trainer.step compiled again after its first call "
+              f"({smoke.compiles - after_first} compile(s))")
+    finally:
+        feed.close()
+    check(len(losses) == len(batches), f"feed delivered {len(losses)} batches")
+
+    multi = np.asarray(trainer.run_steps(x, y, 5))
+    after_first = smoke.compiles
+    multi = np.concatenate([multi, np.asarray(trainer.run_steps(x, y, 5))])
+    check(smoke.compiles == after_first,
+          "trainer.run_steps compiled again on its second call")
+    losses += [float(v) for v in multi]
+
+    check(all(math.isfinite(v) for v in losses), f"non-finite loss: {losses}")
+    check(abs(losses[0] - math.log(n_classes)) < first_loss_tol,
+          f"first loss {losses[0]:.3f} is not ln({n_classes}) = "
+          f"{math.log(n_classes):.3f} within {first_loss_tol}")
+    check(multi[-1] < multi[0],
+          f"loss did not fall over 10 repeated-batch steps: {multi}")
+
+    trainer.sync()
+    for p in net.collect_params().values():
+        check(_on_mesh(p.data().handle, mesh),
+              f"parameter {p.name} not on the mesh: "
+              f"{p.data().handle.devices()}")
+    for kind in ("dp_step", "dp_multi"):
+        fps = smoke.new_audits(audits_before, kind)
+        check(len(fps) == 1, f"expected one {kind} HLO audit, got {len(fps)}")
+        check(fps[0]["counts"]["alias_pairs"] > 0,
+              f"{kind}: donated inputs are not aliased: {fps[0]['counts']}")
+    return losses
+
+
+def resnet_batches(n, batch, image, classes, seed=0):
+    rs = np.random.RandomState(seed)
+    return [(rs.uniform(-1, 1, (batch, 3, image, image)).astype(np.float32),
+             rs.randint(0, classes, (batch,)).astype(np.int32))
+            for _ in range(n)]
+
+
+def token_batches(n, batch, seq, vocab, seed=0):
+    rs = np.random.RandomState(seed)
+    return [(rs.randint(0, vocab, (batch, seq)).astype(np.int32),
+             rs.randint(0, vocab, (batch, seq)).astype(np.int32))
+            for _ in range(n)]
+
+
+def phase_resnet(smoke, mesh, net_fn, batch=32, image=224, classes=1000):
+    import mxnet_tpu as mx
+    from mxnet_tpu import nd
+    mx.random.seed(0)
+    losses = train_steps(
+        smoke, net_fn(), nd.zeros((1, 3, image, image), ctx=mx.cpu()),
+        resnet_batches(3, batch, image, classes), classes, "sgd",
+        {"learning_rate": 0.05, "momentum": 0.9, "wd": 1e-4}, mesh,
+        first_loss_tol=2.5)
+    _release()
+    return losses
+
+
+def phase_bert(smoke, mesh, net_fn, batch=16, seq=512, vocab=30522):
+    import mxnet_tpu as mx
+    from mxnet_tpu import nd
+    mx.random.seed(0)
+    losses = train_steps(
+        smoke, net_fn(), nd.zeros((1, seq), ctx=mx.cpu(), dtype="int32"),
+        token_batches(3, batch, seq, vocab), vocab, "adamw",
+        {"learning_rate": 1e-4}, mesh, first_loss_tol=1.0)
+    _release()
+    return losses
+
+
+# ---------------------------------------------------------------------------
+# Phase 3: flash attention through Mosaic
+# ---------------------------------------------------------------------------
+
+def dense_attention_f32(q, k, v, causal):
+    """Materialized softmax(QK^T)V in f32 at the highest matmul precision."""
+    import jax
+    import jax.numpy as jnp
+    qf, kf, vf = (a.astype(jnp.float32) for a in (q, k, v))
+    with jax.default_matmul_precision("highest"):
+        s = jnp.einsum("bhqd,bhkd->bhqk", qf, kf) / math.sqrt(q.shape[-1])
+        if causal:
+            mask = np.tril(np.ones((q.shape[2], k.shape[2]), bool))
+            s = jnp.where(mask[None, None], s, -1e30)
+        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), vf)
+
+
+# bf16 keeps 8 bits of mantissa (relative step 2^-8 = 0.4%); outputs are
+# O(1), so 2e-2 absolute on the forward and 2% of the largest reference
+# gradient leave room for a few roundings and none for a wrong mask or scale
+FLASH_FWD_ATOL = 2e-2
+FLASH_GRAD_RTOL = 2e-2
+
+
+def phase_flash_kernel(smoke, shapes=((2, 16, 2048, 64), (2, 16, 1000, 64))):
+    """flash_attention forward and jax.grad in bf16, causal and not, against
+    the dense f32 reference; the compiled modules hold the Mosaic calls."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.pallas import flash_attention
+
+    worst_f = worst_g = 0.0
+    for shape in shapes:
+        for causal in (False, True):
+            rs = np.random.RandomState(shape[2] + causal)
+            q, k, v = (jnp.asarray(rs.normal(0, 1, shape), jnp.bfloat16)
+                       for _ in range(3))
+            co = jnp.asarray(rs.normal(0, 1, shape), jnp.float32)
+
+            def contracted(fn):
+                return lambda q, k, v: jnp.vdot(
+                    fn(q, k, v, causal).astype(jnp.float32), co)
+
+            flash = lambda q, k, v, causal: flash_attention(  # noqa: E731
+                q, k, v, causal=causal)
+            fwd = jax.jit(lambda q, k, v: flash(q, k, v, causal)) \
+                .lower(q, k, v).compile()
+            bwd = jax.jit(jax.grad(contracted(flash), argnums=(0, 1, 2))) \
+                .lower(q, k, v).compile()
+            if smoke.mosaic:
+                n_f = fwd.as_text().count("tpu_custom_call")
+                n_b = bwd.as_text().count("tpu_custom_call")
+                check(n_f >= 1 and n_b >= 3,
+                      f"flash {shape} causal={causal}: Mosaic custom calls "
+                      f"fwd={n_f} (want >=1) grad={n_b} (want >=3): the "
+                      "kernel took a fallback or interpret mode")
+            out = fwd(q, k, v)
+            ref = dense_attention_f32(q, k, v, causal)
+            err = float(jnp.max(jnp.abs(out.astype(jnp.float32) - ref)))
+            check(out.dtype == jnp.bfloat16 and err < FLASH_FWD_ATOL,
+                  f"flash fwd {shape} causal={causal}: max err {err:.4f}")
+            worst_f = max(worst_f, err)
+            grads = bwd(q, k, v)
+            refs = jax.jit(jax.grad(contracted(dense_attention_f32),
+                                    argnums=(0, 1, 2)))(q, k, v)
+            for name, g, r in zip("qkv", grads, refs):
+                g, r = g.astype(jnp.float32), r.astype(jnp.float32)
+                rel = float(jnp.max(jnp.abs(g - r)) / jnp.max(jnp.abs(r)))
+                check(math.isfinite(rel) and rel < FLASH_GRAD_RTOL,
+                      f"flash d{name} {shape} causal={causal}: "
+                      f"rel err {rel:.4f}")
+                worst_g = max(worst_g, rel)
+    return worst_f, worst_g
+
+
+def phase_bert_flash(smoke, mesh, net, batch=8, seq=1024, vocab=30522,
+                     layers=12):
+    """Two trainer steps of a BERT long enough to take the flash branch by
+    default (models/bert.py: T >= 1024); the step's optimized HLO holds the
+    kernels (forward, dq, dk/dv per layer)."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import engine, nd
+    from mxnet_tpu.parallel import DataParallelTrainer
+
+    mx.random.seed(0)
+    with mx.cpu():
+        net.initialize(ctx=mx.cpu())
+        net(nd.zeros((1, seq), ctx=mx.cpu(), dtype="int32"))
+    audits_before = set(engine.hlo_audit.fingerprints())
+    trainer = DataParallelTrainer(
+        net, token_loss, optimizer="adamw",
+        optimizer_params={"learning_rate": 1e-4}, mesh=mesh, dtype="bfloat16")
+    (x, y), = token_batches(1, batch, seq, vocab)
+    losses = [float(trainer.step(nd.array(x, dtype="int32"),
+                                 nd.array(y, dtype="int32")))
+              for _ in range(2)]
+    check(all(math.isfinite(v) for v in losses)
+          and abs(losses[0] - math.log(vocab)) < 1.0,
+          f"BERT T={seq} losses {losses}, want ln({vocab}) first")
+    fps = smoke.new_audits(audits_before, "dp_step")
+    check(len(fps) == 1, f"expected one dp_step HLO audit, got {len(fps)}")
+    kernels = fps[0]["counts"]["mosaic_kernels"]
+    if smoke.mosaic:
+        check(kernels >= 3 * layers,
+              f"BERT T={seq} step holds {kernels} Mosaic kernels, want "
+              f">= {3 * layers}: attention did not take the flash kernels")
+    del trainer
+    _release()
+    return losses, kernels
+
+
+# ---------------------------------------------------------------------------
+# Phase 4: the other kernels the package ships
+# ---------------------------------------------------------------------------
+
+def phase_small_kernels(smoke, ctx):
+    """fused_sgd_apply / fused_adam_apply on accelerator arrays against
+    their own jnp branch (taken for host arrays), and one rtc.PallasModule
+    axpy launched on `ctx` arrays."""
+    import jax
+    import jax.numpy as jnp
+    import mxnet_tpu as mx
+    from mxnet_tpu import nd
+    from mxnet_tpu.ops.pallas import fused_optimizer as fo
+
+    cpu = jax.devices("cpu")[0]
+    rs = np.random.RandomState(6)
+    shapes = [(7, 5), (128,), (3, 4, 5), (1000, 333)]
+    host = [[rs.normal(size=s).astype(np.float32) for s in shapes]
+            for _ in range(4)]  # weights, grads, first and second moments
+    host[3] = [np.abs(a) for a in host[3]]
+    dev = [[jnp.asarray(a) for a in group] for group in host]
+    ref = [[jax.device_put(a, cpu) for a in group] for group in host]
+    for name, fn, n_args in (
+            ("fused_sgd_apply",
+             lambda w, g, m: fo.fused_sgd_apply(w, g, m, 0.1, 0.9, 0.01), 3),
+            ("fused_adam_apply",
+             lambda w, g, m, v: fo.fused_adam_apply(
+                 w, g, m, v, lr=1e-3, wd=0.01, t=3), 4)):
+        got, want = fn(*dev[:n_args]), fn(*ref[:n_args])
+        for a, b in zip(jax.tree_util.tree_leaves(got),
+                        jax.tree_util.tree_leaves(want)):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5, atol=1e-6, err_msg=name)
+        if smoke.mosaic:
+            text = jax.jit(fn).lower(*dev[:n_args]).compile().as_text()
+            check("tpu_custom_call" in text,
+                  f"{name} did not compile to a Mosaic kernel")
+
+    mod = mx.rtc.PallasModule('''
+def axpy(x_ref, y_ref, o_ref):
+    o_ref[...] = 2.0 * x_ref[...] + y_ref[...]
+''')
+    kern = mod.get_kernel("axpy", out_shapes=[((256, 256), "float32")])
+    x = nd.array(rs.normal(size=(256, 256)).astype(np.float32), ctx=ctx)
+    y = nd.array(rs.normal(size=(256, 256)).astype(np.float32), ctx=ctx)
+    (z,) = kern.launch([x, y])
+    np.testing.assert_allclose(z.asnumpy(), 2.0 * x.asnumpy() + y.asnumpy(),
+                               rtol=1e-6, atol=1e-6)
+    check(z.handle.devices() == {ctx.jax_device},
+          f"rtc output on {z.handle.devices()}, want {ctx.jax_device}")
+    if smoke.mosaic:
+        text = jax.jit(lambda a, b: kern.launch([a, b])[0].handle) \
+            .lower(x.handle, y.handle).compile().as_text()
+        check("tpu_custom_call" in text,
+              "rtc.PallasModule launch resolved to interpret mode")
+
+
+# ---------------------------------------------------------------------------
+# Phase 5: the imperative path
+# ---------------------------------------------------------------------------
+
+def phase_imperative(smoke, ctx, steps=10):
+    """The README quick-start shape on `ctx`: a small hybridized gluon MLP,
+    autograd.record(), loss.backward(), gluon.Trainer.step."""
+    import mxnet_tpu as mx
+    from mxnet_tpu import autograd, engine, gluon, nd
+
+    mx.random.seed(0)
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Dense(256, activation="relu"),
+            gluon.nn.Dense(256, activation="relu"), gluon.nn.Dense(10))
+    net.initialize(ctx=ctx)
+    net.hybridize()
+    loss_fn = gluon.loss.SoftmaxCrossEntropyLoss()
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.1, "momentum": 0.9})
+    rs = np.random.RandomState(0)
+    x = nd.array(rs.uniform(-1, 1, (64, 128)).astype(np.float32), ctx=ctx)
+    y = nd.array(rs.randint(0, 10, (64,)), dtype="int32", ctx=ctx)
+    donated_before = engine.cache_stats()["donated_updates"]
+    losses = []
+    for i in range(steps):
+        with autograd.record():
+            loss = loss_fn(net(x), y).mean()
+        loss.backward()
+        trainer.step(x.shape[0])
+        losses.append(float(loss.asnumpy()))
+        if i == 2:  # warm: forward, pullback and every update kernel built
+            warm = engine.cache_stats()
+            warm_compiles = smoke.compiles
+    stats = engine.cache_stats()
+    for name, arr in [("loss", loss)] + [
+            (p.name, p.data()) for p in net.collect_params().values()]:
+        check(arr.context == ctx
+              and arr.handle.devices() == {ctx.jax_device},
+              f"{name} reports {arr.context} on {arr.handle.devices()}, "
+              f"want {ctx}")
+    check(stats["traces"] == warm["traces"]
+          and stats["compiles"] == warm["compiles"]
+          and smoke.compiles == warm_compiles,
+          f"retraced after warm-up: traces {warm['traces']} -> "
+          f"{stats['traces']}, compiles {warm['compiles']} -> "
+          f"{stats['compiles']}, xla {warm_compiles} -> {smoke.compiles}")
+    check(all(math.isfinite(v) for v in losses) and losses[-1] < losses[0],
+          f"imperative losses {losses}")
+    if engine.donation_enabled():
+        check(stats["donated_updates"] > donated_before,
+              "gluon.Trainer.step donated no buffers")
+    return losses
+
+
+# ---------------------------------------------------------------------------
+# Phase 6: serving
+# ---------------------------------------------------------------------------
+
+def phase_serving(smoke, net, ctx, row_shape=(3, 224, 224), buckets=(1, 8),
+                  requests=16):
+    """Export `net` to the two-file artifact, register it with buckets,
+    answer `requests` predicts of mixed batch sizes, compare with the
+    block's own forward on `ctx`."""
+    import threading
+    import mxnet_tpu as mx
+    from mxnet_tpu import nd, serving
+
+    mx.random.seed(0)
+    with mx.cpu():
+        net.initialize(ctx=mx.cpu())
+        net(nd.zeros((1,) + row_shape, ctx=mx.cpu()))
+    threads_before = threading.active_count()
+    rs = np.random.RandomState(0)
+    sizes = [1 + (3 * i) % max(buckets) for i in range(requests)]
+    inputs = [rs.uniform(-1, 1, (n,) + row_shape).astype(np.float32)
+              for n in sizes]
+    with tempfile.TemporaryDirectory(prefix="mx_smoke_serving_") as tmp:
+        sym_file, param_file = net.export(os.path.join(tmp, "model"))
+        with serving.Server() as srv:
+            srv.register("model", sym_file, param_file,
+                         input_shapes={"data": row_shape}, buckets=buckets)
+            registered = smoke.compiles
+            outs = [srv.predict("model", data=x, timeout=120.0)
+                    for x in inputs]
+            check(smoke.compiles == registered,
+                  f"serving compiled {smoke.compiles - registered} time(s) "
+                  "after registration")
+    check(threading.active_count() <= threads_before,
+          "Server.close() left threads running: "
+          f"{[t.name for t in threading.enumerate()]}")
+    # the block's own forward, same parameters, one fixed batch shape
+    net.collect_params().reset_ctx(ctx)
+    net.hybridize()
+    worst = 0.0
+    for x, out in zip(inputs, outs):
+        out = np.asarray(out[0] if isinstance(out, (list, tuple)) else out)
+        check(out.shape[0] == x.shape[0] and np.isfinite(out).all(),
+              f"serving output {out.shape} for {x.shape[0]} rows")
+        pad = np.zeros((max(buckets),) + row_shape, np.float32)
+        pad[:x.shape[0]] = x
+        ref = net(nd.array(pad, ctx=ctx)).asnumpy()[:x.shape[0]]
+        err = float(np.max(np.abs(out - ref)) / (np.max(np.abs(ref)) + 1e-6))
+        worst = max(worst, err)
+    check(worst < 2e-2, f"serving differs from the block's forward: {worst}")
+    return sizes, worst
+
+
+# ---------------------------------------------------------------------------
+# Phase 7: four chips
+# ---------------------------------------------------------------------------
+
+def same_trajectory(got, want, what):
+    """Two runs of one global batch on different meshes compute the same
+    sums in a different order. In bf16 the difference starts near 0.1% and
+    the repeated-batch steps amplify it (ResNet-50 on the v5e: under 0.6%
+    through step 8, 2% at step 9, 14% at step 13), so the comparison is the
+    first eight losses within 2%."""
+    np.testing.assert_allclose(got[:8], want[:8], rtol=2e-2, err_msg=what)
+
+
+def phase_four_chips(smoke, devices, resnet_fn, bert_fn, bert_one_chip,
+                     resnet_batch=128, image=224, classes=1000,
+                     bert_batch=64, seq=512, vocab=30522):
+    """Phases 1 and 2 on a dp=4 mesh with a 4x batch. The ResNet trajectory
+    is compared with a one-chip run of the same global batch. One chip
+    cannot hold BERT at 4x (the logits alone are batch*seq*vocab*10 bytes),
+    so BERT runs at 4x for the split, memory and loss checks, and once more
+    at the one-chip batch, whose trajectory `bert_one_chip` phase 2 already
+    produced."""
+    from mxnet_tpu.parallel import make_mesh
+
+    mesh4 = make_mesh({"dp": 4}, devices=devices[:4])
+    mesh1 = make_mesh({"dp": 1}, devices=devices[:1])
+    r4 = phase_resnet(smoke, mesh4, resnet_fn, resnet_batch, image, classes)
+    for d in devices[:4]:
+        stats = d.memory_stats()  # None where the backend keeps no account
+        check(stats is None or stats["bytes_in_use"] > 0,
+              f"{d} holds no memory")
+    r1 = phase_resnet(smoke, mesh1, resnet_fn, resnet_batch, image, classes)
+    same_trajectory(r4, r1, "ResNet dp=4 vs one chip, same batch")
+    phase_bert(smoke, mesh4, bert_fn, bert_batch, seq, vocab)
+    b4 = phase_bert(smoke, mesh4, bert_fn, bert_batch // 4, seq, vocab)
+    same_trajectory(b4, bert_one_chip, "BERT dp=4 vs one chip, same batch")
+    return r4, b4
+
+
+# ---------------------------------------------------------------------------
+
+def timed(label, fn):
+    t0 = time.perf_counter()
+    out = fn()
+    print(f"{label} ({time.perf_counter() - t0:.1f} s)", flush=True)
+    return out
+
+
+def main():
+    t_start = time.perf_counter()
+    import jax
+    import jaxlib
+    import mxnet_tpu as mx
+    from mxnet_tpu import engine, telemetry
+    from mxnet_tpu.gluon.model_zoo.vision import resnet50_v1
+    from mxnet_tpu.models import bert_base
+    from mxnet_tpu.parallel import make_mesh
+
+    cache_dir = engine.enable_compile_cache()
+    devices = jax.devices()
+    dev = devices[0]
+    try:
+        import libtpu
+        libtpu_version = libtpu.__version__
+    except ImportError:
+        libtpu_version = "absent"
+    print(f"device: platform={dev.platform} kind={dev.device_kind} "
+          f"count={len(devices)} | jax {jax.__version__} jaxlib "
+          f"{jaxlib.__version__} libtpu {libtpu_version} | "
+          f"compile cache: {cache_dir}", flush=True)
+    if dev.platform != "tpu":
+        sys.exit(f"chip_smoke: jax's default platform is {dev.platform!r} "
+                 f"({dev.device_kind}), not 'tpu'; nothing was run")
+
+    tpu = mx.tpu(0)
+    check(engine.donation_enabled() and tpu.jax_device == dev,
+          f"donation off, or mx.tpu(0) is {tpu.jax_device} and not {dev}")
+    smoke = Smoke(mosaic=True)
+    # telemetry on: the fused trainers then capture their XLA cost and HLO
+    # audit (alias pairs, Mosaic kernels) once per compiled step
+    telemetry.enable()
+    peak = telemetry.peak_flops()
+    check("v5 lite" not in dev.device_kind.lower() or peak == 197e12,
+          f"peak_flops() = {peak} for {dev.device_kind}")
+    mesh = make_mesh({"dp": 1}, devices=devices[:1])
+
+    resnet = timed(
+        "phase 1 resnet50 train bs32 224^2 bf16: ok, 3 fed steps + 2x "
+        "run_steps(5), loss ln(1000) -> falling, placement, donation, "
+        "one compile per entry",
+        lambda: phase_resnet(smoke, mesh, resnet50_v1))
+    bert = timed(
+        "phase 2 bert_base train 12x768x12 T=512 bs16 vocab 30522 adamw: "
+        "ok, same checks, loss ln(30522) first",
+        lambda: phase_bert(smoke, mesh, bert_base))
+    worst = timed(
+        "phase 3a flash attention (2,16,{2048,1000},64) bf16 fwd+grad, "
+        "causal and not, vs f32 dense: ok, Mosaic calls present",
+        lambda: phase_flash_kernel(smoke))
+    _, kernels = timed(
+        "phase 3b bert_base T=1024 bs8: ok, 2 steps through the flash branch",
+        lambda: phase_bert_flash(smoke, mesh, bert_base(max_length=1024)))
+    timed("phase 4 fused_sgd_apply, fused_adam_apply, rtc axpy on tpu(0): "
+          "ok, Mosaic kernels match their jnp branch",
+          lambda: phase_small_kernels(smoke, tpu))
+    timed("phase 5 imperative gluon MLP on tpu(0): ok, 10 steps, no retrace "
+          "after warm-up, donated updates",
+          lambda: phase_imperative(smoke, tpu))
+    sizes, serve_err = timed(
+        "phase 6 serving resnet50_v1 buckets (1, 8): ok, 16 predicts, no "
+        "compile after registration, matches the block's forward",
+        lambda: phase_serving(smoke, resnet50_v1(), tpu))
+    if len(devices) >= 4:
+        timed("phase 7 four chips dp=4: ok, resnet50 bs128 and bert_base "
+              "bs64/bs16 split four ways, trajectories match one chip",
+              lambda: phase_four_chips(smoke, devices, resnet50_v1,
+                                       bert_base, bert))
+    else:
+        print(f"four_chip: not run, {len(devices)} device(s)", flush=True)
+
+    print(f"facts: resnet losses {resnet[0]:.3f} -> {resnet[-1]:.3f}; bert "
+          f"losses {bert[0]:.3f} -> {bert[-1]:.3f}; flash worst fwd abs err "
+          f"{worst[0]:.4f}, worst grad rel err {worst[1]:.4f}; BERT T=1024 "
+          f"step Mosaic kernels {kernels}; serving request sizes {sizes}, "
+          f"worst rel err {serve_err:.2e}", flush=True)
+    print(f"set-up: wall {time.perf_counter() - t_start:.1f} s; xla compile "
+          f"requests {smoke.compiles}; persistent cache hits "
+          f"{smoke.cache_hits}, misses {smoke.cache_misses} "
+          f"(dir {cache_dir})", flush=True)
+    smoke.close()
+    print(json.dumps({"ok": True, "device": {
+        "platform": dev.platform, "kind": dev.device_kind,
+        "count": len(devices)}}), flush=True)
+
+
+if __name__ == "__main__":
+    main()

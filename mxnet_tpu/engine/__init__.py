@@ -9,9 +9,9 @@ the jax_graft analog of that shared engine state:
     input signature, train flag) so N instances of the same model share one
     set of XLA executables instead of compiling privately per instance
     (gluon HybridBlock and symbol Executor both publish into it);
-  - wiring for jax's persistent on-disk compilation cache via the
-    ``MXNET_TPU_COMPILATION_CACHE_DIR`` environment variable, so repeat
-    processes skip recompiles entirely;
+  - ``enable_compile_cache()``: the one place an entry point turns on jax's
+    persistent on-disk compilation cache. ``JAX_COMPILATION_CACHE_DIR``
+    places it from outside; unset, it is ``<checkout>/.jax_cache``;
   - the buffer-donation policy used by the optimizer update kernels
     (weight/optimizer-state aliasing a la arXiv:2004.13336's weight-update
     sharding — donated inputs alias their outputs in-place on TPU);
@@ -30,6 +30,7 @@ from typing import Any, Dict, Optional, Tuple
 from . import hlo_audit
 
 __all__ = ["lookup", "insert", "clear_compilation_cache", "cache_stats",
+           "enable_compile_cache", "persistent_cache_dir",
            "hlo_audit",
            "reset_stats", "donation_enabled", "record_donation",
            "compile_timer", "record_trace", "record_execution",
@@ -84,34 +85,37 @@ _STATS = {
 
 
 # ---------------------------------------------------------------------------
-# Persistent on-disk XLA cache (MXNET_TPU_COMPILATION_CACHE_DIR)
+# Persistent on-disk XLA cache
 # ---------------------------------------------------------------------------
 
-_persistent_dir = None
+# fixed, derived from the package's own location: the directory is part of
+# the cache key, so a path that moves between runs never hits
+_DEFAULT_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
-def _init_persistent_cache():
-    """Point jax's persistent compilation cache at the user-chosen directory.
-    Safe to call before any backend initializes (pure config updates)."""
-    global _persistent_dir
-    d = os.environ.get("MXNET_TPU_COMPILATION_CACHE_DIR")
-    if not d or _persistent_dir == d:
-        return
-    try:
-        import jax
-        os.makedirs(d, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", d)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
-        _persistent_dir = d
-    except Exception:
-        pass
+def enable_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache for this process and
+    return the directory in use. Entry points (bench.py, chip_smoke.py,
+    the benchmark/ scripts) call this once, before their first compile.
 
-
-_init_persistent_cache()
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax has already read it and
+    the directory is NOT touched here; otherwise the cache goes to
+    ``<checkout>/.jax_cache``."""
+    import jax
+    if "JAX_COMPILATION_CACHE_DIR" not in os.environ:
+        jax.config.update("jax_compilation_cache_dir", _DEFAULT_CACHE_DIR)
+    # the default 1 s floor would skip the small serving/kernel artifacts
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.5)
+    return jax.config.jax_compilation_cache_dir
 
 
 def persistent_cache_dir() -> Optional[str]:
-    return _persistent_dir
+    """The directory jax's persistent compilation cache writes to, or None
+    when it is off."""
+    import jax
+    return jax.config.jax_compilation_cache_dir
 
 
 # ---------------------------------------------------------------------------
@@ -193,7 +197,7 @@ def cache_stats() -> Dict[str, Any]:
         st = dict(_STATS)
         st["artifacts"] = len(_CACHE)
         st["pinned"] = len(_PINS)
-        st["persistent_cache_dir"] = _persistent_dir
+        st["persistent_cache_dir"] = persistent_cache_dir()
         return st
 
 

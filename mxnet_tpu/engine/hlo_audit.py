@@ -21,8 +21,9 @@ lower+compile already captured for the roofline ledger), so every fused DP
 step, 1F1B pipeline tick, and serving artifact gets a **hazard
 fingerprint**: counts per hazard + the collective mix, persisted as JSON
 next to the persistent compilation cache (``MXNET_TPU_HLO_AUDIT_DIR``,
-default ``$MXNET_TPU_COMPILATION_CACHE_DIR/hlo_audit``) and diffed by the
-``tools/hlo_audit_gate.py`` CI gate — a refactor that silently regresses
+default ``hlo_audit/`` under ``jax.config.jax_compilation_cache_dir``) and
+diffed by the ``tools/hlo_audit_gate.py`` CI gate — a refactor that silently
+regresses
 fusion/overlap/donation fails tier-1 instead of a bench round three PRs
 later. Telemetry: ``mx_hlo_hazards_total{kind,region}`` (kind = hazard
 vocabulary above) on /statusz and Prometheus.
@@ -59,6 +60,9 @@ _COLL_RE = re.compile(
     r"\b(all-reduce|all-gather|reduce-scatter|all-to-all|"
     r"collective-permute)(-start|-done)?\(")
 _ALIAS_RE = re.compile(r"\b(?:may|must)-alias\b")
+# Pallas kernels compiled by Mosaic: the one observable trace, in the
+# optimized module, that a kernel did NOT take its jnp/lax.scan fallback
+_MOSAIC_RE = re.compile(r"custom_call_target=\"tpu_custom_call\"")
 _DONATED_RE = re.compile(r"\bdonated\b")
 
 _LOCK = threading.Lock()
@@ -72,7 +76,8 @@ def audit_dir() -> Optional[str]:
     d = os.environ.get("MXNET_TPU_HLO_AUDIT_DIR")
     if d:
         return d
-    cache = os.environ.get("MXNET_TPU_COMPILATION_CACHE_DIR")
+    import jax
+    cache = jax.config.jax_compilation_cache_dir
     if cache:
         return os.path.join(cache, "hlo_audit")
     return None
@@ -124,6 +129,7 @@ def audit_text(hlo_text: str, *, kind: str = "artifact",
             "collectives_async": async_,
             "alias_pairs": alias,
             "donated_params": donated,
+            "mosaic_kernels": len(_MOSAIC_RE.findall(hlo_text)),
         },
         "collectives": mix,
         "hazards": hazards,

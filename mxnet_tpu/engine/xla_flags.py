@@ -1,25 +1,28 @@
-"""XLA flag helper for comm/compute overlap (async collectives + the
-latency-hiding scheduler).
+"""TPU compiler flag helper for comm/compute overlap (async collectives +
+the latency-hiding scheduler).
 
 The overlapped step (parallel/overlap.py) arranges the HLO so each fusion
 bucket's collective is issuable while later backward segments still
 compute; whether the DMA actually hides under the dots is the compiler
-scheduler's call. On TPU/GPU that scheduler sits behind XLA flags which
-are read ONCE, when the backend initializes — setting them after the first
-jax call is a silent no-op. ``ensure_overlap_flags()`` appends the missing
-flags to ``XLA_FLAGS`` when called early enough and warns (once per
+scheduler's call. On TPU that scheduler sits behind libtpu flags which are
+read ONCE, when the backend initializes — setting them after the first jax
+call is a silent no-op. ``ensure_overlap_flags()`` appends the missing
+flags to ``LIBTPU_INIT_ARGS`` when called early enough and warns (once per
 process) when it is already too late; `DataParallelTrainer(
 overlap_grads=True)` calls it at construction.
 
+The flags go to ``LIBTPU_INIT_ARGS`` and never to ``XLA_FLAGS``: jaxlib's
+own ``XLA_FLAGS`` parser does not know the ``--xla_tpu_*`` spellings and
+aborts the process on them (``parse_flags_from_env.cc: Unknown flag``),
+while libtpu parses ``LIBTPU_INIT_ARGS`` itself and nothing reads that
+variable on a CPU-only process.
+
 Env knobs:
-  - ``XLA_FLAGS``: flags already present (by flag name) are never
+  - ``LIBTPU_INIT_ARGS``: flags already present (by flag name) are never
     overridden — operator settings win;
   - ``MXNET_TPU_OVERLAP_XLA_FLAGS``: 'off' disables the helper entirely;
-    otherwise a space-separated flag list REPLACING the built-in set
-    (and bypassing the platform filter — you own the spelling);
-  - ``JAX_PLATFORMS``/``JAX_PLATFORM_NAME``: consulted to decide whether
-    the --xla_tpu_* spellings are safe — XLA aborts on unknown flags, and
-    only libtpu-linked builds parse them.
+    otherwise a space-separated flag list REPLACING the built-in set (you
+    own the spelling).
 """
 from __future__ import annotations
 
@@ -29,85 +32,55 @@ from typing import Tuple
 
 from ..base import env
 
-__all__ = ["OVERLAP_XLA_FLAGS", "OVERLAP_XLA_FLAGS_TPU",
-           "OVERLAP_XLA_FLAGS_GPU", "tpu_expected", "overlap_flags",
-           "backend_initialized", "ensure_overlap_flags"]
+__all__ = ["OVERLAP_XLA_FLAGS", "overlap_flags", "backend_initialized",
+           "ensure_overlap_flags"]
 
 # Async collectives give each DMA its own start/done pair instead of one
 # blocking instruction; the latency-hiding scheduler then moves unrelated
-# compute between start and done. The TPU spellings cover all-reduce /
-# reduce-scatter fusion plus the gather-back; the GPU spelling enables the
-# LHS wholesale. XLA ABORTS the process on unknown flags in XLA_FLAGS, and
-# the TPU spellings only exist in libtpu-linked builds — overlap_flags()
-# therefore drops the TPU group unless a TPU backend is in play.
-OVERLAP_XLA_FLAGS_TPU: Tuple[str, ...] = (
+# compute between start and done. The spellings cover all-reduce /
+# reduce-scatter fusion plus the gather-back.
+OVERLAP_XLA_FLAGS: Tuple[str, ...] = (
     "--xla_tpu_enable_async_collective_fusion=true",
     "--xla_tpu_enable_async_collective_fusion_fuse_all_gather=true",
     "--xla_tpu_enable_async_collective_fusion_multiple_steps=true",
     "--xla_tpu_overlap_compute_collective_tc=true",
     "--xla_enable_async_all_gather=true",
 )
-OVERLAP_XLA_FLAGS_GPU: Tuple[str, ...] = (
-    "--xla_gpu_enable_latency_hiding_scheduler=true",
-)
-OVERLAP_XLA_FLAGS: Tuple[str, ...] = (OVERLAP_XLA_FLAGS_TPU
-                                      + OVERLAP_XLA_FLAGS_GPU)
 
 env.declare("MXNET_TPU_OVERLAP_XLA_FLAGS", "", str,
             "Override for ensure_overlap_flags: 'off' disables the helper, "
-            "any other non-empty value is a space-separated XLA flag list "
-            "used instead of the built-in async-collective set")
+            "any other non-empty value is a space-separated libtpu flag "
+            "list used instead of the built-in async-collective set")
 
 _WARNED = [False]
 
 
-def tpu_expected() -> bool:
-    """Whether this process will (or could) bring up a TPU backend — the
-    only builds whose flag parser knows the --xla_tpu_* spellings."""
-    plats = (os.environ.get("JAX_PLATFORMS")
-             or os.environ.get("JAX_PLATFORM_NAME") or "").lower()
-    if "tpu" in plats:
-        return True
-    if plats:  # an explicit non-TPU platform list pins the backend
-        return False
-    try:
-        import libtpu  # noqa: F401
-        return True
-    except ImportError:
-        return False
-
-
 def overlap_flags() -> Tuple[str, ...]:
-    """The flag set ensure_overlap_flags applies, after the env override
-    and the platform filter (TPU spellings abort non-TPU flag parsers)."""
+    """The flag set ensure_overlap_flags applies, after the env override."""
     override = str(env.get("MXNET_TPU_OVERLAP_XLA_FLAGS")).strip()
     if override.lower() == "off":
         return ()
     if override:
         return tuple(override.split())
-    if tpu_expected():
-        return OVERLAP_XLA_FLAGS
-    return OVERLAP_XLA_FLAGS_GPU
+    return OVERLAP_XLA_FLAGS
 
 
 def backend_initialized() -> bool:
-    """Whether jax already initialized a backend (XLA_FLAGS frozen)."""
-    try:
-        from jax._src import xla_bridge as _xb
-    except ImportError:  # pragma: no cover - jax always present here
-        return False
+    """Whether jax already initialized a backend (libtpu flags frozen)."""
+    from jax._src import xla_bridge as _xb
     return bool(getattr(_xb, "_backends", None))
 
 
 def ensure_overlap_flags(warn: bool = True) -> bool:
-    """Append the missing overlap flags to ``XLA_FLAGS`` if the backend has
-    not initialized yet. Returns True when every flag is (now) in effect;
-    False when the helper was disabled or came too late — in the late case
-    a UserWarning fires once per process (suppress with warn=False)."""
+    """Append the missing overlap flags to ``LIBTPU_INIT_ARGS`` if the
+    backend has not initialized yet. Returns True when every flag is (now)
+    in effect; False when the helper was disabled or came too late — in the
+    late case a UserWarning fires once per process (suppress with
+    warn=False)."""
     flags = overlap_flags()
     if not flags:
         return False
-    have = os.environ.get("XLA_FLAGS", "")
+    have = os.environ.get("LIBTPU_INIT_ARGS", "")
     present = {f.split("=", 1)[0] for f in have.split()}
     missing = [f for f in flags if f.split("=", 1)[0] not in present]
     if not missing:
@@ -119,10 +92,11 @@ def ensure_overlap_flags(warn: bool = True) -> bool:
                 "ensure_overlap_flags: the XLA backend is already "
                 "initialized, so the async-collective / latency-hiding "
                 "scheduler flags cannot take effect this process. Set "
-                "XLA_FLAGS before launch or call ensure_overlap_flags() "
-                "before the first jax operation (docs/data_parallel.md, "
-                "'Overlapping gradient communication').",
+                "LIBTPU_INIT_ARGS before launch or call "
+                "ensure_overlap_flags() before the first jax operation "
+                "(docs/data_parallel.md, 'Overlapping gradient "
+                "communication').",
                 UserWarning, stacklevel=2)
         return False
-    os.environ["XLA_FLAGS"] = (have + " " + " ".join(missing)).strip()
+    os.environ["LIBTPU_INIT_ARGS"] = (have + " " + " ".join(missing)).strip()
     return True

@@ -11,6 +11,8 @@ from __future__ import annotations
 import time
 from typing import Dict, List, Optional
 
+import jax
+
 from ..base import MXNetError
 from .. import engine as _engine
 from ..engine import async_feed as _feed
@@ -125,6 +127,11 @@ class Trainer:
                 h = p.data()._data
                 break
         if h is not None:
+            if self.donation_active:
+                # the NEXT step's update kernel donates the weight buffer,
+                # and the window would then block on a deleted array: admit
+                # a one-element slice, ready exactly when the weight is
+                h = jax.lax.slice(h, (0,) * h.ndim, (1,) * h.ndim)
             self._window.admit(h)
         if t0 is not None:
             _profiler._record("trainer.step", "trainer", t0,

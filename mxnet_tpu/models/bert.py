@@ -98,12 +98,12 @@ class SelfAttention(HybridBlock):
                     axes=(0, 2, 1, 3))
                 for proj in (self.q_proj, self.k_proj, self.v_proj))
         # Length-adaptive: at short T the O(T^2) scores tensor is cheap and
-        # XLA fuses the plain path onto the MXU far better than the tiled
-        # flash kernel (measured on v5e, BERT-base T=512: 151k tok/s plain
-        # vs 106k blockwise — 46% vs 32% MFU); flash attention's tiling
-        # only pays once activation memory actually matters. Override the
-        # crossover with MXNET_FLASH_ATTENTION_MIN_SEQ. Symbolic export
-        # (no concrete shape) always lowers the plain path.
+        # the plain path is one XLA fusion; flash attention's tiling only
+        # pays once activation memory actually matters. The T=1024
+        # crossover predates the current installation and has not been
+        # re-measured on it (PERF.md, open questions); override it with
+        # MXNET_FLASH_ATTENTION_MIN_SEQ. Symbolic export (no concrete
+        # shape) always lowers the plain path.
         import os as _os
         min_t = int(_os.environ.get("MXNET_FLASH_ATTENTION_MIN_SEQ", 1024))
         shape = getattr(x, "shape", None)

@@ -16,11 +16,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-# jax.lax.axis_size compat (absent pre-0.4.38): psum of a static 1
-# constant-folds to the axis size as a Python int
-_axis_size = getattr(lax, 'axis_size', None) or \
-    (lambda name: lax.psum(1, name))
-
 from .registry import register
 
 _NEG = -1e30  # finite mask: -inf makes exp(-inf - -inf) = nan on fully
@@ -96,7 +91,7 @@ def ring_attention(q, k, v, axis_name: str, causal: bool = False,
                    scale: Optional[float] = None):
     """Ring attention over mesh axis `axis_name` (call inside shard_map).
     q/k/v: local sequence shards (B, H, T_local, D)."""
-    n = _axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     idx = lax.axis_index(axis_name)
     B, H, T, D = q.shape
     scale = scale if scale is not None else (1.0 / (D ** 0.5))

@@ -26,18 +26,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG = -1e30  # finite mask value: -inf breeds nans in exp(-inf - -inf)
 
 
 def pallas_available() -> bool:
-    return _HAS_PALLAS and jax.default_backend() == "tpu"
+    return jax.default_backend() == "tpu"
 
 
 def _on_tpu(x) -> bool:
@@ -46,8 +42,6 @@ def _on_tpu(x) -> bool:
     check the concrete device when the array has one; for tracers consult
     jax_default_device (set to CPU by the test conftest) before falling back
     to the default backend."""
-    if not _HAS_PALLAS:
-        return False
     try:
         devs = x.devices()
         return all(d.platform == "tpu" for d in devs)
@@ -73,7 +67,7 @@ def _ceil_to(x: int, m: int) -> int:
 
 
 def _params(interpret):
-    if interpret or not _HAS_PALLAS:
+    if interpret:
         return {}
     return {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=("parallel", "parallel", "arbitrary"))}
@@ -391,7 +385,7 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     on_tpu = _on_tpu(q)
-    if not (on_tpu or (_HAS_PALLAS and _use_interpret())):
+    if not (on_tpu or _use_interpret()):
         # The fallback is differentiated by jax AS WRITTEN (no custom_vjp):
         # its gradient contract — matches the dense-softmax VJP at every
         # shape, including T not a multiple of block_size and causal

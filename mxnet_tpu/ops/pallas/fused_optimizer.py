@@ -18,20 +18,14 @@ from typing import List, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _LANES = 128
 _BLOCK_ROWS = 512  # 512*128 f32 = 256 KB per operand block in VMEM
 
 
 def _available(x=None) -> bool:
-    if not _HAS_PALLAS:
-        return False
     if x is not None:
         try:
             return all(d.platform == "tpu" for d in x.devices())

@@ -26,7 +26,7 @@ the (much smaller) activations, Megatron-LM style (arXiv:1909.08053):
 
 Collectives and the replicated-gradient convention
 --------------------------------------------------
-All programs run inside ``zero.shard_map_compat`` (check_rep=False), where
+All programs run inside ``zero.shard_map_compat`` (check_vma=False), where
 a plain ``lax.psum`` transposes to ANOTHER psum — differentiating through
 it would inflate gradients by tp (the exact failure pipeline.py's GPipe
 loss masking documents). Every boundary collective here is therefore an

@@ -18,11 +18,9 @@ _DEFAULT_MESH: Optional[Mesh] = None
 
 
 def axis_size(name: str) -> int:
-    """Static size of a mapped mesh axis, inside shard_map/pmap bodies:
-    ``jax.lax.axis_size`` where it exists, else the constant-folding
-    ``psum(1, name)`` idiom (returns a Python int on both)."""
-    fn = getattr(jax.lax, "axis_size", None)
-    return fn(name) if fn is not None else jax.lax.psum(1, name)
+    """Static size of a mapped mesh axis, inside shard_map/pmap bodies
+    (a Python int)."""
+    return jax.lax.axis_size(name)
 
 
 def require_axis(mesh: Mesh, name: str, role: str = "this trainer") -> int:

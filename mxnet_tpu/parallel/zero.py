@@ -209,15 +209,11 @@ def wd_vector(bucket: BucketSpec, wds) -> _np.ndarray:
 # ---------------------------------------------------------------------------
 
 def shard_map_compat(body, mesh, in_specs, out_specs):
-    """``jax.shard_map`` across jax versions: top-level (check_vma) on new
-    releases, ``jax.experimental.shard_map`` (check_rep) before that."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    from jax.experimental.shard_map import shard_map as sm_exp
-    return sm_exp(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    """``jax.shard_map`` without the varying-manual-axes check (the step
+    bodies mix replicated and per-shard values by construction)."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
 
 def reduce_scatter_bucket(flat, axis_name: str, ndp: int,
                           comm_dtype: Optional[str] = None):

@@ -23,8 +23,9 @@ exported model compile ONCE and every reuse is a visible cache hit in
 ``compilation_stats()``. Each holder pins its entry (``engine.pin``) so a
 fingerprint-scoped invalidation can't evict a live serving executable;
 ``Predictor.reshape`` releases the old shape's pin when it rebinds, and
-``MXNET_TPU_COMPILATION_CACHE_DIR`` persists the XLA executables so a
-restarted serving process warms from disk instead of recompiling.
+jax's persistent cache (``JAX_COMPILATION_CACHE_DIR``) keeps the XLA
+executables so a restarted serving process warms from disk instead of
+recompiling.
 """
 from __future__ import annotations
 
@@ -225,7 +226,13 @@ class Predictor:
         aux_avals = {name: (tuple(v.shape), str(v.dtype))
                      for name, v in self._aux_params.items()}
         old = self._art
-        self._art = acquire_forward(self._sym, arg_avals, aux_avals)
+        # warm with the bound parameters themselves: a jit call keys on
+        # whether each argument is committed to a device, which fresh zeros
+        # are not, so a zeros warmup built an executable predict() never hit
+        params = {**self._arg_params, **self._aux_params}
+        self._art = acquire_forward(
+            self._sym, arg_avals, aux_avals,
+            place=lambda name, z: params.get(name, z))
         if old is not None:
             old.release()
         self._shapes = {k: tuple(int(s) for s in v)

@@ -9,7 +9,7 @@ around fixed-shape XLA artifacts, arXiv:1810.09868):
     symbol+params load once; each configured batch bucket (e.g. 1/8/64)
     eagerly acquires a compiled artifact through the process-wide engine
     cache under pinned ``("predict", graph_fp, config_fingerprint)`` keys,
-    warm-started from ``MXNET_TPU_COMPILATION_CACHE_DIR`` so a restarted
+    warm-started from ``JAX_COMPILATION_CACHE_DIR`` so a restarted
     replica does not recompile.
   - **ContinuousBatcher** (`batcher.py`) — thread-safe request queue with
     continuous batch formation: requests aggregate into the smallest

@@ -7,7 +7,7 @@ compiled inference artifact per batch bucket through
 ``predict.acquire_forward`` — i.e. through the process-wide engine
 compilation cache under ``("predict", graph_fp, config_fingerprint)`` keys.
 Registration therefore IS the warmup: every bucket compiles (or loads from
-``MXNET_TPU_COMPILATION_CACHE_DIR`` — restart != recompile) before the
+``JAX_COMPILATION_CACHE_DIR`` — restart != recompile) before the
 first request arrives, and the steady-state serve path never compiles.
 Entries are pinned for the model's lifetime; ``close()`` releases them.
 
@@ -189,11 +189,15 @@ class RegisteredModel:
         """Eager startup warmup: one acquire (compile or persistent-cache
         load) per bucket, so the first real request hits a ready
         executable."""
-        inputs = set(self.input_names)
+        params = {**self._arg_params, **self._aux_params}
 
         def place(name, z):
-            return self.place_input(name, z) if name in inputs \
-                else self._place_param(z)
+            # warm with the parameters the requests will pass, not zeros
+            # placed like them: a jit call keys on whether each argument is
+            # committed to its device, and a fresh zeros array is not, so a
+            # zeros warmup compiled an executable no request ever used
+            return params[name] if name in params \
+                else self.place_input(name, z)
 
         for b in self.buckets:
             arg_avals, aux_avals = self._avals(b)

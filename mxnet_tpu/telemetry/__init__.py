@@ -460,33 +460,41 @@ _PEAK_TABLE = (
     ("v5 lite", 197e12), ("v5e", 197e12), ("v5p", 459e12),
     ("v4", 275e12), ("v3", 123e12), ("v2", 46e12), ("v6", 918e12),
 )
-# With no override and no recognized accelerator (CPU CI), MFU is reported
-# against this nominal anchor so the gauge exists and A/B deltas are
-# comparable — the absolute value is NOT a hardware utilization claim
-# (docs/observability.md, "MFU methodology").
-_FALLBACK_PEAK = 1e12
+# On a CPU platform (the unit suite) MFU is reported against this nominal
+# anchor so the gauge exists and A/B deltas are comparable — the absolute
+# value is NOT a hardware utilization claim (docs/observability.md, "MFU
+# methodology"). An accelerator whose device_kind is not in the table is an
+# error, never this anchor.
+_CPU_ANCHOR_PEAK = 1e12
 _peak_cache: List[Optional[float]] = [None]
 
 
+def _device_peak(table, cpu_anchor: float, what: str) -> float:
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform == "cpu":
+        return cpu_anchor
+    kind = dev.device_kind.lower()
+    for sub, peak in table:
+        if sub in kind:
+            return peak
+    raise MXNetError(
+        f"no {what} known for device_kind {dev.device_kind!r} "
+        f"(platform {dev.platform}); add it to the table in "
+        "mxnet_tpu/telemetry/__init__.py or set MXNET_TELEMETRY_PEAK_FLOPS "
+        "/ MXNET_TELEMETRY_PEAK_BYTES")
+
+
 def peak_flops() -> float:
-    """Peak FLOP/s the MFU gauge divides by: env override, else a
-    device_kind table, else a documented 1 TF/s CPU anchor."""
+    """Peak FLOP/s the MFU gauge divides by: env override, else the
+    device_kind table; on a CPU platform the documented 1 TF/s anchor."""
     ov = float(env.get("MXNET_TELEMETRY_PEAK_FLOPS"))
     if ov > 0:
         return ov
     with _LOCK:
         if _peak_cache[0] is None:
-            peak = _FALLBACK_PEAK
-            try:
-                import jax
-                kind = jax.devices()[0].device_kind.lower()
-                for sub, p in _PEAK_TABLE:
-                    if sub in kind:
-                        peak = p
-                        break
-            except Exception:
-                pass
-            _peak_cache[0] = peak
+            _peak_cache[0] = _device_peak(_PEAK_TABLE, _CPU_ANCHOR_PEAK,
+                                          "peak FLOP/s")
         return _peak_cache[0]
 
 
@@ -500,30 +508,21 @@ _BW_TABLE = (
 # ridge point stay meaningful for A/B deltas on CI hosts (with the 1 TF/s
 # FLOPs anchor the ridge sits at 20 FLOP/byte; not a hardware claim —
 # docs/observability.md, "Peak overrides")
-_FALLBACK_BYTES_PER_S = 50e9
+_CPU_ANCHOR_BYTES_PER_S = 50e9
 _peak_bw_cache: List[Optional[float]] = [None]
 
 
 def peak_bytes_per_second() -> float:
     """Peak memory bandwidth the per-region roofline ledger divides by:
-    ``MXNET_TELEMETRY_PEAK_BYTES`` override, else a device_kind HBM table,
-    else the documented 50 GB/s CPU anchor."""
+    ``MXNET_TELEMETRY_PEAK_BYTES`` override, else the device_kind HBM
+    table; on a CPU platform the documented 50 GB/s anchor."""
     ov = float(env.get("MXNET_TELEMETRY_PEAK_BYTES"))
     if ov > 0:
         return ov
     with _LOCK:
         if _peak_bw_cache[0] is None:
-            bw = _FALLBACK_BYTES_PER_S
-            try:
-                import jax
-                kind = jax.devices()[0].device_kind.lower()
-                for sub, b in _BW_TABLE:
-                    if sub in kind:
-                        bw = b
-                        break
-            except Exception:
-                pass
-            _peak_bw_cache[0] = bw
+            _peak_bw_cache[0] = _device_peak(
+                _BW_TABLE, _CPU_ANCHOR_BYTES_PER_S, "peak bytes/s")
         return _peak_bw_cache[0]
 
 

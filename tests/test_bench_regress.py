@@ -95,6 +95,13 @@ def test_json_output_schema(tmp_path, capsys):
     assert d["regressions"] and d["regressions"][0]["delta_pct"] == -50.0
 
 
+def test_no_rounds_is_nothing_to_compare(tmp_path, capsys):
+    """The repo root holds no BENCH_r*.json (the old records were withdrawn
+    in PR 21): a plain message and exit 0, not a traceback."""
+    assert br.main(["--dir", str(tmp_path)]) == 0
+    assert "nothing to compare" in capsys.readouterr().out
+
+
 def test_missing_dir_is_usage_error(tmp_path):
     assert br.main(["--dir", str(tmp_path / "nope")]) == 2
 

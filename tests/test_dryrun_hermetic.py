@@ -1,6 +1,6 @@
 """Driver-shaped hermeticity check for __graft_entry__.dryrun_multichip.
 
-Round-1 failure mode (MULTICHIP_r01.json): the dryrun touched the *default*
+An early failure mode: the dryrun touched the *default*
 XLA backend (eager jax.random.key at import, default-context resolution), and
 on a host whose accelerator runtime was broken (libtpu version mismatch) the
 first eager op crashed before the CPU mesh was ever built.

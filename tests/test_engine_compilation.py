@@ -293,22 +293,44 @@ def test_profiler_surfaces_compilation_stats():
     assert "donated_updates" in st and "artifacts" in st
 
 
-def test_persistent_cache_env_wiring():
-    """MXNET_TPU_COMPILATION_CACHE_DIR points jax's persistent cache at the
-    chosen directory (subprocess: config must be applied pre-backend)."""
-    import subprocess
-    import sys
-    import tempfile
-    with tempfile.TemporaryDirectory() as d:
-        code = (
-            "import jax, mxnet_tpu.engine as e; "
-            "assert e.persistent_cache_dir() == "
-            f"{d!r}, e.persistent_cache_dir(); "
-            f"assert jax.config.jax_compilation_cache_dir == {d!r}"
-        )
-        env = dict(__import__('os').environ,
-                   MXNET_TPU_COMPILATION_CACHE_DIR=d,
-                   JAX_PLATFORMS="cpu")
-        r = subprocess.run([sys.executable, "-c", code], env=env,
-                           capture_output=True, text=True)
-        assert r.returncode == 0, r.stderr
+@pytest.mark.parametrize("placed", [True, False])
+def test_compile_cache_placement(placed, tmp_path, monkeypatch):
+    """One function turns the persistent cache on. With
+    JAX_COMPILATION_CACHE_DIR set, the program makes no
+    jax_compilation_cache_dir update at all (jax read the variable itself);
+    unset, the cache is <checkout>/.jax_cache."""
+    import os
+    import jax
+    updates = {}
+    # record, do not apply: config state is process-wide
+    monkeypatch.setattr(jax.config, "update", updates.__setitem__)
+    if placed:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert engine.enable_compile_cache() == engine.persistent_cache_dir() \
+        == jax.config.jax_compilation_cache_dir
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if placed:
+        assert "jax_compilation_cache_dir" not in updates
+    else:
+        assert updates["jax_compilation_cache_dir"] == \
+            os.path.join(repo, ".jax_cache")
+
+
+def test_one_place_sets_the_cache_dir():
+    """No entry point, benchmark script or library module sets a cache
+    directory of its own (bench.py and qkv_fusion_probe.py used to)."""
+    import os
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    offenders = []
+    for root, dirs, files in os.walk(repo):
+        dirs[:] = [d for d in dirs if not d.startswith(".")
+                   and d not in ("tests", "__pycache__", "chiprun_out")]
+        for f in files:
+            if f.endswith(".py"):
+                path = os.path.join(root, f)
+                with open(path) as fh:
+                    if '"jax_compilation_cache_dir",' in fh.read():
+                        offenders.append(os.path.relpath(path, repo))
+    assert offenders == [os.path.join("mxnet_tpu", "engine", "__init__.py")]

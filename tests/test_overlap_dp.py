@@ -360,16 +360,17 @@ def test_zero_buckets_align_to_segments(host_mesh8):
 # XLA flag helper
 # ---------------------------------------------------------------------------
 
-def test_xla_flags_platform_filter(monkeypatch):
-    """XLA aborts the process on unknown XLA_FLAGS, and the --xla_tpu_*
-    spellings only exist in libtpu builds — so the default set shrinks to
-    the generic LHS flag off-TPU (this suite pins JAX_PLATFORMS=cpu)."""
-    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
-    assert xf.overlap_flags() == xf.OVERLAP_XLA_FLAGS_GPU
-    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
-    assert xf.overlap_flags() == xf.OVERLAP_XLA_FLAGS
-    assert set(xf.OVERLAP_XLA_FLAGS) == \
-        set(xf.OVERLAP_XLA_FLAGS_TPU) | set(xf.OVERLAP_XLA_FLAGS_GPU)
+def test_xla_flags_never_touch_XLA_FLAGS(monkeypatch):
+    """jaxlib's XLA_FLAGS parser aborts the process on the --xla_tpu_*
+    spellings (seen on the v5e: 'Unknown flag in XLA_FLAGS'); libtpu reads
+    them from LIBTPU_INIT_ARGS, which nothing parses on a CPU process."""
+    monkeypatch.setattr(xf, "backend_initialized", lambda: False)
+    monkeypatch.setenv("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+    monkeypatch.setenv("LIBTPU_INIT_ARGS", "")
+    assert xf.ensure_overlap_flags() is True
+    assert os.environ["XLA_FLAGS"] == \
+        "--xla_force_host_platform_device_count=8"
+    assert not any("gpu" in f for f in xf.OVERLAP_XLA_FLAGS)
 
 
 def test_xla_flags_env_override(monkeypatch):
@@ -383,25 +384,24 @@ def test_xla_flags_env_override(monkeypatch):
 
 def test_xla_flags_append_before_init(monkeypatch):
     monkeypatch.setattr(xf, "backend_initialized", lambda: False)
-    monkeypatch.setattr(xf, "tpu_expected", lambda: True)
-    monkeypatch.setenv("XLA_FLAGS",
-                       "--xla_force_host_platform_device_count=8 "
-                       "--xla_gpu_enable_latency_hiding_scheduler=false")
+    monkeypatch.setenv("LIBTPU_INIT_ARGS",
+                       "--xla_tpu_overlap_compute_collective_tc=false")
     assert xf.ensure_overlap_flags() is True
-    got = os.environ["XLA_FLAGS"].split()
+    got = os.environ["LIBTPU_INIT_ARGS"].split()
     # operator's value survives; missing flags appended once
-    assert "--xla_gpu_enable_latency_hiding_scheduler=false" in got
-    assert "--xla_gpu_enable_latency_hiding_scheduler=true" not in got
-    for f in xf.OVERLAP_XLA_FLAGS_TPU:
-        assert f in got
-    before = os.environ["XLA_FLAGS"]
+    assert "--xla_tpu_overlap_compute_collective_tc=false" in got
+    assert "--xla_tpu_overlap_compute_collective_tc=true" not in got
+    for f in xf.OVERLAP_XLA_FLAGS:
+        assert f in got or f.startswith(
+            "--xla_tpu_overlap_compute_collective_tc")
+    before = os.environ["LIBTPU_INIT_ARGS"]
     assert xf.ensure_overlap_flags() is True  # idempotent
-    assert os.environ["XLA_FLAGS"] == before
+    assert os.environ["LIBTPU_INIT_ARGS"] == before
 
 
 def test_xla_flags_warns_once_when_late(monkeypatch):
     monkeypatch.setattr(xf, "backend_initialized", lambda: True)
-    monkeypatch.setenv("XLA_FLAGS", "")
+    monkeypatch.setenv("LIBTPU_INIT_ARGS", "")
     monkeypatch.setattr(xf, "_WARNED", [False])
     with pytest.warns(UserWarning, match="already initialized"):
         assert xf.ensure_overlap_flags() is False

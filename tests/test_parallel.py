@@ -8,10 +8,7 @@ import pytest
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.4.38 jax: experimental home, same signature
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 import mxnet_tpu as mx
 from mxnet_tpu import nd, gluon

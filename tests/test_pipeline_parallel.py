@@ -15,10 +15,7 @@ from mxnet_tpu.base import MXNetError
 from mxnet_tpu.models.bert import BertModel
 from mxnet_tpu.parallel import (make_mesh, P, DataParallelTrainer,
                                 PipelineTrainer, pipeline_apply)
-try:
-    from jax import shard_map
-except ImportError:  # pre-0.4.38 jax: experimental home, same signature
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def _devices(n):

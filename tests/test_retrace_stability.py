@@ -117,3 +117,30 @@ def test_fused_trainer_step_never_retraces():
     # per-step host scalars (lr, t, key) must be jit arguments, not
     # trace constants — any count here is a silent perf catastrophe
     assert c.count == 0, f"fused step retraced {c.count} times"
+    # run_steps feeds its second call the key/step counter its first call
+    # returned: they must come back with the type they went in with (found
+    # by chip_smoke.py: a plain device_put carried no mesh, the outputs
+    # did, and the whole n-step scan compiled twice)
+    tr.run_steps(x, y, 3)
+    with _CompileCounter() as c:
+        tr.run_steps(x, y, 3)
+        tr.run_steps(x, y, 3)
+    assert c.count == 0, f"run_steps recompiled {c.count} times"
+
+
+def test_predictor_first_predict_does_not_compile(tmp_path):
+    """The bind-time warmup must build the executable predict() uses: the
+    engine counters cannot see a jit-level recompile, the XLA chokepoint
+    can (found by chip_smoke.py's serving phase)."""
+    from mxnet_tpu.predict import Predictor
+
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Dense(8, activation="relu"), gluon.nn.Dense(3))
+    net.initialize()
+    net(nd.zeros((1, 5)))
+    sym_file, param_file = net.export(str(tmp_path / "m"))
+    p = Predictor(sym_file, param_file, input_shapes={"data": (2, 5)})
+    with _CompileCounter() as c:
+        p.predict(np.ones((2, 5), np.float32))
+    p.close()
+    assert c.count == 0, f"first predict compiled {c.count} time(s)"

@@ -184,6 +184,31 @@ def test_peak_flops_env_override(monkeypatch):
     assert telem.peak_flops() == 123.0
 
 
+def test_unknown_accelerator_kind_is_an_error(monkeypatch):
+    """The 1 TF/s / 50 GB/s anchors are for CPU platforms only: a chip the
+    tables do not know raises instead of quietly reporting MFU against
+    them; a known one (v5e reports 'TPU v5 lite') reads the table."""
+    import jax
+    from mxnet_tpu.base import MXNetError
+
+    class Dev:
+        platform = "tpu"
+        device_kind = "TPU v5 lite"
+
+    monkeypatch.setattr(jax, "devices", lambda *a: [Dev()])
+    monkeypatch.setattr(telem, "_peak_cache", [None])
+    monkeypatch.setattr(telem, "_peak_bw_cache", [None])
+    assert telem.peak_flops() == 197e12
+    assert telem.peak_bytes_per_second() == 819e9
+    Dev.device_kind = "TPU v99"
+    monkeypatch.setattr(telem, "_peak_cache", [None])
+    monkeypatch.setattr(telem, "_peak_bw_cache", [None])
+    with pytest.raises(MXNetError, match="TPU v99"):
+        telem.peak_flops()
+    with pytest.raises(MXNetError, match="TPU v99"):
+        telem.peak_bytes_per_second()
+
+
 # ---------------------------------------------------------------------------
 # acceptance: short Trainer run -> full scrape
 # ---------------------------------------------------------------------------

@@ -129,8 +129,8 @@ def main(argv=None) -> int:
         return 2
     rounds = load_rounds(directory)
     if len(rounds) < 2:
-        print(f"only {len(rounds)} usable bench round(s) under "
-              f"{directory}; nothing to gate")
+        print(f"{len(rounds)} usable bench round(s) under {directory}: "
+              "nothing to compare")
         return 0
     (n_old, old), (n_new, new) = rounds[-2], rounds[-1]
     regressions, improvements, infos = compare(old, new, args.threshold)

@@ -146,7 +146,7 @@ def main(argv=None) -> int:
         d = hlo_audit.audit_dir()
     if not d:
         print("hlo_audit_gate: no audit dir (set MXNET_TPU_HLO_AUDIT_DIR "
-              "or MXNET_TPU_COMPILATION_CACHE_DIR)", file=sys.stderr)
+              "or JAX_COMPILATION_CACHE_DIR)", file=sys.stderr)
         return 2
     fps = load_fingerprints(Path(d))
 
