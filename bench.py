@@ -424,8 +424,8 @@ def bench_tracing(steps, warmup):
     off/on/off with the best disabled run as baseline (same discipline as
     bench_telemetry_overhead); acceptance is <2% armed overhead on both.
     Also reports the ns-scale cost of the DISARMED path: the bare
-    `tracing._ENABLED` flag check call sites pay, and a disarmed span()
-    call (flag check + shared nullcontext return).
+    `tracing._ENABLED` flag check call sites pay, and building a disarmed
+    span() (entered, it is a profiler TraceAnnotation and nothing else).
 
     The serving model is sized to the regime bench_serving measures
     (ResNet/BERT — ms-scale per batch), not a micro-MLP: armed tracing

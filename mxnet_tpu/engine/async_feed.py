@@ -215,26 +215,16 @@ class DispatchWindow:
         """Register one dispatched step; blocks on the oldest in-flight step
         when the window exceeds its depth (never on the current one)."""
         self._pending.append(handles)
-        wait0 = self.wait_seconds
-        retired = 0
-        t_first = 0.0
         while len(self._pending) > max(self.depth, 0):
             old = self._pending.popleft()
-            t0 = time.perf_counter()
-            if retired == 0:
-                t_first = t0
-            self._block(old)
-            self.wait_seconds += time.perf_counter() - t0
+            # the backpressure wait on the oldest step in flight
+            with _tracing.span("mx.window.admit", source=self.name,
+                               inflight=len(self._pending)):
+                t0 = time.perf_counter()
+                self._block(old)
+                self.wait_seconds += time.perf_counter() - t0
             self.retired += 1
-            retired += 1
         self.max_inflight = max(self.max_inflight, len(self._pending))
-        if _tracing._ENABLED and retired:
-            # the backpressure wait, rebuilt from the stamps the window
-            # already took — no clock reads beyond the existing ones
-            _tracing.record_span("mx.window.admit", t_first,
-                                 t_first + (self.wait_seconds - wait0),
-                                 source=self.name, retired=retired,
-                                 inflight=len(self._pending))
         from .. import telemetry as _telem
         if _telem._ENABLED:
             _telem.record_inflight(len(self._pending), source=self.name)
@@ -245,19 +235,16 @@ class DispatchWindow:
 
     def drain(self):
         """Block until every admitted step completed (epoch/eval boundary)."""
-        t_d0 = time.perf_counter() if _tracing._ENABLED else 0.0
-        drained = 0
-        while self._pending:
-            old = self._pending.popleft()
-            t0 = time.perf_counter()
-            self._block(old)
-            self.wait_seconds += time.perf_counter() - t0
-            self.retired += 1
-            drained += 1
-        if _tracing._ENABLED:
-            _tracing.record_span("mx.window.drain", t_d0,
-                                 time.perf_counter(), source=self.name,
-                                 drained=drained)
+        with _tracing.span("mx.window.drain", source=self.name) as sp:
+            drained = 0
+            while self._pending:
+                old = self._pending.popleft()
+                t0 = time.perf_counter()
+                self._block(old)
+                self.wait_seconds += time.perf_counter() - t0
+                self.retired += 1
+                drained += 1
+            sp.set_attr("drained", drained)
         from .. import telemetry as _telem
         if _telem._ENABLED:
             _telem.record_inflight(0, source=self.name)
@@ -449,22 +436,27 @@ class DeviceFeed:
                 while not stop.is_set():
                     if _faults._ACTIVE:
                         _faults.check("feed.produce")
-                    if _tracing._ENABLED:
-                        t0 = time.perf_counter()
-                        item = next(it)
-                        t1 = time.perf_counter()
-                        placed = self._place(item)
-                        t2 = time.perf_counter()
-                        _tracing.record_span("mx.feed.produce", t0, t1,
-                                             parent=root, source=self.name,
-                                             batch=produced)
-                        _tracing.record_span("mx.feed.put", t1, t2,
-                                             parent=root, source=self.name,
-                                             batch=produced)
-                    else:
-                        item = next(it)
-                        placed = self._place(item)
-                    if not _bounded_put(q, placed, stop):
+                    # one record a batch the producer began, always on;
+                    # the phases are this thread's spans (queue_wait is the
+                    # feed's slack: time blocked on a full queue, the
+                    # consumer being slower). A batch that was not handed
+                    # over says why: `error` where the source ended or
+                    # failed, `aborted` where stop cut the wait
+                    with _tracing.phased("batch", "mx.feed.batch",
+                                         prefix="mx.feed.", parent=root,
+                                         source=self.name,
+                                         batch=produced) as rec:
+                        with rec.phase("produce"):
+                            item = next(it)
+                        with rec.phase("put"):
+                            placed = self._place(item)
+                        with rec.phase("queue_wait"):
+                            queued = _bounded_put(q, placed, stop)
+                            if not queued or stop.is_set():
+                                # a put that got in behind the stop is
+                                # drained, not delivered
+                                rec.set_attr("aborted", True)
+                    if not queued:
                         return
                     produced += 1
                 return
@@ -551,18 +543,20 @@ class DeviceFeed:
         try:
             item = self._q.get_nowait()
         except queue.Empty:
-            t0 = time.perf_counter()
-            while True:
-                try:
-                    item = self._q.get(timeout=1.0)
-                    break
-                except queue.Empty:
-                    if self._producer is None or \
-                            not self._producer.is_alive():
-                        raise MXNetError(
-                            "DeviceFeed producer thread died without "
-                            "delivering a batch or an error")
-            self.stall_seconds += time.perf_counter() - t0
+            # the stall: the consumer waits for the producer
+            with _tracing.span("mx.feed.next", source=self.name):
+                t0 = time.perf_counter()
+                while True:
+                    try:
+                        item = self._q.get(timeout=1.0)
+                        break
+                    except queue.Empty:
+                        if self._producer is None or \
+                                not self._producer.is_alive():
+                            raise MXNetError(
+                                "DeviceFeed producer thread died without "
+                                "delivering a batch or an error")
+                self.stall_seconds += time.perf_counter() - t0
         from .. import telemetry as _telem
         if _telem._ENABLED:
             if t0 is not None:

@@ -163,6 +163,7 @@ def _fwd(q, k, v, causal, scale, block_q, block_k, interpret):
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="mx_flash_fwd",
         **_params(interpret),
     )(qp, kp, vp)
     return o[:, :T], lse[:, :T, 0]
@@ -307,6 +308,7 @@ def _bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k, interpret):
         out_shape=jax.ShapeDtypeStruct((BH, Tp, D), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
         interpret=interpret,
+        name="mx_flash_bwd_dq",
         **_params(interpret),
     )(qp, kp, vp, dop, lsep, deltap)
 
@@ -344,6 +346,7 @@ def _bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k, interpret):
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
         interpret=interpret,
+        name="mx_flash_bwd_dkv",
         **_params(interpret),
     )(qp, kp, vp, dop, lsep, deltap)
     return dq[:, :T], dk[:, :Tk], dv[:, :Tk]

@@ -15,7 +15,6 @@ jit so XLA can overlap the gather with the next forward.
 from __future__ import annotations
 
 import functools
-import time
 import warnings
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -1377,154 +1376,192 @@ class DataParallelTrainer:
         resulting lr array is scanned); the fp16 loss scale, however, is
         constant within one call — split into shorter calls if dynamic
         scaling needs to react faster. Returns the per-step loss array."""
-        xr = x._data if isinstance(x, NDArray) else jnp.asarray(x)
-        yr = y._data if isinstance(y, NDArray) else jnp.asarray(y)
-        self.optimizer.rescale_grad = 1.0
-        if stacked and (xr.shape[0] != n or yr.shape[0] != n):
-            raise MXNetError(
-                f"run_steps(stacked=True): leading dim must be n={n}, got "
-                f"{xr.shape[0]}/{yr.shape[0]}")
-        sig = (xr.shape, str(xr.dtype), yr.shape, str(yr.dtype), stacked)
-        fn = self._get_multi(sig, n, stacked)
-        # All per-call scalars are kept device-resident, so a repeat call
-        # makes no host->device transfer at all (explicit or implicit —
-        # the latter is what sanitize mode's transfer guard rejects):
-        # lr/scale are cached by host value, and the RNG key + step counter
-        # ride the donated carry (multi returns their advanced values).
-        lrs = []
-        for i in range(n):
-            self.optimizer.num_update = self._t + 1 + i
-            lrs.append(float(self.optimizer.learning_rate))
-        scale_val = float(self._scaler.loss_scale if self._scaler else 1.0)
-        if self._is_multiprocess():
-            # multi-process SPMD: plain host values (device_put cannot
-            # target non-addressable devices)
-            lr_in = _np.asarray(lrs, _np.float32)
-            scale_in = _np.float32(scale_val)
-            key_in = _np.asarray(_rng.next_key_raw())
-            t_in = _np.float32(self._t + 1)
-        else:
-            # replicated ON THE MESH, like the values multi hands back: a
-            # plain device_put has no mesh in its type, so the second call
-            # (fed the first call's outputs) would retrace and recompile
-            rep = NamedSharding(self.mesh, P())
-            lr_sig = (tuple(lrs),)
-            if getattr(self, "_lr_cache_sig", None) != lr_sig:
-                self._lr_dev = jax.device_put(
-                    _np.asarray(lrs, _np.float32), rep)
-                self._lr_cache_sig = lr_sig
-            if getattr(self, "_scale_cache_val", None) != scale_val:
-                self._scale_dev = jax.device_put(
-                    _np.float32(scale_val), rep)
-                self._scale_cache_val = scale_val
-            ep = _rng._host_state["epoch"]
-            if getattr(self, "_key_dev", None) is None \
-                    or self._key_epoch != ep:
-                self._key_dev = jax.device_put(
-                    _np.asarray(_rng.next_key_raw()), rep)
-                self._key_epoch = ep
-            if getattr(self, "_t_dev_val", None) != self._t:
-                self._t_dev = jax.device_put(_np.float32(self._t + 1), rep)
-                self._t_dev_val = self._t
-            lr_in, scale_in = self._lr_dev, self._scale_dev
-            key_in, t_in = self._key_dev, self._t_dev
-        spec = self.data_spec
-        if stacked:
-            spec = P(None, *self.data_spec)
-        xr = self._put_batch(xr, NamedSharding(self.mesh, P(*spec[:xr.ndim])))
-        yr = self._put_batch(yr, NamedSharding(self.mesh, P(*spec[:yr.ndim])))
-        cost_key = (sig, "multi", n)
-        self._program.capture_cost(
-            cost_key, fn, self._params_raw, self._opt_state,
-            self._comp_resid, key_in, xr, yr, lr_in, t_in, scale_in,
-            kind="dp_multi", overlap_expected=self._overlap)
-        t_sp = time.perf_counter() if _tracing._ENABLED else 0.0
-        with _telem.annotate("mx.dp.run_steps"), _sanitize.guard():
-            (self._params_raw, self._opt_state, self._comp_resid, losses,
-             finite, key_out, t_out) = fn(
-                self._params_raw, self._opt_state, self._comp_resid,
-                key_in, xr, yr, lr_in, t_in, scale_in)
-        if _tracing._ENABLED:
-            # dispatch-only span; the same name as the TraceAnnotation
-            # region so host and device timelines line up in Perfetto
-            _tracing.record_span("mx.dp.run_steps", t_sp,
-                                 time.perf_counter(), steps=n,
-                                 step=self._t, source="data_parallel")
-        # one run_steps call = one in-flight entry (n fused steps inside a
-        # single executable); telemetry after admission, as in step()
-        self._window.admit(losses)
-        if _telem._ENABLED:
-            per_step_batch = xr.shape[1] if stacked else xr.shape[0]
-            self._record_telemetry(sig, per_step_batch * n, n,
-                                   flops_key=cost_key)
-        self._t += n
-        if not self._is_multiprocess():
-            self._key_dev, self._t_dev = key_out, t_out
-            self._t_dev_val = self._t
-        self.optimizer.num_update = self._t
-        if self._scaler is not None:
-            self._scaler.update_from_step(finite)
-        return losses
+        with _tracing.phased("step", "mx.dp.run_steps", steps=n,
+                             step=self._t,
+                             source="data_parallel") as rec:
+            # the call's phases (docs/observability.md has the table): each
+            # is a child span of mx.dp.run_steps and a field of its record
+            with rec.phase("get_step"):
+                xr = x._data if isinstance(x, NDArray) else jnp.asarray(x)
+                yr = y._data if isinstance(y, NDArray) else jnp.asarray(y)
+                self.optimizer.rescale_grad = 1.0
+                if stacked and (xr.shape[0] != n or yr.shape[0] != n):
+                    raise MXNetError(
+                        f"run_steps(stacked=True): leading dim must be n={n}, "
+                        f"got {xr.shape[0]}/{yr.shape[0]}")
+                sig = (xr.shape, str(xr.dtype), yr.shape, str(yr.dtype),
+                       stacked)
+                fn = self._get_multi(sig, n, stacked)
+            # All per-call scalars are kept device-resident, so a repeat call
+            # makes no host->device transfer at all (explicit or implicit —
+            # the latter is what sanitize mode's transfer guard rejects):
+            # lr/scale are cached by host value, and the RNG key + step counter
+            # ride the donated carry (multi returns their advanced values).
+            with rec.phase("put_scalars"):
+                lrs = []
+                for i in range(n):
+                    self.optimizer.num_update = self._t + 1 + i
+                    lrs.append(float(self.optimizer.learning_rate))
+                scale_val = float(self._scaler.loss_scale if self._scaler
+                                  else 1.0)
+                multiprocess = self._is_multiprocess()
+                ep = _rng._host_state["epoch"]
+                new_key = multiprocess \
+                    or getattr(self, "_key_dev", None) is None \
+                    or self._key_epoch != ep
+                if multiprocess:
+                    # multi-process SPMD: plain host values (device_put cannot
+                    # target non-addressable devices)
+                    lr_in = _np.asarray(lrs, _np.float32)
+                    scale_in = _np.float32(scale_val)
+                else:
+                    # replicated ON THE MESH, like the values multi hands back:
+                    # a plain device_put has no mesh in its type, so the second
+                    # call (fed the first call's outputs) would retrace and
+                    # recompile
+                    rep = NamedSharding(self.mesh, P())
+                    lr_sig = (tuple(lrs),)
+                    if getattr(self, "_lr_cache_sig", None) != lr_sig:
+                        self._lr_dev = jax.device_put(
+                            _np.asarray(lrs, _np.float32), rep)
+                        self._lr_cache_sig = lr_sig
+                    if getattr(self, "_scale_cache_val", None) != scale_val:
+                        self._scale_dev = jax.device_put(
+                            _np.float32(scale_val), rep)
+                        self._scale_cache_val = scale_val
+                    lr_in, scale_in = self._lr_dev, self._scale_dev
+            if new_key:
+                with rec.phase("rng_key"):
+                    # the host waits here for a key computed on the device
+                    key_in = _np.asarray(_rng.next_key_raw())
+            with rec.phase("put_scalars"):
+                if multiprocess:
+                    t_in = _np.float32(self._t + 1)
+                else:
+                    if new_key:
+                        self._key_dev = jax.device_put(key_in, rep)
+                        self._key_epoch = ep
+                    if getattr(self, "_t_dev_val", None) != self._t:
+                        self._t_dev = jax.device_put(
+                            _np.float32(self._t + 1), rep)
+                        self._t_dev_val = self._t
+                    key_in, t_in = self._key_dev, self._t_dev
+            with rec.phase("put_batch"):
+                spec = self.data_spec
+                if stacked:
+                    spec = P(None, *self.data_spec)
+                xr = self._put_batch(
+                    xr, NamedSharding(self.mesh, P(*spec[:xr.ndim])))
+                yr = self._put_batch(
+                    yr, NamedSharding(self.mesh, P(*spec[:yr.ndim])))
+            with rec.phase("capture_cost"):
+                cost_key = (sig, "multi", n)
+                self._program.capture_cost(
+                    cost_key, fn, self._params_raw, self._opt_state,
+                    self._comp_resid, key_in, xr, yr, lr_in, t_in, scale_in,
+                    kind="dp_multi", overlap_expected=self._overlap)
+            with rec.phase("launch"), _sanitize.guard():
+                (self._params_raw, self._opt_state, self._comp_resid, losses,
+                 finite, key_out, t_out) = fn(
+                    self._params_raw, self._opt_state, self._comp_resid,
+                    key_in, xr, yr, lr_in, t_in, scale_in)
+            with rec.phase("admit"):
+                # one run_steps call = one in-flight entry (n fused steps
+                # inside a single executable); telemetry after admission, as
+                # in step()
+                wait0 = self._window.wait_seconds
+                self._window.admit(losses)
+                if _telem._ENABLED:
+                    per_step_batch = xr.shape[1] if stacked else xr.shape[0]
+                    self._record_telemetry(sig, per_step_batch * n, n,
+                                           flops_key=cost_key)
+                self._t += n
+                if not multiprocess:
+                    self._key_dev, self._t_dev = key_out, t_out
+                    self._t_dev_val = self._t
+                self.optimizer.num_update = self._t
+            rec.split("admit", "admit_wait", self._window.wait_seconds - wait0)
+            if self._scaler is not None:
+                with rec.phase("scaler_sync"):
+                    self._scaler.update_from_step(finite)
+            return losses
 
     def step(self, x, y, batch_size=None):
         """Run one fused training step; x/y are NDArrays (global batch)."""
-        xr = x._data if isinstance(x, NDArray) else jnp.asarray(x)
-        yr = y._data if isinstance(y, NDArray) else jnp.asarray(y)
-        bs = batch_size or xr.shape[0]
-        self.optimizer.rescale_grad = 1.0
-        sig = (xr.shape, str(xr.dtype), yr.shape, str(yr.dtype))
-        fn = self._get_step(sig)
-        self._t += 1
-        self.optimizer.num_update = self._t
-        lr = _np.float32(self.optimizer.learning_rate)
-        key = _np.asarray(_rng.next_key_raw())
-        xr = self._put_batch(xr, NamedSharding(self.mesh, self.data_spec))
-        y_spec = self.data_spec if yr.ndim >= len(self.data_spec) \
-            else P(*self.data_spec[:yr.ndim])
-        yr = self._put_batch(yr, NamedSharding(self.mesh, y_spec))
-        scale = _np.float32(self._scaler.loss_scale if self._scaler else 1.0)
-        t_in = _np.float32(self._t)
-        if not self._is_multiprocess():
-            # EXPLICIT placement of the per-step host scalars: the uploads
-            # happen either way, but implicit numpy->device transfers are
-            # exactly what sanitize mode's transfer guard rejects
-            key, lr, t_in, scale = jax.device_put(
-                (key, lr, t_in, scale), NamedSharding(self.mesh, P()))
-        call_args = ((self._params_raw, self._opt_state, self._comp_resid,
-                      key, xr, yr, lr, t_in, scale) if self._compression
-                     else (self._params_raw, self._opt_state, key, xr, yr,
-                           lr, t_in, scale))
-        # cost_analysis FLOPs of the fused step, captured once per
-        # signature at artifact-build time (AOT lower shares XLA caches)
-        self._program.capture_cost(sig, fn, *call_args, kind="dp_step",
-                                   overlap_expected=self._overlap)
-        t_sp = time.perf_counter() if _tracing._ENABLED else 0.0
-        with _telem.annotate("mx.dp.step"), _sanitize.guard():
-            if self._compression:
-                (self._params_raw, self._opt_state, self._comp_resid, lossv,
-                 finite, aux) = fn(*call_args)
-            else:
-                self._params_raw, self._opt_state, lossv, finite, aux = fn(
-                    *call_args)
-        if _tracing._ENABLED:
-            # the step-dispatch span, same name as the TraceAnnotation
-            # region; admit/drain pacing is the window's own span
-            _tracing.record_span("mx.dp.step", t_sp, time.perf_counter(),
-                                 step=self._t, source="data_parallel")
-        if self._scaler is not None:
-            # fp16 dynamic loss scaling reads the finite flag per step —
-            # the one sync the overlap window cannot remove (documented in
-            # docs/input_pipeline.md "when overlap cannot help")
-            self._scaler.update_from_step(finite)
-        # non-blocking dispatch: admit the step into the bounded window
-        # (blocks on the (i-K)th step, never this one), THEN record
-        # telemetry — the interval-based step timing thereby runs at
-        # completion pace under backpressure instead of dispatch pace, and
-        # never adds a sync of its own
-        self._window.admit(lossv)
-        if _telem._ENABLED:
-            self._record_telemetry(sig, bs, 1)
-        return _feed.PendingScalar(lossv)
+        with _tracing.phased("step", "mx.dp.step", step=self._t + 1,
+                             source="data_parallel") as rec:
+            # the call's phases (docs/observability.md has the table): each
+            # is a child span of mx.dp.step and a field of its record
+            with rec.phase("get_step"):
+                xr = x._data if isinstance(x, NDArray) else jnp.asarray(x)
+                yr = y._data if isinstance(y, NDArray) else jnp.asarray(y)
+                bs = batch_size or xr.shape[0]
+                self.optimizer.rescale_grad = 1.0
+                sig = (xr.shape, str(xr.dtype), yr.shape, str(yr.dtype))
+                fn = self._get_step(sig)
+                self._t += 1
+                self.optimizer.num_update = self._t
+                lr = _np.float32(self.optimizer.learning_rate)
+            with rec.phase("rng_key"):
+                # the host waits here for a key computed on the device
+                key = _np.asarray(_rng.next_key_raw())
+            with rec.phase("put_batch"):
+                xr = self._put_batch(
+                    xr, NamedSharding(self.mesh, self.data_spec))
+                y_spec = self.data_spec if yr.ndim >= len(self.data_spec) \
+                    else P(*self.data_spec[:yr.ndim])
+                yr = self._put_batch(yr, NamedSharding(self.mesh, y_spec))
+            with rec.phase("put_scalars"):
+                scale = _np.float32(self._scaler.loss_scale if self._scaler
+                                    else 1.0)
+                t_in = _np.float32(self._t)
+                if not self._is_multiprocess():
+                    # EXPLICIT placement of the per-step host scalars: the
+                    # uploads happen either way, but implicit numpy->device
+                    # transfers are exactly what sanitize mode's transfer guard
+                    # rejects
+                    key, lr, t_in, scale = jax.device_put(
+                        (key, lr, t_in, scale), NamedSharding(self.mesh, P()))
+                call_args = ((self._params_raw, self._opt_state,
+                              self._comp_resid, key, xr, yr, lr, t_in, scale)
+                             if self._compression
+                             else (self._params_raw, self._opt_state, key, xr,
+                                   yr, lr, t_in, scale))
+            with rec.phase("capture_cost"):
+                # cost_analysis FLOPs of the fused step, captured once per
+                # signature at artifact-build time (AOT lower shares XLA
+                # caches)
+                self._program.capture_cost(sig, fn, *call_args, kind="dp_step",
+                                           overlap_expected=self._overlap)
+            with rec.phase("launch"), _sanitize.guard():
+                if self._compression:
+                    (self._params_raw, self._opt_state, self._comp_resid,
+                     lossv, finite, aux) = fn(*call_args)
+                else:
+                    self._params_raw, self._opt_state, lossv, finite, aux = fn(
+                        *call_args)
+                # the donated inputs are dead and this tuple holds the last
+                # references to them: let the several hundred handles go
+                # inside the call's record, not when the frame dies after it
+                del call_args
+            if self._scaler is not None:
+                with rec.phase("scaler_sync"):
+                    # fp16 dynamic loss scaling reads the finite flag per
+                    # step — the one sync the overlap window cannot remove
+                    # (docs/input_pipeline.md "when overlap cannot help")
+                    self._scaler.update_from_step(finite)
+            with rec.phase("admit"):
+                # non-blocking dispatch: admit the step into the bounded window
+                # (blocks on the (i-K)th step, never this one), THEN record
+                # telemetry — the interval-based step timing thereby runs at
+                # completion pace under backpressure instead of dispatch pace,
+                # and never adds a sync of its own
+                wait0 = self._window.wait_seconds
+                self._window.admit(lossv)
+                if _telem._ENABLED:
+                    self._record_telemetry(sig, bs, 1)
+            rec.split("admit", "admit_wait", self._window.wait_seconds - wait0)
+            return _feed.PendingScalar(lossv)
 
     def drain(self):
         """Block until every dispatched step completed — the designed

@@ -1151,15 +1151,11 @@ def comm_scope(op: str, nbytes: int, store: str = ""):
 
 
 def annotate(name: str):
-    """Device-trace region (jax.profiler.TraceAnnotation) when telemetry is
-    enabled — shows up inside the xplane timeline; nullcontext otherwise."""
-    if not _ENABLED:
-        return contextlib.nullcontext()
-    try:
-        import jax
-        return jax.profiler.TraceAnnotation(name)
-    except Exception:
-        return contextlib.nullcontext()
+    """A named region of host code: ``tracing.span(name)``, the one span
+    primitive. Always a ``jax.profiler.TraceAnnotation`` (inert without a
+    profiler session, inside the xplane timeline under one); with tracing
+    armed also a span in the flight-recorder ring."""
+    return tracing.span(name)
 
 
 def instrument_comm(op: str):

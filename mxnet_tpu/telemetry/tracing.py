@@ -1,30 +1,46 @@
 """Cross-layer span tracing and the black-box flight recorder.
 
 The metrics registry (mx.telemetry) and the roofline ledger answer *how
-much*; this module answers *which request* and *which step*.  It is an
-off-by-default tracing plane with the same discipline as metrics and
-fault injection: disarmed, every call site is a single module-flag check
-(``if _tracing._ENABLED:``) and nothing — no allocation, no clock read,
-no lock — happens on the hot path.
+much*; this module answers *which request*, *which step* and *which phase
+of it*. One primitive, :func:`span`, marks a region of host code:
 
-Armed, layers that already carry telemetry hooks record **spans**
-(named intervals with process-unique trace/span ids and parent links)
-and **events** (instants: fault firings, io retries, anomalies) into
-one bounded ring buffer.  The ring doubles as a black-box flight
-recorder: on preemption (``elastic.run``) or an unhandled exception
-(``sys.excepthook``/``threading.excepthook`` chain installed by
-:func:`enable`) the last N entries are dumped as NDJSON so the moments
-*before* a crash survive it.
+- it always enters a ``jax.profiler.TraceAnnotation`` of the same name, with
+  no flag in front. Without a profiler session that is an atomic load; under
+  any session (``jax.profiler.start_trace``, ``telemetry.trace_steps``, the
+  chip benchmark's ``--trace 1``) the span lands on the host plane of the
+  same xplane as the device's ``XLA Ops``, on that file's clock, nested
+  under whatever called it. Host spans and device gaps share one axis by
+  construction;
+- armed (``MXNET_TPU_TRACING`` / :func:`enable`) it also records the span,
+  with process-unique trace/span ids and its parent link, into one bounded
+  ring buffer, next to **events** (instants: fault firings, io retries,
+  anomalies).
+
+:func:`phased` is the same primitive for a call that is cut into contiguous
+phases (the fused trainer's ``step``, the feed's producer): every phase is a
+child span, and the call appends one small **record** (kind ``"step"`` or
+``"batch"``: start, duration, seconds by phase) to the ring whether tracing
+is armed or not: one ``perf_counter`` read at each phase boundary and one
+GIL-atomic append a call. :func:`step_records` returns the records;
+:func:`spans` returns spans and events only.
+
+The ring doubles as a black-box flight recorder. Armed, on preemption
+(``elastic.run``) or an unhandled exception (``sys.excepthook``/
+``threading.excepthook`` chain installed by :func:`enable`) the last N
+entries are dumped as NDJSON so the moments *before* a crash survive it.
+Every automatic dump is tied to arming: a default job that crashes leaves no
+file. The ring holds its last steps and batches all the same, and what reads
+them at the default is :func:`step_records`, ``/statusz`` (:func:`recent`)
+and a :func:`dump_flight_recorder` that the job calls itself.
 
 Export surfaces:
 
-- :func:`dump_chrome_trace` — Perfetto-loadable Chrome trace-event JSON.
-  Track (event) names reuse the ``TraceAnnotation`` region names
-  (``mx.dp.step``, ``mx.dp.run_steps``, ...) so the host spans line up
-  by name with the device timeline captured by
-  ``telemetry.trace_steps(n)``.
-- :func:`dump_flight_recorder` — NDJSON, one entry per line, with a
-  leading meta line carrying wall-clock ↔ perf_counter alignment.
+- the profiler's xplane (above): the only surface on the device's clock.
+- :func:`dump_chrome_trace` — Perfetto-loadable Chrome trace-event JSON of
+  the ring's spans and events, on ``perf_counter``.
+- :func:`dump_flight_recorder` — NDJSON, one entry per line (records
+  included), with a leading meta line carrying wall-clock ↔ perf_counter
+  alignment.
 - ``telemetry.statusz()`` / the ``/statusz`` HTTP endpoint — includes
   the last ``MXNET_TPU_STATUSZ_EVENTS`` recorder entries.
 
@@ -57,18 +73,21 @@ from ..base import env
 
 __all__ = [
     "enable", "disable", "is_enabled",
-    "span", "record_span", "event",
+    "span", "phased", "record_span", "event",
     "current", "attach", "new_root",
-    "spans", "recent", "set_max_spans", "reset",
+    "spans", "step_records", "recent", "set_max_spans", "reset",
     "dump_chrome_trace", "dump_flight_recorder",
     "watch_step_time", "check_loss", "install_crash_hooks",
 ]
 
 env.declare("MXNET_TPU_TRACING", False, bool,
-            "Arm the span-tracing plane at import (tracing.enable() at "
-            "runtime). Disarmed call sites are a single flag check.")
+            "Arm the span ring at import (tracing.enable() at runtime): "
+            "spans then carry ids and parents into the flight recorder. "
+            "Disarmed, a span is a profiler TraceAnnotation and nothing "
+            "else; step and batch records are kept either way.")
 env.declare("MXNET_TPU_TRACING_MAX_SPANS", 100_000, int,
-            "Flight-recorder ring capacity (completed spans + events); "
+            "Flight-recorder ring capacity (spans, events, step and batch "
+            "records); "
             "same bounding convention as MXNET_PROFILER_MAX_EVENTS.")
 env.declare("MXNET_TPU_FLIGHT_RECORDER", "mx_flight_recorder.ndjson", str,
             "Default path for the NDJSON flight-recorder dump (preemption, "
@@ -97,7 +116,14 @@ _PREFIX = "%x-%08x" % (os.getpid(),
 _WD_ALPHA = 0.1
 _WD: Dict[str, List[float]] = {}  # source -> [count, ewma]
 
-_NULL = contextlib.nullcontext()  # shared, reusable, reentrant
+
+def _annotation(name: str):
+    """``jax.profiler.TraceAnnotation(name)``; the class is bound on first
+    use, so that importing this module stays stdlib-only."""
+    global _annotation
+    from jax.profiler import TraceAnnotation
+    _annotation = TraceAnnotation
+    return TraceAnnotation(name)
 
 
 # ---------------------------------------------------------------------------
@@ -165,9 +191,10 @@ def attach(ctx: Optional[Tuple[str, str]]):
 def _resolve_parent(parent) -> Tuple[str, Optional[str]]:
     """(trace_id, parent_span_id) from an explicit parent, the thread-local
     stack, or a fresh root trace."""
+    if isinstance(parent, _Span):
+        # an open span that was entered disarmed has no ids to hand on
+        parent = parent.context if parent.span_id is not None else None
     if parent is not None:
-        if isinstance(parent, _Span):
-            return parent.trace_id, parent.span_id
         return parent[0], parent[1]
     cur = current()
     if cur is not None:
@@ -180,36 +207,46 @@ def _resolve_parent(parent) -> Tuple[str, Optional[str]]:
 # ---------------------------------------------------------------------------
 
 class _Span:
-    """An open span; context manager. Completed on exit into the ring."""
+    """An open span; context manager. Always a profiler TraceAnnotation
+    region; where tracing was armed at entry, completed on exit into the
+    ring."""
 
-    __slots__ = ("name", "trace_id", "span_id", "parent_id", "attrs", "_t0")
+    __slots__ = ("name", "attrs", "trace_id", "span_id", "parent_id",
+                 "_parent", "_t0", "_ann")
 
-    def __init__(self, name: str, trace_id: str, span_id: str,
-                 parent_id: Optional[str], attrs: Dict[str, Any]):
+    def __init__(self, name: str, parent, attrs: Dict[str, Any]):
         self.name = name
-        self.trace_id = trace_id
-        self.span_id = span_id
-        self.parent_id = parent_id
         self.attrs = attrs
+        self.trace_id = self.span_id = self.parent_id = None
+        self._parent = parent
         self._t0 = 0.0
 
     @property
-    def context(self) -> Tuple[str, str]:
+    def context(self) -> Tuple[Optional[str], Optional[str]]:
         return (self.trace_id, self.span_id)
 
     def set_attr(self, key: str, value: Any) -> None:
         self.attrs[key] = value
 
     def __enter__(self) -> "_Span":
-        stack = getattr(_TLS, "stack", None)
-        if stack is None:
-            stack = _TLS.stack = []
-        stack.append((self.trace_id, self.span_id))
+        self._ann = _annotation(self.name)
+        self._ann.__enter__()
+        if _ENABLED:
+            self.trace_id, self.parent_id = _resolve_parent(self._parent)
+            self.span_id = _next_id()
+            stack = getattr(_TLS, "stack", None)
+            if stack is None:
+                stack = _TLS.stack = []
+            stack.append((self.trace_id, self.span_id))
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
-        t1 = time.perf_counter()
+        if self.span_id is not None:
+            self._complete(time.perf_counter(), exc_type)
+        self._ann.__exit__(exc_type, exc, tb)
+
+    def _complete(self, t1: float, exc_type) -> None:
         stack = getattr(_TLS, "stack", None)
         if stack:
             stack.pop()
@@ -222,15 +259,114 @@ class _Span:
                  "attrs": self.attrs})
 
 
-def span(name: str, parent=None, **attrs):
-    """Context manager recording a span on exit. Disarmed: returns a shared
-    nullcontext (no allocation). ``parent`` is an explicit (trace_id,
-    span_id) tuple or open span; default is the thread-local current span,
-    else a fresh root trace."""
-    if not _ENABLED:
-        return _NULL
-    trace_id, parent_id = _resolve_parent(parent)
-    return _Span(name, trace_id, _next_id(), parent_id, attrs)
+def span(name: str, parent=None, **attrs) -> _Span:
+    """The span primitive: a context manager that is always a
+    ``jax.profiler.TraceAnnotation(name)`` region (inert without a profiler
+    session; under one, on the host plane of the device's own trace) and,
+    armed, records the span into the ring on exit. ``parent`` is an explicit
+    (trace_id, span_id) tuple or open span; default is the thread-local
+    current span, else a fresh root trace."""
+    return _Span(name, parent, attrs)
+
+
+class _Phased(_Span):
+    """A span cut into contiguous phases that also leaves one always-on
+    record in the ring (see :func:`phased`)."""
+
+    __slots__ = ("kind", "prefix", "phases", "_t", "_last")
+
+    def __init__(self, kind: str, name: str, prefix: Optional[str], parent,
+                 attrs: Dict[str, Any]):
+        super().__init__(name, parent, attrs)
+        self.kind = kind
+        self.prefix = name + "." if prefix is None else prefix
+        self.phases: Dict[str, float] = {}
+        self._t = 0.0
+        self._last = None
+
+    def __enter__(self) -> "_Phased":
+        super().__enter__()
+        self._t = self._t0
+        return self
+
+    def phase(self, name: str) -> "_Phase":
+        """Child span ``<prefix><name>``. It is booked from the boundary the
+        phase before it closed at (or the call's entry) to its own exit, so
+        phases share their boundaries and what runs between two ``with``
+        blocks belongs to the later one. A name used twice accumulates."""
+        return _Phase(self, name)
+
+    def split(self, phase: str, part: str, seconds: float) -> None:
+        """Book ``seconds`` of ``phase`` under ``part`` instead (a wait
+        measured inside the phase by the layer that waited)."""
+        booked = self.phases.get(phase, 0.0)
+        seconds = min(max(seconds, 0.0), booked)
+        self.phases[phase] = booked - seconds
+        self.phases[part] = self.phases.get(part, 0.0) + seconds
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        t1 = time.perf_counter()
+        if self._last is not None:
+            # the return path after the last phase, so that the phases sum
+            # to the call
+            self.phases[self._last] += t1 - self._t
+        entry = dict(self.attrs, kind=self.kind, name=self.name,
+                     ts=self._t0, dur=t1 - self._t0, phases=self.phases,
+                     thread=threading.get_ident())
+        if exc_type is not None:
+            entry["error"] = exc_type.__name__
+        _append(entry)
+        if self.span_id is not None:
+            self._complete(t1, exc_type)
+        self._ann.__exit__(exc_type, exc, tb)
+
+
+class _Phase:
+    """One open phase of a :class:`_Phased` call; context manager."""
+
+    __slots__ = ("_rec", "_name", "_ann")
+
+    def __init__(self, rec: _Phased, name: str):
+        self._rec = rec
+        self._name = name
+
+    def __enter__(self) -> "_Phase":
+        self._ann = _annotation(self._rec.prefix + self._name)
+        self._ann.__enter__()
+        return self
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        rec, name = self._rec, self._name
+        t = time.perf_counter()
+        rec.phases[name] = rec.phases.get(name, 0.0) + (t - rec._t)
+        if rec.span_id is not None:
+            record_span(rec.prefix + name, rec._t, t, parent=rec.context)
+        rec._t = t
+        rec._last = name
+        self._ann.__exit__(exc_type, exc, tb)
+
+
+def phased(kind: str, name: str, prefix: Optional[str] = None, parent=None,
+           **attrs) -> _Phased:
+    """:func:`span` for a call that is cut into phases::
+
+        with tracing.phased("step", "mx.dp.step", step=t) as rec:
+            with rec.phase("rng_key"):
+                ...
+            with rec.phase("launch"):
+                ...
+
+    The call is the span ``name``, each phase a child span
+    ``<prefix><phase>`` (``prefix`` defaults to ``name + "."``). Armed or
+    not, the call also appends one record to the ring: ``{"kind": kind,
+    "name": name, "ts": perf_counter at entry, "dur": seconds, "phases":
+    {phase: seconds}, "thread": ident, **attrs}``, with ``"error"`` where an
+    exception passed through. The phases partition the call: they sum to
+    ``dur``. A call leaves its record whether or not it delivered: one that
+    raised carries ``error``, and the caller may mark one it abandoned
+    (``rec.set_attr``; the feed marks ``aborted``). :func:`step_records`
+    reads the records back."""
+    return _Phased(kind, name, prefix, parent, attrs)
 
 
 def record_span(name: str, t_start: float, t_end: float, parent=None,
@@ -282,8 +418,11 @@ def _append(entry: Dict[str, Any]) -> None:
 # Ring access
 # ---------------------------------------------------------------------------
 
-def spans() -> List[Dict[str, Any]]:
-    """Snapshot of the recorder ring (oldest first). Writers are lock-free
+_RECORD_KINDS = ("step", "batch")
+
+
+def _entries() -> List[Dict[str, Any]]:
+    """Snapshot of the whole ring (oldest first). Writers are lock-free
     (see _append), so a snapshot taken mid-append can raise "deque mutated
     during iteration" — retry; the window is a single append."""
     for _ in range(64):
@@ -294,11 +433,30 @@ def spans() -> List[Dict[str, Any]]:
     return []  # writer storm: the flight recorder prefers empty to hanging
 
 
+def spans() -> List[Dict[str, Any]]:
+    """The ring's spans and events (oldest first), without the step and
+    batch records."""
+    return [e for e in _entries() if e["kind"] not in _RECORD_KINDS]
+
+
+def step_records(name: Optional[str] = None, since: Optional[float] = None,
+                 until: Optional[float] = None) -> List[Dict[str, Any]]:
+    """The ring's step and batch records (see :func:`phased`), oldest first:
+    those named ``name`` (``"mx.dp.step"``, ``"mx.dp.run_steps"``,
+    ``"mx.feed.batch"``; all if None) that began in ``[since, until]``,
+    times on ``time.perf_counter``."""
+    return [e for e in _entries() if e["kind"] in _RECORD_KINDS
+            and (name is None or e["name"] == name)
+            and (since is None or e["ts"] >= since)
+            and (until is None or e["ts"] <= until)]
+
+
 def recent(n: Optional[int] = None) -> List[Dict[str, Any]]:
-    """The trailing ``n`` entries (default MXNET_TPU_STATUSZ_EVENTS)."""
+    """The trailing ``n`` entries, records included (default
+    MXNET_TPU_STATUSZ_EVENTS)."""
     if n is None:
         n = int(env.get("MXNET_TPU_STATUSZ_EVENTS"))
-    entries = spans()
+    entries = _entries()
     if n <= 0 or n >= len(entries):
         return entries
     return entries[-n:]
@@ -309,7 +467,7 @@ def set_max_spans(n: int) -> None:
     profiler.set_max_events — the shared bounding convention)."""
     global _RING
     with _LOCK:  # excludes concurrent re-cap/reset; appends are atomic
-        _RING = deque(spans(), maxlen=max(int(n), 0))
+        _RING = deque(_entries(), maxlen=max(int(n), 0))
 
 
 def reset() -> None:
@@ -325,13 +483,11 @@ def reset() -> None:
 # ---------------------------------------------------------------------------
 
 def dump_chrome_trace(path: str) -> str:
-    """Write the ring as Chrome trace-event JSON (Perfetto-loadable).
-
-    Span names are the track names; the trainer's dispatch spans reuse the
-    ``TraceAnnotation`` region names (``mx.dp.step``, ``mx.dp.run_steps``)
-    so this file and the ``trace_steps(n)`` device timeline line up by
-    name. Timestamps are perf_counter microseconds, matching
-    ``profiler.dump()``."""
+    """Write the ring's spans and events as Chrome trace-event JSON
+    (Perfetto-loadable). Span names are the track names, the same names the
+    profiler's xplane holds; timestamps are perf_counter microseconds,
+    matching ``profiler.dump()`` (the xplane, not this file, is on the
+    device's clock)."""
     events = []
     for e in spans():
         out = {"name": e["name"], "cat": "mx." + e["kind"],
@@ -354,11 +510,13 @@ def dump_chrome_trace(path: str) -> str:
 def dump_flight_recorder(path: Optional[str] = None,
                          reason: str = "manual") -> str:
     """Write the ring as NDJSON: a meta line (reason, pid, wall-clock ↔
-    perf_counter anchor), then one entry per line, oldest first. This is
-    the black-box dump taken on preemption and by the crash hooks."""
+    perf_counter anchor), then one entry per line (spans, events, step and
+    batch records), oldest first. This is the black-box dump that
+    ``elastic.run`` (preemption, a step that raised) and the crash hooks
+    take when tracing is armed; disarmed nobody calls it but the job."""
     if path is None:
         path = str(env.get("MXNET_TPU_FLIGHT_RECORDER"))
-    entries = spans()
+    entries = _entries()
     with open(path, "w") as f:
         f.write(json.dumps({"kind": "meta", "reason": reason,
                             "pid": os.getpid(), "wall_time": time.time(),

@@ -91,6 +91,54 @@ class DataParallelTrainer:
     assert out[0].symbol.endswith("_build_step.step")
 
 
+@pytest.mark.parametrize("method,planted", [
+    ("step", "self._window.admit(lossv)"),
+    ("run_steps", "self._window.admit(losses)"),
+])
+def test_host_sync_reads_the_real_step_bodies(tmp_path, method, planted):
+    """The lint keys on names: the code between `mx.dp.step`'s entry and its
+    return has to live in a function the hot list names. A read-back planted
+    in the real file's step body is found, under the method's own name."""
+    rel = "mxnet_tpu/parallel/data_parallel.py"
+    src = (REPO / rel).read_text()
+    assert src.count(planted) == 1
+    src = src.replace(planted, "float(finite); " + planted)
+    out = [f for f in _lint(tmp_path, rel, src, ["host-sync"])
+           if "float(finite)" in f.message]
+    assert [f.symbol for f in out] == ["DataParallelTrainer." + method]
+
+
+def test_hot_lists_cover_the_always_on_phase_bookkeeping():
+    """Every step and every batch passes through tracing.phased armed or
+    not, so all of it is held to the no-sync rule by both lints."""
+    from tools.mxlint.core import ModuleInfo
+    from tools.mxlint.passes import host_sync, sync_in_loop
+    mod = ModuleInfo(REPO / "mxnet_tpu/telemetry/tracing.py")
+    names = {mod.qualname(fn): fn for fn in mod.functions()}
+    per_step = ["span", "phased", "record_span", "_append",
+                "_Span.__enter__", "_Span.__exit__", "_Span._complete",
+                "_Phased.__enter__", "_Phased.__exit__", "_Phased.phase",
+                "_Phased.split", "_Phase.__enter__", "_Phase.__exit__"]
+    assert [n for n in per_step if n not in names] == []
+    assert [n for n in per_step
+            if not host_sync._is_hot(mod, names[n])] == []
+    looped = ["record_span", "_Span._complete", "_Phased.__exit__",
+              "_Phased.split", "_Phase.__exit__", "step_records"]
+    assert [n for n in looped
+            if not sync_in_loop._is_hot(mod, names[n])] == []
+    # and the trainer's calls are whole bodies, not wrappers round a helper
+    # the lists do not name
+    dp = ModuleInfo(REPO / "mxnet_tpu/parallel/data_parallel.py")
+    phased = [dp.qualname(fn) for fn in dp.functions()
+              if ".phase(" in "\n".join(
+                  dp.lines[fn.lineno - 1:fn.end_lineno])]
+    assert sorted(phased) == ["DataParallelTrainer.run_steps",
+                              "DataParallelTrainer.step"]
+    for fn in dp.functions():
+        if dp.qualname(fn) in phased:
+            assert host_sync._is_hot(dp, fn) and sync_in_loop._is_hot(dp, fn)
+
+
 # ---------------------------------------------------------------------------
 # retrace-hazard
 # ---------------------------------------------------------------------------

@@ -2,8 +2,8 @@
 
 Acceptance under test:
 
-  - disarmed = one flag check: span() returns the shared nullcontext, no
-    ring writes anywhere;
+  - disarmed: span() is a profiler TraceAnnotation only, spans and events
+    write nothing to the ring;
   - armed: process-unique trace/span ids, parent propagation within and
     across threads (attach/new_root/explicit parent);
   - one serving request's trace id observable END TO END: the submit-side
@@ -60,13 +60,16 @@ def _armed():
 # span API
 # ---------------------------------------------------------------------------
 
-def test_disarmed_span_is_shared_nullcontext():
+def test_disarmed_span_writes_nothing_to_the_ring():
+    """Disarmed, a span is a profiler TraceAnnotation and nothing else: no
+    ids, no thread-local stack, no ring write (ISSUE 25: the one primitive
+    has no flag in front of the annotation)."""
     assert not tracing.is_enabled()
-    a = tracing.span("x")
-    b = tracing.span("y", rows=3)
-    assert a is b is tracing._NULL
-    with a:
-        pass
+    with tracing.span("x") as a:
+        assert a.context == (None, None)
+        assert tracing.current() is None
+        with tracing.span("y", rows=3):
+            pass
     assert tracing.spans() == []
     assert tracing.record_span("x", 0.0, 1.0) is None
     assert tracing.event("x") is None
@@ -367,8 +370,13 @@ def test_sigterm_kill_dumps_flight_recorder(tmp_path, monkeypatch):
     meta, entries = lines[0], lines[1:]
     assert meta["reason"] == "preemption"
     assert meta["entries"] == len(entries)
-    steps = [e for e in entries if e["name"] == "mx.dp.step"]
+    steps = [e for e in entries
+             if e["name"] == "mx.dp.step" and e["kind"] == "span"]
     assert {e["attrs"]["step"] for e in steps} == {1, 2, 3}
+    # each step's always-on record is in the dump beside its span
+    records = [e for e in entries
+               if e["name"] == "mx.dp.step" and e["kind"] == "step"]
+    assert [e["step"] for e in records] == [1, 2, 3]
     assert any(e["name"] == "mx.preemption" for e in entries)
     # the final snapshot's writer spans land in the ring too (post-dump),
     # proving the elastic write/commit funnel records

@@ -173,14 +173,23 @@ METHOD_CHECKS = [
      {"record_span"}, "call"),
     ("serving/batcher.py", "ContinuousBatcher", "_complete",
      {"record_span"}, "call"),
+    # (ISSUE 25) the trainer's calls and the feed's producer go through
+    # tracing.phased — the span, its phases and the always-on step / batch
+    # record in one body; the window's wait is a real tracing.span
     ("parallel/data_parallel.py", "DataParallelTrainer", "step",
-     {"record_span"}, "call"),
+     {"phased"}, "call"),
+    ("parallel/data_parallel.py", "DataParallelTrainer", "step",
+     {"phase"}, "call"),
     ("parallel/data_parallel.py", "DataParallelTrainer", "run_steps",
-     {"record_span"}, "call"),
+     {"phased"}, "call"),
+    ("parallel/data_parallel.py", "DataParallelTrainer", "run_steps",
+     {"phase"}, "call"),
     ("engine/async_feed.py", "DeviceFeed", "_produce",
-     {"record_span"}, "call"),
+     {"phased"}, "call"),
+    ("engine/async_feed.py", "DeviceFeed", "next",
+     {"span"}, "call"),
     ("engine/async_feed.py", "DispatchWindow", "admit",
-     {"record_span"}, "call"),
+     {"span"}, "call"),
     ("elastic/snapshot.py", "SnapshotManager", "_write",
      {"span", "attach"}, "call"),
     ("faults/__init__.py", None, "check",

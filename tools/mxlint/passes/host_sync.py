@@ -107,8 +107,13 @@ HOT_FUNCTIONS = [
     # callers already materialized; a sync sneaking in here would charge
     # every armed step for it.
     ("mxnet_tpu/telemetry/tracing.py",
-     r"(\b(span|record_span|event|attach|new_root|watch_step_time|"
-     r"check_loss|_append|_anomaly|_resolve_parent)\b|_Span\.__(enter|exit)__)"),
+     r"(\b(span|phased|record_span|event|attach|new_root|watch_step_time|"
+     r"check_loss|_append|_anomaly|_resolve_parent)\b|"
+     # (ISSUE 25) the always-on half: every step and every batch passes
+     # through these whether tracing is armed or not
+     r"_Span\.(__enter__|__exit__|_complete)\b|"
+     r"_Phased\.(__enter__|__exit__|phase|split)\b|"
+     r"_Phase\.(__enter__|__exit__)\b)"),
 ]
 
 # host reads of *python* scalars that merely look like syncs. Matched

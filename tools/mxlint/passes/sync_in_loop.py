@@ -59,8 +59,12 @@ LOOP_FUNCTIONS = [
     # ring inside loops — syncing on a step output in here would serialize
     # every armed training loop that feeds the watchdog
     ("mxnet_tpu/telemetry/tracing.py",
-     r"\b(record_span|event|watch_step_time|check_loss|dump_chrome_trace|"
-     r"dump_flight_recorder)\b"),
+     r"(\b(record_span|event|watch_step_time|check_loss|dump_chrome_trace|"
+     r"dump_flight_recorder|step_records)\b|"
+     # (ISSUE 25) the phase bookkeeping runs in every step of every loop,
+     # armed or not
+     r"_Span\._complete\b|_Phased\.(__exit__|phase|split)\b|"
+     r"_Phase\.__(enter|exit)__)"),
     # goodput ledger (ISSUE 17): the waterfall funnel and ring append run
     # inside every armed training loop at step pace — syncing on a step
     # output here would serialize exactly the pipeline whose stalls the
