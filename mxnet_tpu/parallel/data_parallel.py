@@ -233,6 +233,24 @@ def functional_lazy_update(opt: "opt_mod.Optimizer"):
     return None
 
 
+def next_step_key(host: bool):
+    """The raw key of one fused training step: one split of the global
+    stream (mxnet_tpu/random.py), the same values on both routes.
+
+    ``host=False`` (a single process): the key stays the device array the
+    split made. Its two tiny programs queue behind the running step and the
+    host goes on to dispatch the next one; the trainer's ``device_put`` of
+    the step's scalars then places it device to device. ``host=True``
+    (multi-process SPMD): a host value, which blocks until the key is
+    computed, that is until the step before it has finished."""
+    if host:
+        # device_put cannot target the non-addressable devices of a mesh
+        # spanning processes, so the jitted step takes the key as a host
+        # value there: the one designed read-back of the key
+        return _np.asarray(_rng.next_key_raw())  # mxlint: disable=host-sync
+    return _rng.next_key_raw()
+
+
 def _make_apply_fn(block: HybridBlock, plist: List[Parameter], train: bool,
                    aux_order_out: Optional[List[Parameter]] = None):
     """Pure fn(key_raw, params_raw_list, *inputs_raw) -> (outputs, aux_list).
@@ -1432,8 +1450,8 @@ class DataParallelTrainer:
                     lr_in, scale_in = self._lr_dev, self._scale_dev
             if new_key:
                 with rec.phase("rng_key"):
-                    # the host waits here for a key computed on the device
-                    key_in = _np.asarray(_rng.next_key_raw())
+                    # as in step(): dispatched and not read in one process
+                    key_in = next_step_key(multiprocess)
             with rec.phase("put_scalars"):
                 if multiprocess:
                     t_in = _np.float32(self._t + 1)
@@ -1502,9 +1520,14 @@ class DataParallelTrainer:
                 self._t += 1
                 self.optimizer.num_update = self._t
                 lr = _np.float32(self.optimizer.learning_rate)
+            multiprocess = self._is_multiprocess()
             with rec.phase("rng_key"):
-                # the host waits here for a key computed on the device
-                key = _np.asarray(_rng.next_key_raw())
+                # in one process the key stays on the device: this is the
+                # dispatch of its split, nothing here waits for the step
+                # before and the window fills. Multi-process SPMD reads it
+                # back, which does wait (next_step_key). Booked as a sync
+                # on both routes: whatever waits here waits on the device
+                key = next_step_key(multiprocess)
             with rec.phase("put_batch"):
                 xr = self._put_batch(
                     xr, NamedSharding(self.mesh, self.data_spec))
@@ -1515,11 +1538,12 @@ class DataParallelTrainer:
                 scale = _np.float32(self._scaler.loss_scale if self._scaler
                                     else 1.0)
                 t_in = _np.float32(self._t)
-                if not self._is_multiprocess():
+                if not multiprocess:
                     # EXPLICIT placement of the per-step host scalars: the
                     # uploads happen either way, but implicit numpy->device
                     # transfers are exactly what sanitize mode's transfer guard
-                    # rejects
+                    # rejects. The key is a device array already: device to
+                    # device, on a one-chip mesh no copy at all
                     key, lr, t_in, scale = jax.device_put(
                         (key, lr, t_in, scale), NamedSharding(self.mesh, P()))
                 call_args = ((self._params_raw, self._opt_state,
@@ -1542,8 +1566,9 @@ class DataParallelTrainer:
                         *call_args)
                 # the donated inputs are dead and this tuple holds the last
                 # references to them: let the several hundred handles go
-                # inside the call's record, not when the frame dies after it
-                del call_args
+                # inside the call's record, not when the frame dies after it.
+                # The same for the unused aux outputs and the step's inputs
+                del call_args, aux, xr, yr, key, lr, t_in, scale
             if self._scaler is not None:
                 with rec.phase("scaler_sync"):
                     # fp16 dynamic loss scaling reads the finite flag per

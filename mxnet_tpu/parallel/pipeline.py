@@ -54,11 +54,11 @@ from ..ndarray import NDArray
 from .. import engine as _engine
 from ..engine import async_feed as _feed
 from .. import optimizer as opt_mod
-from .. import random as _rng
 from .. import sanitize as _sanitize
 from .. import telemetry as _telem
 from . import megatron as _mg
 from . import zero as _zero
+from .data_parallel import next_step_key
 from .mesh import current_mesh, P
 from .step_program import StepProgram
 from .tensor_parallel import gather_tp, slice_tp, tp_shard_dim
@@ -1118,7 +1118,7 @@ class PipelineTrainer:
         self._t += 1
         self.optimizer.num_update = self._t
         lr = _np.float32(self.optimizer.learning_rate)
-        key = _np.asarray(_rng.next_key_raw())
+        key = next_step_key(host=False)   # single-process trainer
         data = P(None, self.dp_axis) if self.dp_axis else P(None)
         xr = jax.device_put(xr, NamedSharding(
             self.mesh, P(*data, *([None] * (xr.ndim - 2)))))

@@ -37,13 +37,13 @@ from jax.sharding import NamedSharding
 from ..base import MXNetError
 from ..ndarray import NDArray
 from ..engine import async_feed as _feed
-from .. import random as _rng
 from .. import sanitize as _sanitize
 from .. import telemetry as _telem
 from .. import optimizer as opt_mod
 from ..parallel import zero as _zero
 from ..parallel import moe as _moe
-from ..parallel.data_parallel import DataParallelTrainer, _make_apply_fn
+from ..parallel.data_parallel import (DataParallelTrainer, _make_apply_fn,
+                                      next_step_key)
 from ..parallel.mesh import require_axis, P
 from ..parallel.step_program import StepProgram
 
@@ -297,7 +297,7 @@ class MoETrainer(DataParallelTrainer):
         self._t += 1
         self.optimizer.num_update = self._t
         lr = _np.float32(self.optimizer.learning_rate)
-        key = _np.asarray(_rng.next_key_raw())
+        key = next_step_key(self._is_multiprocess())
         xr = self._put_batch(xr, NamedSharding(self.mesh, self.data_spec))
         y_spec = self.data_spec if yr.ndim >= len(self.data_spec) \
             else P(*self.data_spec[:yr.ndim])

@@ -11,7 +11,9 @@ Acceptance under test:
     MXNET_TELEMETRY and MXNET_TPU_TRACING unset, the host plane of a CPU trace
     holds `mx.dp.step` with every phase nested inside it, and `mx.feed.put` on
     another thread's line;
-  - a wait planted in the key read-back shows in `rng_key`, one planted in the
+  - a wait planted where the key is drawn shows in `rng_key` on both routes
+    (ISSUE 26: in one process the key stays on the device and the phase is its
+    dispatch; multi-process SPMD reads it back), one planted in the
     window's block in `admit_wait` and in a real `mx.window.admit` span;
   - `telemetry.annotate` has no gate of its own.
 """
@@ -250,8 +252,8 @@ def test_one_record_per_run_steps_call_disarmed():
     records = tracing.step_records("mx.dp.run_steps")
     assert [(r["step"], r["steps"]) for r in records] == [(0, 2), (2, 2),
                                                           (4, 2)]
-    # the key is read back for the first call alone; afterwards it rides
-    # the donated carry on the device
+    # the key is drawn for the first call alone; afterwards it rides the
+    # donated carry on the device
     assert "rng_key" in records[0]["phases"]
     assert all("rng_key" not in r["phases"] for r in records[1:])
     for r in records:
@@ -316,8 +318,10 @@ def test_queue_wait_is_the_feeds_slack():
     assert waited > 0.05 and waited > busy
 
 
-def test_planted_wait_in_the_key_read_back_shows_in_rng_key(monkeypatch):
+@pytest.mark.parametrize("multiprocess", [False, True])
+def test_planted_wait_in_the_key_shows_in_rng_key(monkeypatch, multiprocess):
     tr = _trainer()
+    tr._multiprocess = multiprocess
     tr.step(*_batch())
     tr.drain()
     real = mx_random.next_key_raw

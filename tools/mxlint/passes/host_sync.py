@@ -26,7 +26,10 @@ HOT_FUNCTIONS = [
      r"DataParallelTrainer\.(step|run_steps|_build_step|"
      r"_build_step_compressed|_get_step|_get_multi|_record_telemetry|"
      r"_loss_raw|_put_batch|_grad_allreduce_bytes)\b"),
-    ("mxnet_tpu/parallel/data_parallel.py", r"\b_make_apply_fn\b"),
+    # next_step_key: the per-step RNG key, shared by the three fused
+    # trainers; its multi-process branch is the one waived read-back
+    ("mxnet_tpu/parallel/data_parallel.py",
+     r"\b(_make_apply_fn|next_step_key)\b"),
     ("mxnet_tpu/parallel/pipeline.py",
      r"(PipelineTrainer\.(step|_build_step|_loss_raw|_record_telemetry|"
      r"_record_partitioned_tp_telemetry|_init_zero_state_partitioned)\b"
@@ -120,7 +123,7 @@ HOT_FUNCTIONS = [
 # against the unparsed argument of float()/int()/bool()/np.asarray().
 ALLOWED_ARG = re.compile(
     r"learning_rate|loss_scale|num_update|\.shape\b|\.ndim\b|\.nbytes\b|"
-    r"perf_counter|len\(|\blrs?\b|next_key_raw|batch_size|wd_mult|"
+    r"perf_counter|len\(|\blrs?\b|batch_size|wd_mult|"
     r"rescale_grad|\.get\(|self\._t\b|_np\.prod")
 
 _COERCIONS = {"float", "int", "bool"}
