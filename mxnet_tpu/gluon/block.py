@@ -356,6 +356,57 @@ class HybridBlock(Block):
         self._fingerprint_memo = None
         super().hybridize(active, **kwargs)
 
+    # whether `recompute()` was asked of this block (an instance attribute
+    # once set, so it enters the structural fingerprint of those blocks only)
+    _recompute = False
+
+    def recompute(self, active=True):
+        """Recompute this block's forward in the backward pass instead of
+        keeping its activations, wherever the block is traced into an
+        enclosing program (a fused trainer's step, a hybridized parent):
+        only the block's inputs live from the forward pass to the backward
+        one. A property of the model, set where the model is built; the
+        eager tape is not affected (its `MXNET_TPU_REMAT_BWD` is its own).
+        Returns the block."""
+        self._recompute = bool(active)
+        self.clear_cache()
+        return self
+
+    def _forward_recomputed(self, *args):
+        """`jax.checkpoint` of the forward over the block's array inputs.
+        The parameters are what the enclosing trace put in their place
+        (closed-over tracers, which `jax.checkpoint` takes as further
+        inputs); deferred aux updates (BatchNorm's statistics) leave the
+        recomputed region as outputs and are handed on outside it."""
+        raw, treedef, is_nd = _flatten_nd(list(args))
+        traced = [i for i, r in enumerate(raw) if isinstance(r, jax.core.Tracer)]
+        aux_params: List[Parameter] = []
+        out_treedef = []
+
+        def run(*dyn):
+            leaves = list(raw)
+            for i, r in zip(traced, dyn):
+                leaves[i] = r
+            nds = jax.tree_util.tree_unflatten(
+                treedef, [NDArray(l) if n else l
+                          for l, n in zip(leaves, is_nd)])
+            aux: List[Tuple[Parameter, Any]] = []
+            _AUX_STACK.append(aux)
+            try:
+                out = self._forward_unhybridized(*nds)
+            finally:
+                _AUX_STACK.pop()
+            flat, tdef, _ = _flatten_nd(out)
+            out_treedef[:] = [tdef]
+            aux_params[:] = [p for p, _ in aux]
+            return tuple(flat), tuple(v for _, v in aux)
+
+        flat, aux_vals = jax.checkpoint(run)(*[raw[i] for i in traced])
+        for p, v in zip(aux_params, aux_vals):
+            defer_aux_update(p, v)
+        return jax.tree_util.tree_unflatten(
+            out_treedef[0], [NDArray(r) for r in flat])
+
     def clear_cache(self):
         # drop this block's entries from the process-wide cache too, so a
         # structurally-stale artifact can't be handed back on the next call
@@ -426,15 +477,17 @@ class HybridBlock(Block):
         # inside an enclosing trace, fold into the same XLA program instead of
         # nesting another cached graph (keeps one fused computation)
         use_cached = self._active and not in_trace()
+        if use_cached:
+            run = self._call_cached
+        elif self._recompute and in_trace():
+            run = self._forward_recomputed
+        else:
+            run = self._forward_unhybridized
         try:
-            if use_cached:
-                return self._call_cached(*args)
-            return self._forward_unhybridized(*args)
+            return run(*args)
         except DeferredInitializationError:
             self._ensure_params_ready(list(args))
-            if use_cached:
-                return self._call_cached(*args)
-            return self._forward_unhybridized(*args)
+            return run(*args)
 
     def _forward_unhybridized(self, *args):
         kwargs = {}

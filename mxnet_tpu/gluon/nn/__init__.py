@@ -2,7 +2,7 @@
 from .basic_layers import (Sequential, HybridSequential, Dense, Dropout,
                            BatchNorm, SyncBatchNorm, Embedding, Flatten,
                            Activation, LeakyReLU, PReLU, ELU, SELU, GELU, Swish,
-                           LayerNorm, GroupNorm, InstanceNorm, Lambda,
+                           LayerNorm, RMSNorm, GroupNorm, InstanceNorm, Lambda,
                            HybridLambda, Identity)
 from .conv_layers import (Conv1D, Conv2D, Conv3D, Conv1DTranspose,
                           Conv2DTranspose, Conv3DTranspose, MaxPool1D, MaxPool2D,

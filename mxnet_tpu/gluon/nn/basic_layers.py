@@ -277,6 +277,27 @@ class LayerNorm(HybridBlock):
         return F.LayerNorm(x, gamma, beta, axis=self._axis, eps=self._epsilon)
 
 
+class RMSNorm(HybridBlock):
+    """gamma * v * rsqrt(mean(v^2) + eps) over the last axis, computed in
+    float32 (ops/nn.py `RMSNorm`). Called with a second input it is the
+    gated form, v = x * silu(gate), that a Mamba-2 mixer puts in front of
+    its output projection. No reference counterpart."""
+
+    def __init__(self, epsilon=1e-5, gamma_initializer="ones", in_channels=0,
+                 prefix=None, params=None):
+        super().__init__(prefix, params)
+        self._epsilon = epsilon
+        self.gamma = self.params.get("gamma", shape=(in_channels,),
+                                     init=gamma_initializer,
+                                     allow_deferred_init=True)
+
+    def infer_shape(self, x, *args):
+        self.gamma.shape = (x.shape[-1],)
+
+    def hybrid_forward(self, F, x, gate=None, gamma=None):
+        return F.RMSNorm(x, gamma, gate, eps=self._epsilon)
+
+
 class GroupNorm(HybridBlock):
     """reference basic_layers.py:630."""
 

@@ -25,3 +25,4 @@ from . import legacy     # noqa: F401
 from . import quantized  # noqa: F401
 from . import detection_extra  # noqa: F401
 from . import dgl_ops    # noqa: F401
+from . import ssm        # noqa: F401

@@ -78,12 +78,13 @@ def blockwise_attention(q, k, v, block_size: int = 512, causal: bool = False,
 
 
 @register("_contrib_flash_attention")
-def flash_attention_op(q, k, v, *, causal=False, block_size=512):
+def flash_attention_op(q, k, v, *, causal=False, block_size=512, scale=None):
     """Registered op form so the eager autograd tape records its VJP.
     Dispatches to the Pallas TPU kernel (ops/pallas/flash_attention.py)
-    when on TPU; the lax.scan blockwise path elsewhere."""
+    when on TPU; the lax.scan blockwise path elsewhere. `scale` multiplies
+    q k^T (None: 1/sqrt(d))."""
     from .pallas.flash_attention import flash_attention as _pallas_flash
-    return _pallas_flash(q, k, v, causal=causal,
+    return _pallas_flash(q, k, v, causal=causal, scale=scale,
                          block_q=min(block_size, 256), block_k=min(block_size, 256))
 
 

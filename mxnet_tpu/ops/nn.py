@@ -288,6 +288,18 @@ def layer_norm(data, gamma, beta, *, axis=-1, eps=1e-5):
     return out.astype(data.dtype)
 
 
+@register("RMSNorm")
+def rms_norm(data, gamma, gate=None, *, eps=1e-5):
+    """gamma * v * rsqrt(mean(v^2) + eps) over the last axis, in float32;
+    with `gate`, v = data * silu(gate) (the gated norm in front of a
+    Mamba-2 mixer's output projection). No reference counterpart."""
+    v = data.astype(jnp.float32)
+    if gate is not None:
+        v = v * jax.nn.silu(gate.astype(jnp.float32))
+    out = v * lax.rsqrt(jnp.mean(jnp.square(v), axis=-1, keepdims=True) + eps)
+    return (out * gamma.astype(jnp.float32)).astype(data.dtype)
+
+
 @register("GroupNorm")
 def group_norm(data, gamma, beta, *, num_groups=1, eps=1e-5):
     """reference src/operator/nn/group_norm.cc — (N, C, ...) grouped over C."""
