@@ -85,7 +85,7 @@ def flash_attention_op(q, k, v, *, causal=False, block_size=512, scale=None):
     q k^T (None: 1/sqrt(d))."""
     from .pallas.flash_attention import flash_attention as _pallas_flash
     return _pallas_flash(q, k, v, causal=causal, scale=scale,
-                         block_q=min(block_size, 256), block_k=min(block_size, 256))
+                         block_q=block_size, block_k=block_size)
 
 
 def ring_attention(q, k, v, axis_name: str, causal: bool = False,

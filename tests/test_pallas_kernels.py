@@ -115,6 +115,130 @@ def test_flash_bf16(interpret_mode):
 
 
 # ---------------------------------------------------------------------------
+# the schedule's branches: resident heads (several a grid step, more than one
+# grid step), loops that stop at the diagonal, padded lengths, T != Tk, and
+# the streamed route that long or wide sequences take
+# ---------------------------------------------------------------------------
+
+def _fa():
+    # the package re-exports the flash_attention FUNCTION under the module's
+    # name: load the module itself
+    import importlib
+    return importlib.import_module("mxnet_tpu.ops.pallas.flash_attention")
+
+
+def _plans(BH, T, Tk, D, dtype, causal, limit=512):
+    """The forward's and the backward's (q chunks, k chunks, heads a step)
+    as `flash_attention` would plan them: one chunk a side is the resident
+    route."""
+    fa = _fa()
+    bq, bk = fa._block(T, limit), fa._block(Tk, limit)
+    return [(n_qc, n_kc, G) for _, n_qc, _, n_kc, G, _ in (
+        fa._plan(backward, BH, T, Tk, D, jnp.dtype(dtype), causal, bq, bk)
+        for backward in (False, True))]
+
+
+# (B*H, T, Tk, D, causal, dtype, streamed, least grid steps of several heads)
+_SCHEDULE_CASES = [
+    pytest.param(8, 1024, 1024, 64, False, np.float32, False, 2,
+                 id="bert_cell_shape"),
+    pytest.param(4, 2048, 2048, 64, True, np.float32, False, 0,
+                 id="granite_cell_shape"),
+    pytest.param(8, 1024, 1024, 64, False, jnp.bfloat16, False, 2,
+                 id="bert_cell_shape_bf16"),
+    pytest.param(4, 2048, 2048, 64, True, jnp.bfloat16, False, 2,
+                 id="granite_cell_shape_bf16"),
+    pytest.param(2, 1000, 1000, 64, True, np.float32, False, 0,
+                 id="padded_causal"),
+    pytest.param(2, 300, 700, 32, False, np.float32, False, 0,
+                 id="cross_attention"),
+    pytest.param(2, 4096, 4096, 64, True, jnp.bfloat16, False, 0,
+                 id="resident_too_long_to_unroll"),
+    pytest.param(1, 2400, 2400, 128, True, np.float32, True, 0,
+                 id="streamed_causal"),
+    pytest.param(1, 640, 2400, 128, False, np.float32, True, 0,
+                 id="streamed_keys_cross"),
+]
+
+
+@pytest.mark.parametrize("BH,T,Tk,D,causal,dtype,streamed,min_steps",
+                         _SCHEDULE_CASES)
+def test_flash_schedule_branches(interpret_mode, BH, T, Tk, D, causal, dtype,
+                                 streamed, min_steps):
+    """Forward and backward of every branch of the schedule against the
+    dense softmax in float32; bfloat16 inputs at test_flash_bf16's
+    tolerance."""
+    (n_q, n_k, G), _ = _plans(BH, T, Tk, D, dtype, causal)
+    assert (n_q > 1 or n_k > 1) == streamed, (n_q, n_k)
+    if min_steps:
+        # several heads a forward step, and more than one grid step
+        assert G >= 2 and BH // G >= min_steps, (BH, G)
+    q, k, v = _rand_qkv(31 + T, B=1, H=BH, T=T, Tk=Tk, D=D)
+    rs = np.random.RandomState(T)
+    co = jnp.asarray(rs.normal(0, 1, q.shape).astype(np.float32))
+    qd, kd, vd = (x.astype(dtype) for x in (q, k, v))
+
+    def f_flash(q, k, v):
+        out = flash_attention(q, k, v, causal=causal)
+        return jnp.vdot(out.astype(jnp.float32), co), out
+
+    def f_naive(q, k, v):
+        out = naive_attention(q, k, v, causal=causal)
+        return jnp.vdot(out, co), out
+
+    g, out = jax.grad(f_flash, argnums=(0, 1, 2), has_aux=True)(qd, kd, vd)
+    g_ref, ref = jax.grad(f_naive, argnums=(0, 1, 2), has_aux=True)(q, k, v)
+    assert out.dtype == dtype
+    tol = dict(rtol=2e-4, atol=2e-4) if dtype == np.float32 \
+        else dict(rtol=0.05, atol=0.05)
+    np.testing.assert_allclose(np.asarray(out, dtype=np.float32),
+                               np.asarray(ref), **tol)
+    for name, a, b in zip("qkv", g, g_ref):
+        np.testing.assert_allclose(
+            np.asarray(a, dtype=np.float32), np.asarray(b),
+            err_msg=f"d{name}", **tol)
+
+
+def test_flash_residual_statistics_are_lane_dense(interpret_mode):
+    """What the forward hands the backward has T on its last axis: as a
+    column (BH, T, 1) every statistic would take a 128-lane tile in memory
+    (100 MB where 0.8 MB is needed at the t1024 cell's shapes)."""
+    fa = _fa()
+    q, k, v = (x.reshape(4, 384, 64) for x in _rand_qkv(9, T=384, D=64))
+    out, res = fa._flash_fwd(q, k, v, False, 0.125, 128, 128, True)
+    lse = res[-1]
+    assert lse.shape == (4, 1, 384) and lse.dtype == jnp.float32
+    s = jnp.einsum("bqd,bkd->bqk", q, k) * 0.125
+    np.testing.assert_allclose(np.asarray(lse[:, 0]),
+                               np.asarray(jax.nn.logsumexp(s, axis=-1)),
+                               rtol=1e-5, atol=1e-5)
+    # a padded length pads the statistics to whole blocks, still on lanes
+    _, res = fa._flash_fwd(q[:, :300], k, v, True, 0.125, 128, 128, True)
+    assert res[-1].shape == (4, 1, 384)
+
+
+def test_flash_plan_at_the_cells_shapes():
+    fa = _fa()
+    bf16 = jnp.bfloat16
+    # resident up to T = 4096 at d = 64 in bfloat16, streamed beyond; the
+    # cells: four heads a forward step and two a backward step (t1024), two
+    # and one under the causal mask at T = 2048 (granite)
+    assert _plans(192, 1024, 1024, 64, bf16, False) == [(1, 1, 4), (1, 1, 2)]
+    assert _plans(64, 2048, 2048, 64, bf16, True) == [(1, 1, 2), (1, 1, 1)]
+    assert _plans(16, 4096, 4096, 64, bf16, True)[1][:2] == (1, 1)
+    assert _plans(8, 8192, 8192, 64, bf16, True) == [(2, 2, 1), (2, 2, 1)]
+    for BH in (192, 64, 7, 1):
+        for flops in (1e6, 3e8, 1e10):
+            G = fa._heads_per_step(BH, 2 << 20, flops)
+            assert BH % G == 0 and G * (2 << 20) <= fa._VMEM_BLOCK_BYTES
+    assert fa._heads_per_step(192, 1 << 30, 1e6) == 1
+    # blocks: whole lane tiles under the limit, the cheapest walk of L
+    assert [fa._block(L, 512) for L in (100, 1000, 1024, 1100, 2400)] \
+        == [128, 512, 512, 384, 512]
+    assert fa._block(1000, 128) == 128 and fa._block(50, 64) == 128
+
+
+# ---------------------------------------------------------------------------
 # non-Pallas fallback gradient path (NO interpret fixture: on CPU
 # flash_attention routes to the blockwise lax.scan — the path every
 # CPU-trained model differentiates through)
@@ -217,11 +341,8 @@ def test_flash_dispatch_respects_exec_platform():
     jax.default_backend() (which says 'tpu' on a TPU machine even while
     compiling for CPU arrays — that crashed CPU deferred-init of models
     containing flash attention)."""
-    import importlib
     from mxnet_tpu.ops import registry
-    # the package __init__ re-exports the flash_attention FUNCTION under the
-    # same name — load the module itself
-    fa = importlib.import_module("mxnet_tpu.ops.pallas.flash_attention")
+    fa = _fa()
 
     class TracerLike:
         def devices(self):
@@ -258,11 +379,17 @@ def building_for_tpu():
     registry.exec_platform.reset(tok)
 
 
-@pytest.mark.parametrize("causal", [False, True])
-def test_flash_kernels_lower_for_tpu(building_for_tpu, causal):
-    # T=1000: not a multiple of the block, so the padded path lowers too
-    q, k, v = (x.astype(jnp.bfloat16)
-               for x in _rand_qkv(8, B=1, H=2, T=1000, D=64))
+# (B*H, T, causal): a T that is no multiple of the block (the padded path),
+# then the two cells that run the kernels at their own shapes
+@pytest.mark.parametrize("BH,T,causal", [
+    (2, 1000, False), (2, 1000, True),
+    pytest.param(192, 1024, False, id="bert_base_train_t1024"),
+    pytest.param(64, 2048, True, id="granite4_h_micro_train_t2048"),
+])
+def test_flash_kernels_lower_for_tpu(building_for_tpu, BH, T, causal):
+    rs = np.random.RandomState(8)
+    q, k, v = (jnp.asarray(rs.normal(0, 1, (1, BH, T, 64)), jnp.bfloat16)
+               for _ in range(3))
 
     def f(q, k, v):
         return flash_attention(q, k, v, causal=causal)
@@ -270,8 +397,44 @@ def test_flash_kernels_lower_for_tpu(building_for_tpu, causal):
     assert _tpu_module(f, q, k, v).count("tpu_custom_call") == 1
     grad = jax.grad(lambda *a: jnp.sum(f(*a).astype(jnp.float32)),
                     argnums=(0, 1, 2))
-    # forward (for the residuals) + dq + dk/dv
-    assert _tpu_module(grad, q, k, v).count("tpu_custom_call") == 3
+    # forward (for the residuals) + the one backward call
+    assert _tpu_module(grad, q, k, v).count("tpu_custom_call") == 2
+
+
+# What only the chip's compiler can say, said off the chip: Mosaic compiles
+# the kernels at the cells' shapes for a described v5e (no device attached).
+# An unaligned slice or a step that passes the VMEM limit fails here. The
+# topology is described inside the fixture, never at import (one process at
+# a time may load the TPU's library, and every xdist worker imports this file).
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("BH,T,causal", [
+    pytest.param(192, 1024, False, id="bert_base_train_t1024"),
+    pytest.param(64, 2048, True, id="granite4_h_micro_train_t2048"),
+])
+def test_flash_kernels_compile_for_v5e(building_for_tpu, one_chip, BH, T,
+                                       causal):
+    x = jax.ShapeDtypeStruct((1, BH, T, 64), jnp.bfloat16, sharding=one_chip)
+    grad = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=causal).astype(jnp.float32)), argnums=(0, 1, 2))
+    compiled = jax.jit(grad).lower(x, x, x).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= 2
+    # the statistics between the two calls: T on lanes, under 1 MB for all
+    # heads where the column layout took 100 MB
+    assert f"f32[{BH},1,{T}]" in text and f"f32[{BH},{T},1]" not in text
 
 
 def test_fused_optimizer_kernels_lower_for_tpu(monkeypatch):
