@@ -795,184 +795,6 @@ def bench_zero_dp(steps, warmup):
     }
 
 
-def bench_overlap(steps, warmup):
-    """A/B: the plain fused DP step vs backward-overlapped gradient
-    collectives (DataParallelTrainer(overlap_grads=True) — chunked-vjp
-    backward, per-bucket collectives issued as segments finalize) on the
-    ResNet-50 and BERT-base configs, each with zero_update off and on.
-    Reports per-variant step time, the step-time ratio, segment/bucket
-    counts, per-step collective wire bytes, and a trajectory-match
-    boolean per pairing (max relative per-step loss delta over
-    BENCH_OVERLAP_TRAJ_STEPS fresh steps against the unoverlapped
-    baseline).
-
-    CPU-host physics: one host core serializes compute and 'wire', so the
-    latency the overlap hides on chip does not exist here — expect a
-    ratio ~1.0 (the chunked backward adds no flops); the win this bench
-    can't show needs the async-collective XLA flags on a real mesh
-    (engine/xla_flags.py). The resnet50/zero-off pairing compares a
-    shard_map body (per-device BatchNorm tiles) against the replicated
-    jit (global-batch BN statistics) — a statistics-semantics gap, not
-    an overlap error (docs/data_parallel.md); when that cross-semantics
-    delta exceeds BENCH_OVERLAP_BN_TOL, the pairing instead checks the
-    overlapped trajectory against the UNOVERLAPPED local-BN reference
-    (the zero_on baseline) at the tight tolerance and reports the raw
-    delta as semantics_ref_max_rel_delta. BERT (LayerNorm) and the zero
-    pairings match tightly against their own baselines."""
-    import gc
-    import mxnet_tpu as mx
-    from mxnet_tpu import nd, gluon
-    from mxnet_tpu.parallel import DataParallelTrainer, make_mesh
-    from mxnet_tpu.parallel import overlap as overlap_mod
-    from mxnet_tpu.parallel import zero as zero_mod
-
-    ndp = int(os.environ.get("BENCH_OVERLAP_DP", 8))
-    mesh = make_mesh({"dp": ndp}, devices=_lane_devices(ndp))
-
-    image = int(os.environ.get("BENCH_OVERLAP_IMAGE", 32))
-    batch = int(os.environ.get("BENCH_OVERLAP_BATCH", 32))
-    seq = int(os.environ.get("BENCH_OVERLAP_SEQ", 32))
-    vocab = int(os.environ.get("BENCH_OVERLAP_VOCAB", 1000))
-    traj_steps = int(os.environ.get("BENCH_OVERLAP_TRAJ_STEPS", 10))
-
-    def resnet():
-        from mxnet_tpu.gluon.model_zoo.vision import resnet50_v1
-        # fresh per-call RandomState: the A/B sides must see IDENTICAL
-        # batches or the trajectory match is vacuous
-        rs = np.random.RandomState(0)
-        net = resnet50_v1()
-        with mx.cpu():
-            net.initialize(ctx=mx.cpu())
-            net(nd.zeros((1, 3, image, image), ctx=mx.cpu()))
-        x = nd.array(rs.uniform(-1, 1, (batch, 3, image, image))
-                     .astype(np.float32))
-        y = nd.array(rs.randint(0, 1000, (batch,)), dtype="int32")
-        return net, x, y
-
-    def bert():
-        # BERT-base layer shape, depth/width scaled by env so the CPU
-        # mesh finishes in bench time (BENCH_OVERLAP_FULL=1 for the real
-        # 12x768); dropout stays 0 (the models' default) so the paired
-        # trajectories see identical randomness
-        from mxnet_tpu.models.bert import BertModel, bert_base
-        rs = np.random.RandomState(0)
-        if os.environ.get("BENCH_OVERLAP_FULL") == "1":
-            net = bert_base(vocab_size=vocab)
-        else:
-            net = BertModel(
-                vocab, num_layers=int(os.environ.get("BENCH_OVERLAP_LAYERS",
-                                                     4)),
-                units=128, hidden_size=256, num_heads=4)
-        with mx.cpu():
-            net.initialize(ctx=mx.cpu())
-            net(nd.zeros((1, seq), ctx=mx.cpu(), dtype="int32"))
-        x = nd.array(rs.randint(0, vocab, (batch, seq)), dtype="int32")
-        y = nd.array(rs.randint(0, vocab, (batch, seq)), dtype="int32")
-        return net, x, y
-
-    def run(make_cfg, zero, overlap):
-        mx.random.seed(0)
-        net, x, y = make_cfg()
-        tr = DataParallelTrainer(
-            net, _loss_tokens, optimizer="sgd",
-            optimizer_params={
-                "learning_rate": float(os.environ.get("BENCH_OVERLAP_LR",
-                                                      0.005)),
-                "momentum": 0.9},
-            mesh=mesh, zero_update=zero, overlap_grads=overlap,
-            comm_dtype=os.environ.get("MXNET_TPU_COMM_DTYPE") or None)
-        # the trajectory run doubles as compile+warmup
-        traj = [float(v) for v in np.asarray(
-            tr.run_steps(x, y, traj_steps))]
-        float(tr.run_steps(x, y, max(warmup, 1))[-1])
-        best = float("inf")
-        for _ in range(2):
-            t0 = time.perf_counter()
-            tr.run_steps(x, y, steps)
-            best = min(best, time.perf_counter() - t0)
-        if zero:
-            buckets = tr._zero_plan
-            comm = {
-                "reduce_scatter": zero_mod.reduce_scatter_wire_bytes(
-                    buckets, ndp, tr._comm_dtype),
-                "all_gather": zero_mod.all_gather_wire_bytes(buckets,
-                                                             ndp),
-            }
-        elif overlap:
-            buckets = tr._overlap_buckets
-            comm = {"allreduce": overlap_mod.allreduce_wire_bytes(
-                buckets, ndp, tr._comm_dtype)}
-        else:
-            buckets = ()
-            comm = {"allreduce": tr._grad_allreduce_bytes()}
-        out = {
-            "step_ms": round(best / steps * 1e3, 3),
-            "collective_bytes_per_step": comm,
-            "buckets": len(buckets),
-            "segments": len(tr._overlap_plan) if overlap else 0,
-            "trajectory": [round(v, 6) for v in traj],
-        }
-        del tr, net, x, y
-        gc.collect()
-        return out
-
-    def pair(make_cfg, zero, tol, semantics_ref=None):
-        base = run(make_cfg, zero, overlap=False)
-        over = run(make_cfg, zero, overlap=True)
-        deltas = [abs(a - b) / max(abs(a), 1e-9)
-                  for a, b in zip(base["trajectory"],
-                                  over["trajectory"])]
-        out = {
-            "baseline": base,
-            "overlap": over,
-            "step_time_ratio": round(over["step_ms"]
-                                     / max(base["step_ms"], 1e-9), 3),
-            "traj_max_rel_delta": round(max(deltas), 6),
-            "trajectory_match": bool(max(deltas) <= tol),
-            "match_tol": tol,
-        }
-        if semantics_ref is not None and not out["trajectory_match"]:
-            # The plain zero_off baseline is a replicated jit with
-            # GLOBAL-batch BN statistics; the overlapped step (a shard_map
-            # body) sees per-device LOCAL batches, so under training the
-            # two trajectories diverge for BN models regardless of
-            # overlap. The apples-to-apples check is the overlapped
-            # trajectory against the UNOVERLAPPED shard_map reference —
-            # the zero_on baseline, which has the same local-BN
-            # statistics and no overlap machinery.
-            sdeltas = [abs(a - b) / max(abs(a), 1e-9)
-                       for a, b in zip(semantics_ref, over["trajectory"])]
-            out["semantics_ref_max_rel_delta"] = round(max(sdeltas), 6)
-            out["trajectory_match"] = bool(max(sdeltas) <= tight)
-        return out
-
-    tight = float(os.environ.get("BENCH_OVERLAP_TOL", 1e-3))
-    bn_tol = float(os.environ.get("BENCH_OVERLAP_BN_TOL", 0.05))
-    configs = {"bert_base": (bert, {"zero_off": tight, "zero_on": tight})}
-    if os.environ.get("BENCH_QUICK") != "1":
-        # zero_off compares local-BN shard_map vs global-BN jit: see
-        # docstring — statistics semantics, not overlap correctness
-        configs["resnet50"] = (resnet, {"zero_off": bn_tol,
-                                        "zero_on": tight})
-    extra = {"dp": ndp, "batch": batch, "image": image, "seq": seq,
-             "traj_steps": traj_steps}
-    for name, (cfg, tols) in configs.items():
-        on = pair(cfg, True, tols["zero_on"])
-        off = pair(cfg, False, tols["zero_off"],
-                   semantics_ref=on["baseline"]["trajectory"])
-        extra[name] = {"zero_off": off, "zero_on": on}
-    key = "bert_base"
-    return {
-        "metric": "overlap_step_time_ratio",
-        "value": extra[key]["zero_off"]["step_time_ratio"],
-        "unit": "overlapped/baseline",
-        "vs_baseline": 1.0 if all(
-            extra[n][z]["trajectory_match"]
-            for n, (_, tols) in configs.items() for z in tols) else 0.0,
-        "extra": extra,
-    }
-
-
 def bench_pipeline(steps, warmup):
     """A/B: GPipe (grad-of-scan transpose) vs the hand-scheduled 1F1B
     pipeline schedule (docs/pipeline_parallel.md) on BERT-base-shaped
@@ -2503,7 +2325,6 @@ def main():
         "async_feed": (bench_async_feed, _env_int("BENCH_FEED_DP", 4),
                        (40, 8)),
         "zero_dp": (bench_zero_dp, _env_int("BENCH_ZERO_DP", 8), (5, 2)),
-        "overlap": (bench_overlap, _env_int("BENCH_OVERLAP_DP", 8), (5, 2)),
         "pipeline": (bench_pipeline, _env_int("BENCH_PP", 4), (5, 2)),
         "elastic": (bench_elastic, _env_int("BENCH_ELASTIC_DP", 4), (40, 8)),
         "moe": (bench_moe,
@@ -2516,11 +2337,6 @@ def main():
     if scenario in mesh_lanes:
         fn, need, defaults = mesh_lanes[scenario]
         _virtual_cpu_devices(need)
-        if scenario == "overlap":
-            # the async-collective flags must land before the backend
-            # initializes — the window ensure_overlap_flags() is built for
-            from mxnet_tpu.engine import xla_flags as _xf
-            _xf.ensure_overlap_flags()
         engine.enable_compile_cache()
         # async_feed times on one device (only its parity arm uses the dp
         # mesh); every other lane times on the mesh it asked for

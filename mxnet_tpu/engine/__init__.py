@@ -257,8 +257,7 @@ _COST_KEYS = (("flops", "flops"), ("bytes accessed", "bytes_accessed"),
 
 
 def estimate_cost(jitted, *args, kind: str = "artifact",
-                  region: Optional[str] = None,
-                  overlap_expected: bool = False) -> Dict[str, float]:
+                  region: Optional[str] = None) -> Dict[str, float]:
     """XLA cost-model + memory estimate for a jitted callable at example
     args: ``{"flops", "bytes_accessed", "bytes_in", "bytes_out",
     "transcendentals", "peak_memory_bytes", "temp_memory_bytes"}`` (keys
@@ -284,7 +283,6 @@ def estimate_cost(jitted, *args, kind: str = "artifact",
                                   "donate_argnums", ()) or ())
             hlo_audit.audit_compiled(
                 compiled, kind=kind, region=region or kind,
-                overlap_expected=overlap_expected,
                 donation_expected=donate)
         except Exception:
             pass  # the audit must never fail a cost capture

@@ -10,9 +10,6 @@ HLO the compiler produced — hazards no source-level analysis can see:
   f64             f64 ops in a framework whose numerics are f32/bf16 —
                   almost always an accidental promotion (python float,
                   np.float64 constant) silently doubling bytes + flops
-  sync_collective collectives that failed to become async ``-start/-done``
-                  pairs when grad overlap is ON: the schedule serialized
-                  compute behind communication (arXiv:2301.13062 framing)
   no_alias        donation that produced zero input/output aliases — the
                   donated buffers were copied, not reused
 
@@ -39,7 +36,7 @@ from typing import Any, Dict, List, Optional
 __all__ = ["audit_text", "audit_compiled", "fingerprints", "audit_dir",
            "reset", "HAZARD_KINDS"]
 
-HAZARD_KINDS = ("host_transfer", "f64", "sync_collective", "no_alias")
+HAZARD_KINDS = ("host_transfer", "f64", "no_alias")
 
 # -- HLO text patterns -------------------------------------------------------
 # host boundary crossings: infeed/outfeed ops, is_host_transfer sends/recvs,
@@ -84,7 +81,7 @@ def audit_dir() -> Optional[str]:
 
 
 def audit_text(hlo_text: str, *, kind: str = "artifact",
-               region: str = "", overlap_expected: bool = False,
+               region: str = "",
                donation_expected: bool = False) -> Dict[str, Any]:
     """Scan one optimized-HLO module; return its hazard fingerprint.
     Pure text analysis — no jax import, no device."""
@@ -111,8 +108,6 @@ def audit_text(hlo_text: str, *, kind: str = "artifact",
         hazards.append({"kind": "host_transfer", "count": host})
     if f64:
         hazards.append({"kind": "f64", "count": f64})
-    if overlap_expected and sync and not async_:
-        hazards.append({"kind": "sync_collective", "count": sync})
     if donation_expected and donated and not alias:
         hazards.append({"kind": "no_alias", "count": donated})
 
@@ -137,7 +132,6 @@ def audit_text(hlo_text: str, *, kind: str = "artifact",
 
 
 def audit_compiled(compiled, *, kind: str = "artifact", region: str = "",
-                   overlap_expected: bool = False,
                    donation_expected: bool = False) -> Optional[Dict[str, Any]]:
     """Audit a jax ``Compiled`` object (post-optimization HLO), record the
     fingerprint (memory + telemetry + on-disk). Best-effort: backends that
@@ -150,7 +144,6 @@ def audit_compiled(compiled, *, kind: str = "artifact", region: str = "",
     if not text:
         return None
     fp = audit_text(text, kind=kind, region=region,
-                    overlap_expected=overlap_expected,
                     donation_expected=donation_expected)
     _record(fp)
     return fp
@@ -164,8 +157,7 @@ def _record(fp: Dict[str, Any]):
         c = _telem.counter(
             "mx_hlo_hazards_total",
             "Hazards the compiled-HLO audit found in built artifacts "
-            "(host transfers, f64 ops, unoverlapped collectives, "
-            "non-aliasing donation)", ("kind", "region"))
+            "(host transfers, f64 ops, non-aliasing donation)", ("kind", "region"))
         for h in fp["hazards"]:
             c.labels(h["kind"], fp["label"]).inc(h["count"])
     d = audit_dir()

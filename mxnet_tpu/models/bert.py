@@ -17,6 +17,7 @@ import math
 
 from ..gluon.block import HybridBlock
 from ..gluon import nn
+from ..ops import attention as _attn_ops
 
 __all__ = ["TransformerEncoderCell", "BertEncoder", "BertModel", "bert_base",
            "bert_large", "bert_tiny"]
@@ -97,18 +98,10 @@ class SelfAttention(HybridBlock):
                     F.reshape(proj(x), shape=(0, 0, -4, H, -1)),
                     axes=(0, 2, 1, 3))
                 for proj in (self.q_proj, self.k_proj, self.v_proj))
-        # Length-adaptive: at short T the O(T^2) scores tensor is cheap and
-        # the plain path is one XLA fusion; flash attention's tiling only
-        # pays once activation memory actually matters. The T=1024
-        # crossover predates the current installation and has not been
-        # re-measured on it (PERF.md, open questions); override it with
-        # MXNET_FLASH_ATTENTION_MIN_SEQ. Symbolic export (no concrete
-        # shape) always lowers the plain path.
-        import os as _os
-        min_t = int(_os.environ.get("MXNET_FLASH_ATTENTION_MIN_SEQ", 1024))
+        # Symbolic export (no concrete shape) always lowers the plain path.
         shape = getattr(x, "shape", None)
         if shape is not None and self._use_blockwise and mask is None \
-                and shape[1] >= min_t:
+                and _attn_ops.use_flash(shape[1]):
             # registered-op form: dispatches to the Pallas kernel on TPU and
             # records the VJP on the eager autograd tape (raw-array calls
             # would silently detach attention from loss.backward())

@@ -27,12 +27,11 @@ the backward one, which is what lets a model of this width train on one chip.
 """
 from __future__ import annotations
 
-import os
-
 import jax
 
 from ..gluon.block import HybridBlock
 from ..gluon import nn
+from ..ops import attention as _attn_ops
 
 __all__ = ["Mamba2Mixer", "GroupedQueryAttention", "SwiGLU",
            "HybridDecoderLayer", "HybridDecoder", "hybrid_decoder_tiny"]
@@ -98,8 +97,8 @@ class GroupedQueryAttention(HybridBlock):
     """Causal self-attention without positions: `num_heads` query heads over
     `num_kv_heads` key/value heads (each repeated for its queries, so the
     attention kernels see equal head counts), softmax scale `scale` as
-    stated (None: 1/sqrt(d)), no bias. Through the flash kernels from
-    T = MXNET_FLASH_ATTENTION_MIN_SEQ (1024) up, as models/bert.py."""
+    stated (None: 1/sqrt(d)), no bias. Through the flash kernels where
+    `ops.attention.use_flash(T)` says so, as models/bert.py."""
 
     def __init__(self, units, num_heads, num_kv_heads, scale=None, **kwargs):
         super().__init__(**kwargs)
@@ -120,8 +119,7 @@ class GroupedQueryAttention(HybridBlock):
         if rep > 1:
             k, v = F.repeat(k, repeats=rep, axis=1), \
                 F.repeat(v, repeats=rep, axis=1)
-        min_t = int(os.environ.get("MXNET_FLASH_ATTENTION_MIN_SEQ", 1024))
-        if x.shape[1] >= min_t:
+        if _attn_ops.use_flash(x.shape[1]):
             out = F._contrib_flash_attention(q, k, v, causal=True,
                                              scale=self._scale)
         else:

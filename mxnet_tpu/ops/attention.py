@@ -16,10 +16,30 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..base import env
 from .registry import register
+
+env.declare("MXNET_FLASH_ATTENTION_MIN_SEQ", 1024, int,
+            "Sequence length from which the models' attention goes through "
+            "_contrib_flash_attention instead of the plain scores-softmax "
+            "path (ops/attention.py: use_flash)")
 
 _NEG = -1e30  # finite mask: -inf makes exp(-inf - -inf) = nan on fully
               # masked (q-row, k-block) pairs under causal blocking
+
+
+def flash_min_seq() -> int:
+    """The crossover length: below it the O(T^2) scores tensor is cheap and
+    the plain path is a few XLA fusions; from it up the flash kernels'
+    tiling pays (PERF.md, Findings of PR 29, has the measurements)."""
+    return env.get("MXNET_FLASH_ATTENTION_MIN_SEQ")
+
+
+def use_flash(seq_len: int) -> bool:
+    """Whether attention over `seq_len` positions takes the flash kernels:
+    the one place the models (models/bert.py, models/hybrid_decoder.py,
+    parallel/megatron.py) ask."""
+    return seq_len >= flash_min_seq()
 
 
 def _block_attn(q, k, v, bias, scale):

@@ -15,8 +15,7 @@ jit so XLA can overlap the gather with the next forward.
 from __future__ import annotations
 
 import functools
-import warnings
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import Any, Callable, List, Optional, Tuple
 
 import jax
 from jax import lax
@@ -29,7 +28,6 @@ from ..ndarray import NDArray
 from .. import autograd
 from .. import engine as _engine
 from ..engine import async_feed as _feed
-from ..engine import xla_flags as _xla_flags
 from .. import random as _rng
 from .. import sanitize as _sanitize
 from .. import telemetry as _telem
@@ -37,7 +35,6 @@ from ..telemetry import tracing as _tracing
 from ..gluon.block import HybridBlock, _AUX_STACK
 from ..gluon.parameter import Parameter
 from .. import optimizer as opt_mod
-from . import overlap as _overlap
 from . import zero as _zero
 from .mesh import current_mesh, P
 from .step_program import StepProgram
@@ -306,7 +303,7 @@ class DataParallelTrainer:
                  optimizer_params=None, mesh: Optional[Mesh] = None,
                  batch_axis_name: str = "dp", dtype=None, data_spec=None,
                  compression=None, zero_update=None, bucket_bytes=None,
-                 comm_dtype=None, overlap_grads=None, overlap_segments=None):
+                 comm_dtype=None):
         self.net = net
         # Mixed precision: dtype="bfloat16" (or "float16") runs forward/backward
         # in low precision with fp32 master weights + fp32 optimizer math —
@@ -379,24 +376,10 @@ class DataParallelTrainer:
         self._zero = bool(zero_update)
         self._bucket_bytes = int(bucket_bytes if bucket_bytes is not None
                                  else env.get("MXNET_TPU_BUCKET_BYTES"))
-        # Backward-overlapped collectives (parallel/overlap.py): chunk the
-        # backward into vjp segments and issue each segment-aligned bucket's
-        # collective as the segment finalizes. Env-derived enablement
-        # degrades to the plain step on unsegmentable nets (warning);
-        # explicit overlap_grads=True raises instead.
-        overlap_env = overlap_grads is None
-        if overlap_grads is None:
-            overlap_grads = bool(env.get("MXNET_TPU_OVERLAP_GRADS"))
-        self._overlap = bool(overlap_grads)
-        self._overlap_segments = int(
-            overlap_segments if overlap_segments is not None
-            else env.get("MXNET_TPU_OVERLAP_SEGMENTS"))
-        self._overlap_plan = None
-        self._overlap_buckets = ()
         if comm_dtype is None:
             comm_dtype = env.get("MXNET_TPU_COMM_DTYPE") or None
         self._comm_dtype = _zero.canonical_comm_dtype(comm_dtype) \
-            if (self._zero or self._overlap) else None
+            if self._zero else None
 
         # shardings: params per their spec (default replicated)
         self._param_shardings = [
@@ -405,34 +388,6 @@ class DataParallelTrainer:
         self._params_raw = [self._place_param(w, s)
                             for w, s in zip(self._params_raw,
                                             self._param_shardings)]
-        # resolve the overlap segmentation BEFORE any bucket planning: the
-        # zero plan must align to segment boundaries so every bucket's
-        # collective becomes issuable the moment one segment finalizes
-        if self._overlap:
-            try:
-                self._validate_overlap(compression)
-                self._overlap_plan = _overlap.plan_segments(
-                    self.net, self._plist, self._overlap_segments)
-                owning = sum(1 for s in self._overlap_plan.segments
-                             if s.owned)
-                if owning < 2:
-                    raise MXNetError(
-                        "overlap_grads needs >= 2 backward segments that "
-                        f"own parameters, got {owning}; nothing to overlap")
-            except MXNetError as e:
-                if not overlap_env:
-                    raise
-                warnings.warn(
-                    f"MXNET_TPU_OVERLAP_GRADS: falling back to the plain "
-                    f"fused step ({e})", UserWarning, stacklevel=2)
-                self._overlap = False
-                self._overlap_plan = None
-                if not self._zero:
-                    self._comm_dtype = None
-        if self._overlap:
-            # async-collective / latency-hiding scheduler flags; a no-op
-            # plus one per-process warning when the backend beat us to init
-            _xla_flags.ensure_overlap_flags()
         # Optimizer state is created from the PLACED master weights, so each
         # leaf is born with its final placement (zeros_like inherits the
         # NamedSharding) — single-process included: the step jit requires
@@ -450,16 +405,6 @@ class DataParallelTrainer:
             self._opt_state = [self._init_fn(w) if t else ()
                                for w, t in zip(self._params_raw,
                                                self._trainable)]
-            if self._overlap:
-                # non-zero overlap: segment-aligned fusion buckets carry the
-                # per-bucket all-reduces (state stays replicated per param)
-                entries = [(i, w.shape, w.dtype)
-                           for i, (w, t) in enumerate(zip(self._params_raw,
-                                                          self._trainable))
-                           if t and jnp.issubdtype(w.dtype, jnp.floating)]
-                self._overlap_buckets = _zero.plan_buckets(
-                    entries, self._dp_degree, self._bucket_bytes,
-                    boundaries=self._overlap_plan.boundaries)
 
         # 2-bit gradient compression with per-device error feedback
         # (reference src/kvstore/gradient_compression.cc:60). Each device
@@ -546,11 +491,8 @@ class DataParallelTrainer:
                 compression=tuple(sorted(self._compression.items()))
                 if self._compression else None,
                 zero=self._zero,
-                bucket_bytes=self._bucket_bytes
-                if (self._zero or self._overlap) else None,
-                comm_dtype=self._comm_dtype,
-                overlap=self._overlap_plan.fingerprint
-                if self._overlap else None))
+                bucket_bytes=self._bucket_bytes if self._zero else None,
+                comm_dtype=self._comm_dtype))
         # executables, cost captures and roofline regions live in the
         # PROCESS-WIDE engine cache behind this program (parallel/
         # step_program.py) — same-config trainers share compiles
@@ -588,30 +530,6 @@ class DataParallelTrainer:
                 "trust-ratio norms do not decompose over flat bucket "
                 "shards; use sgd/adam/adamw/...")
 
-    def _validate_overlap(self, compression):
-        """overlap_grads preconditions: the chunked-vjp backward with
-        per-bucket collectives is only defined for pure data parallelism
-        with dense gradients (zero_update's scope); 2-bit compression's
-        per-parameter error-feedback carry has no segmented form."""
-        if compression:
-            raise MXNetError(
-                "overlap_grads is incompatible with 2-bit gradient "
-                "compression; use comm_dtype='bfloat16'/'int8' for a "
-                "compressed overlapped wire instead")
-        bad = [p.name for p, s in zip(self._plist, self._param_shardings)
-               if any(ax is not None for ax in s.spec)]
-        if bad or tuple(self.data_spec) != (self.batch_axis,):
-            raise MXNetError(
-                "overlap_grads requires pure data parallelism (replicated "
-                "parameters, data sharded over the batch axis only); "
-                f"offending params={bad[:3]} data_spec={self.data_spec}")
-        sparse = [p.name for p, lz in zip(self._plist, self._lazy) if lz]
-        if sparse:
-            raise MXNetError(
-                "overlap_grads is incompatible with row_sparse lazy-update "
-                f"parameters ({sparse[:3]}): absent rows have no meaning "
-                "inside a flattened bucket")
-
     def _init_zero_state(self):
         """Plan fusion buckets over the trainable master weights and create
         the optimizer state SHARDED: every bucket-state leaf lives under a
@@ -627,9 +545,7 @@ class DataParallelTrainer:
                                                   self._trainable))
                    if t and jnp.issubdtype(w.dtype, jnp.floating)]
         self._zero_plan = _zero.plan_buckets(
-            entries, self._dp_degree, self._bucket_bytes,
-            boundaries=self._overlap_plan.boundaries
-            if self._overlap else None)
+            entries, self._dp_degree, self._bucket_bytes)
         in_bucket = frozenset(i for b in self._zero_plan for i in b.indices)
         carry = []
         for b in self._zero_plan:
@@ -732,37 +648,6 @@ class DataParallelTrainer:
         _telem.record_comm("all_gather", self._ag_bytes * steps,
                            store="mesh", calls=steps * nb, axis="dp")
 
-    def _record_overlap_telemetry(self, steps):
-        """Overlap-mode collective accounting: the per-bucket collectives
-        issued inside the backward book with the overlap='1' label —
-        reduce-scatter of the gradient buckets under zero_update, the
-        per-bucket all-reduce otherwise. Zero's all-gather of the updated
-        shards runs at the tail, after the backward is gone, so it stays
-        unoverlapped; the mx_comm_overlap_ratio gauge reports the split."""
-        if self._rs_bytes is None:
-            if self._zero:
-                self._rs_bytes = _zero.reduce_scatter_wire_bytes(
-                    self._zero_plan, self._dp_degree, self._comm_dtype)
-                self._ag_bytes = _zero.all_gather_wire_bytes(
-                    self._zero_plan, self._dp_degree)
-            else:
-                self._rs_bytes = _overlap.allreduce_wire_bytes(
-                    self._overlap_buckets, self._dp_degree,
-                    self._comm_dtype)
-                self._ag_bytes = 0
-        if self._zero:
-            nb = len(self._zero_plan)
-            _telem.record_comm("reduce_scatter", self._rs_bytes * steps,
-                               store="mesh", calls=steps * nb,
-                               overlapped=True, axis="dp")
-            _telem.record_comm("all_gather", self._ag_bytes * steps,
-                               store="mesh", calls=steps * nb, axis="dp")
-        else:
-            nb = len(self._overlap_buckets)
-            _telem.record_comm("allreduce", self._rs_bytes * steps,
-                               store="mesh", calls=steps * nb,
-                               overlapped=True, axis="dp")
-
     def _opt_state_replica_bytes(self) -> int:
         if self._opt_bytes is None:
             tree = self._opt_state
@@ -788,9 +673,7 @@ class DataParallelTrainer:
         cost = self._program.cost(cost_key)
         flops = cost.get("flops")
         if self._dp_degree > 1:
-            if self._overlap:
-                self._record_overlap_telemetry(steps)
-            elif self._zero:
+            if self._zero:
                 self._record_zero_telemetry(steps)
             else:
                 _telem.record_comm("allreduce",
@@ -1108,231 +991,10 @@ class DataParallelTrainer:
             in_specs=(rep, (P(ax), rep), rep, dp, dp, rep, rep, rep),
             out_specs=(rep, (P(ax), rep), rep, rep, rep))
 
-    def _build_step_overlap(self):
-        """Fused step with backward-overlapped gradient collectives
-        (parallel/overlap.py): the forward runs as K chained ``jax.vjp``
-        segments (the per-cell vjp machinery the 1F1B pipeline schedule
-        proved out, applied along one replica's depth), the backward
-        replays the pullbacks newest-first, and each segment-aligned fusion
-        bucket's collective — reduce-scatter under zero_update, all-reduce
-        otherwise, either comm dtype — issues the moment its owning
-        segment's pullback finalizes, while the older segments' backward
-        dots are still ahead of the scheduler (async-collective XLA flags:
-        engine/xla_flags.py). Updates, and zero's gather-back, run at the
-        tail gated on the fp16 finite flag like the other bodies. Same
-        call/return contract as _build_step / _build_step_zero."""
-        plan = self._overlap_plan
-        plist = self._plist
-        update_fn = self._update_fn
-        loss_raw = self._loss_raw
-        wds = self._wds
-        trainable = self._trainable
-        mesh = self.mesh
-        ax = self.batch_axis
-        ndp = self._dp_degree
-        zero = self._zero
-        comm = self._comm_dtype
-        cdt = self.compute_dtype
-        scaled = self._scaler is not None
-        buckets = self._zero_plan if zero else self._overlap_buckets
-        in_bucket = frozenset(i for b in buckets for i in b.indices)
-        seg_of = plan.segment_of_slot
-        buckets_by_seg: Dict[int, List[int]] = {}
-        for bi, b in enumerate(buckets):
-            owners = {seg_of[i] for i in b.indices}
-            if len(owners) != 1:  # plan_buckets boundaries guarantee this
-                raise MXNetError(
-                    f"bucket {bi} spans segments {sorted(owners)}")
-            buckets_by_seg.setdefault(owners.pop(), []).append(bi)
-
-        # one pure apply per chain block; BN aux concatenates in forward
-        # order, preserving the unsegmented builders' aux contract
-        aux_orders: List[List[Parameter]] = []
-        seg_applies = []
-        for seg in plan.segments:
-            apps = []
-            for blk, uses in zip(seg.blocks, seg.block_uses):
-                order: List[Parameter] = []
-                aux_orders.append(order)
-                sub = [plist[i] for i in uses]
-                pos_in_seg = [seg.uses.index(i) for i in uses]
-                apps.append((_make_apply_fn(blk, sub, train=True,
-                                            aux_order_out=order),
-                             pos_in_seg))
-            seg_applies.append(apps)
-
-        def _low(a):
-            if cdt is not None and jnp.issubdtype(a.dtype, jnp.floating):
-                return a.astype(cdt)
-            return a
-
-        def body(params, opt_state, key, x, y, lr, t, loss_scale):
-            # x/y are the device-local batch tiles; params replicated
-            if zero:
-                bucket_carry, extra_state = opt_state
-            pos = lax.axis_index(ax)
-            kk = jax.random.wrap_key_data(key.astype(jnp.uint32),
-                                          impl="threefry2x32")
-            key_local = jax.random.key_data(jax.random.fold_in(kk, pos))
-
-            def run_segment(s, seg_params, h):
-                seg_aux = []
-                for apply_b, idxs in seg_applies[s]:
-                    out, aux_b = apply_b(
-                        key_local, [_low(seg_params[j]) for j in idxs], h)
-                    h = out[0] if isinstance(out, tuple) else out
-                    seg_aux.extend(aux_b)
-                return h, seg_aux
-
-            # forward: one vjp per segment, pullbacks saved — the chunked
-            # analog of value_and_grad's single backward
-            pulls = []
-            aux: List[Any] = []
-            h = _low(x)
-            for s, seg in enumerate(plan.segments):
-                seg_params = [params[i] for i in seg.uses]
-                if s == 0:  # close over the batch: no d/dx at the stem
-                    h, pull, aux_s = jax.vjp(
-                        functools.partial(run_segment, s, h=h),
-                        seg_params, has_aux=True)
-                else:
-                    h, pull, aux_s = jax.vjp(
-                        functools.partial(run_segment, s),
-                        seg_params, h, has_aux=True)
-                pulls.append(pull)
-                aux.extend(aux_s)
-
-            pred = h[0] if isinstance(h, tuple) else h
-            lossv = loss_raw(pred, y)  # mean over the LOCAL batch
-            _, loss_pull = jax.vjp(
-                lambda hh: loss_raw(hh, y) * loss_scale, pred)
-
-            # backward: replay pullbacks newest-first; a segment's owned
-            # buckets reduce IMMEDIATELY, before older segments' dots
-            inv = 1.0 / loss_scale
-            grads: List[Any] = [None] * len(params)
-            reduced: Dict[int, Any] = {}
-            fin = jnp.bool_(True)
-            (cot,) = loss_pull(jnp.ones_like(lossv))
-            for s in range(len(plan.segments) - 1, -1, -1):
-                seg = plan.segments[s]
-                if s == 0:
-                    (gseg,) = pulls[s](cot)
-                else:
-                    gseg, cot = pulls[s](cot)
-                for j, i in enumerate(seg.uses):
-                    g = gseg[j]
-                    if scaled and jnp.issubdtype(g.dtype, jnp.floating):
-                        g = g * inv
-                    # a parameter shared across segments accumulates; its
-                    # grad finalizes at its EARLIEST user (= owner)
-                    grads[i] = g if grads[i] is None else grads[i] + g
-                if scaled:
-                    for i in seg.owned:
-                        g = grads[i]
-                        if trainable[i] and \
-                                jnp.issubdtype(g.dtype, jnp.floating):
-                            fin = jnp.logical_and(fin, jnp.all(
-                                jnp.isfinite(g.astype(jnp.float32))))
-                for bi in buckets_by_seg.get(s, ()):
-                    flat_g = _zero.flatten_bucket(buckets[bi], grads)
-                    if zero:
-                        reduced[bi] = _zero.reduce_scatter_bucket(
-                            flat_g, ax, ndp, comm)
-                    else:
-                        reduced[bi] = _overlap.allreduce_bucket(
-                            flat_g, ax, ndp, comm)
-            if scaled:
-                finite = lax.pmin(fin.astype(jnp.int32), ax) \
-                    .astype(jnp.bool_)
-            else:
-                finite = jnp.bool_(True)
-
-            def _gate(new, old):
-                # fp16 overflow step: keep the old buffer contents
-                return jnp.where(finite, new, old) if scaled else new
-
-            if zero:
-                new_params = list(params)
-                new_extra = list(extra_state)
-                # trainables outside every bucket (non-float dtypes):
-                # replicated update on the pmean'd gradient
-                for i, (w, st) in enumerate(zip(params, extra_state)):
-                    if not trainable[i] or i in in_bucket:
-                        continue
-                    gg = lax.pmean(grads[i], ax)
-                    w2, s2 = update_fn(gg, w, st, t, lr,
-                                       jnp.float32(wds[i]))
-                    new_params[i] = _gate(w2.astype(w.dtype), w)
-                    new_extra[i] = jax.tree_util.tree_map(_gate, s2, st) \
-                        if scaled else s2
-                new_carry = []
-                for bi, (b, (wd_vec, st)) in enumerate(zip(buckets,
-                                                           bucket_carry)):
-                    g_shard = reduced[bi] / ndp
-                    w_shard = _zero.shard_slice(
-                        b, _zero.flatten_bucket(b, params), pos)
-                    w2, s2 = update_fn(g_shard.astype(w_shard.dtype),
-                                       w_shard, st, t, lr, wd_vec)
-                    w2 = _gate(w2.astype(w_shard.dtype), w_shard)
-                    s2 = jax.tree_util.tree_map(_gate, s2, st) \
-                        if scaled else s2
-                    full = _zero.all_gather_bucket(w2, ax)
-                    for i, arr in _zero.unflatten_bucket(b, full):
-                        new_params[i] = arr.astype(params[i].dtype)
-                    new_carry.append((wd_vec, s2))
-                new_state = (tuple(new_carry), tuple(new_extra))
-            else:
-                # unflatten the bucket-mean grads, then per-parameter
-                # updates exactly as the plain step (per-tensor trust
-                # ratios stay intact — buckets only carried the collective)
-                gg_of: Dict[int, Any] = {}
-                for bi, b in enumerate(buckets):
-                    for i, arr in _zero.unflatten_bucket(
-                            b, reduced[bi] / ndp):
-                        gg_of[i] = arr
-                new_params, new_state = [], []
-                for i, (w, st) in enumerate(zip(params, opt_state)):
-                    if not trainable[i]:
-                        new_params.append(w)
-                        new_state.append(st)
-                        continue
-                    gg = gg_of.get(i)
-                    if gg is None:
-                        gg = lax.pmean(grads[i], ax)
-                    w2, s2 = update_fn(gg, w, st, t, lr,
-                                       jnp.float32(wds[i]))
-                    new_params.append(_gate(w2.astype(w.dtype), w))
-                    new_state.append(
-                        jax.tree_util.tree_map(_gate, s2, st)
-                        if scaled else s2)
-            glob_loss = lax.pmean(lossv, ax)
-            aux = jax.tree_util.tree_map(
-                lambda v: lax.pmean(v, ax)
-                if jnp.issubdtype(v.dtype, jnp.floating) else v, aux)
-            # cross-device-averaged BN running stats flow through the carry
-            idx_of = {id(p): i for i, p in enumerate(plist)}
-            aux_params = [p for order in aux_orders for p in order]
-            for p, v in zip(aux_params, aux):
-                j = idx_of.get(id(p))
-                if j is not None and not trainable[j]:
-                    new_params[j] = v.astype(new_params[j].dtype)
-            return new_params, new_state, glob_loss, finite, aux
-
-        dp = P(ax)
-        rep = P()
-        state_spec = (P(ax), rep) if zero else rep
-        return _zero.shard_map_compat(
-            body, mesh=mesh,
-            in_specs=(rep, state_spec, rep, dp, dp, rep, rep, rep),
-            out_specs=(rep, state_spec, rep, rep, rep))
-
     def _build_any_step(self):
         """Pick the step body for this trainer's configuration."""
         if self._compression:
             return self._build_step_compressed()
-        if self._overlap:
-            return self._build_step_overlap()
         if self._zero:
             return self._build_step_zero()
         return self._build_step(None, None)
@@ -1477,7 +1139,7 @@ class DataParallelTrainer:
                 self._program.capture_cost(
                     cost_key, fn, self._params_raw, self._opt_state,
                     self._comp_resid, key_in, xr, yr, lr_in, t_in, scale_in,
-                    kind="dp_multi", overlap_expected=self._overlap)
+                    kind="dp_multi")
             with rec.phase("launch"), _sanitize.guard():
                 (self._params_raw, self._opt_state, self._comp_resid, losses,
                  finite, key_out, t_out) = fn(
@@ -1555,8 +1217,8 @@ class DataParallelTrainer:
                 # cost_analysis FLOPs of the fused step, captured once per
                 # signature at artifact-build time (AOT lower shares XLA
                 # caches)
-                self._program.capture_cost(sig, fn, *call_args, kind="dp_step",
-                                           overlap_expected=self._overlap)
+                self._program.capture_cost(sig, fn, *call_args,
+                                           kind="dp_step")
             with rec.phase("launch"), _sanitize.guard():
                 if self._compression:
                     (self._params_raw, self._opt_state, self._comp_resid,

@@ -63,7 +63,6 @@ from __future__ import annotations
 
 import functools
 import math
-import os
 from dataclasses import dataclass
 from typing import List, Optional, Tuple
 
@@ -72,6 +71,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ..base import MXNetError
+from ..ops import attention as _attn_ops
 from ..ops import nn as _ops
 from .mesh import axis_size as _axis_size
 
@@ -618,13 +618,10 @@ def _attention(plan: CellPlan, cfg: PartitionConfig, x, leaves, key,
                              jax.nn.softmax(s, axis=-1),
                              v.astype(jnp.float32)).astype(q.dtype)
         else:
-            from ..ops.attention import flash_attention_op
-            out = flash_attention_op(q, k, v, causal=True)
+            out = _attn_ops.flash_attention_op(q, k, v, causal=True)
     else:
-        min_t = int(os.environ.get("MXNET_FLASH_ATTENTION_MIN_SEQ", 1024))
-        if plan.use_blockwise and T >= min_t:
-            from ..ops.attention import flash_attention_op
-            out = flash_attention_op(q, k, v, causal=False)
+        if plan.use_blockwise and _attn_ops.use_flash(T):
+            out = _attn_ops.flash_attention_op(q, k, v, causal=False)
         else:
             q2 = q.reshape(B * h_local, T, d)
             k2 = k.reshape(B * h_local, T, d)

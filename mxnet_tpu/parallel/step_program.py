@@ -84,19 +84,15 @@ class StepProgram:
         return name
 
     # -- cost capture -------------------------------------------------------
-    def capture_cost(self, cost_key, fn, *args, kind: str = "artifact",
-                     overlap_expected: bool = False):
+    def capture_cost(self, cost_key, fn, *args, kind: str = "artifact"):
         """XLA cost_analysis/memory_analysis of ``fn`` at ``args``, captured
         ONCE per cost_key and only while telemetry is enabled (the AOT
         lower+compile shares XLA's compilation caches with the real call).
         The same compile feeds the HLO hazard audit, fingerprinted under
-        this program's ledger region (engine/hlo_audit.py);
-        ``overlap_expected`` marks artifacts whose collectives are supposed
-        to compile to async start/done pairs (overlap_grads on)."""
+        this program's ledger region (engine/hlo_audit.py)."""
         if _telem._ENABLED and cost_key not in self._costs:
             self._costs[cost_key] = _engine.estimate_cost(
-                fn, *args, kind=kind, region=self.region(cost_key),
-                overlap_expected=overlap_expected)
+                fn, *args, kind=kind, region=self.region(cost_key))
         return self._costs.get(cost_key, {})
 
     def cost(self, cost_key) -> Dict[str, float]:

@@ -25,7 +25,6 @@ kvstore's bucketed ``pushpull`` share:
 """
 from __future__ import annotations
 
-import bisect
 import functools
 from dataclasses import dataclass
 from typing import Any, List, Optional, Sequence, Tuple
@@ -105,25 +104,12 @@ class BucketSpec:
 
 
 def plan_buckets(entries: Sequence[Tuple[int, Sequence[int], Any]],
-                 ndp: int, bucket_bytes: int,
-                 boundaries: Optional[Sequence[int]] = None
-                 ) -> Tuple[BucketSpec, ...]:
+                 ndp: int, bucket_bytes: int) -> Tuple[BucketSpec, ...]:
     """Pack ``(slot_index, shape, dtype)`` entries into dtype-homogeneous
     buckets, greedily in order, size-capped at ``bucket_bytes`` (a tensor
     larger than the cap gets a bucket of its own). Every bucket is padded to
-    a multiple of ``ndp`` elements.
-
-    ``boundaries`` is an optional increasing sequence of slot indices at
-    which a bucket must close: no bucket packs two entries that fall on
-    opposite sides of a boundary (entry ``i`` belongs to side
-    ``bisect_right(boundaries, i)``). The backward-overlap path
-    (parallel/overlap.py) aligns buckets to its vjp segments this way, so
-    every bucket's collective can be issued the moment one segment's
-    backward finalizes. ``boundaries=None`` (or empty) produces plans
-    byte-identical to the unhinted planner — the kvstore's bucketed
-    ``pushpull`` relies on that."""
+    a multiple of ``ndp`` elements."""
     ndp = max(int(ndp), 1)
-    bounds = tuple(sorted(int(b) for b in boundaries)) if boundaries else ()
     groups: List[Tuple[str, List[Tuple[int, Tuple[int, ...], int]]]] = []
     by_dtype = {}
     for idx, shape, dtype in entries:
@@ -157,15 +143,13 @@ def plan_buckets(entries: Sequence[Tuple[int, Sequence[int], Any]],
 
     for dtype, members in groups:
         cap = max(int(bucket_bytes) // jnp.dtype(dtype).itemsize, 1)
-        cur, total, side = [], 0, None
+        cur, total = [], 0
         for idx, shape, size in members:
-            s = bisect.bisect_right(bounds, idx) if bounds else 0
-            if cur and (total + size > cap or s != side):
+            if cur and total + size > cap:
                 close(dtype, cur)
                 cur, total = [], 0
             cur.append((idx, shape, size))
             total += size
-            side = s
         close(dtype, cur)
     return tuple(buckets)
 

@@ -288,8 +288,7 @@ class LongContextTrainer(DataParallelTrainer):
                          optimizer_params=optimizer_params, mesh=mesh,
                          batch_axis_name=dp_axis, dtype="float32",
                          data_spec=P(dp_axis, sp_axis), zero_update=True,
-                         bucket_bytes=bucket_bytes, comm_dtype=comm_dtype,
-                         overlap_grads=False)
+                         bucket_bytes=bucket_bytes, comm_dtype=comm_dtype)
         self._step_key_base = self._step_key_base + (
             ("long_context", sp_axis, self._sp_degree),)
         self._program = StepProgram(

@@ -114,8 +114,7 @@ class MoETrainer(DataParallelTrainer):
                          optimizer_params=optimizer_params, mesh=mesh,
                          batch_axis_name=dp_axis, dtype="float32",
                          data_spec=P((dp_axis, ep_axis)), zero_update=True,
-                         bucket_bytes=bucket_bytes, comm_dtype=comm_dtype,
-                         overlap_grads=False)
+                         bucket_bytes=bucket_bytes, comm_dtype=comm_dtype)
         # MoE-specific compile-key terms: ep layout, aux weight, wire dtype
         # (the a2a exchanges ride the same canonicalized _comm_dtype the
         # base constructor resolved for the zero collectives)

@@ -739,7 +739,7 @@ def payload_bytes(x) -> int:
 
 def record_comm(op: str, nbytes: int, store: str = "",
                 seconds: Optional[float] = None, calls: int = 1,
-                overlapped: bool = False, axis: str = ""):
+                axis: str = ""):
     """Account one collective/comm operation (bytes moved, calls, time).
 
     `op` labels the collective kind — "allreduce", "reduce_scatter",
@@ -748,23 +748,17 @@ def record_comm(op: str, nbytes: int, store: str = "",
     TP path's "tp_act_psum"/"tp_act_all_gather"/"tp_act_psum_scatter",
     kvstore "push"/"pull" — so per-kind wire accounting survives
     aggregation (the check_instrumentation gate pins the trainer paths
-    that must book here). `overlapped` marks traffic issued while backward
-    compute was still pending (the chunked-vjp schedule,
-    parallel/overlap.py); it becomes the "overlap" label and feeds the
-    mx_comm_overlap_ratio gauge. `axis` names the MESH axis the collective
-    crosses ("dp"/"tp"/"sp"/"pp"/"ep") so the ratio and byte totals split
-    per parallelism lane — the signal that distinguishes "the dp grad
-    allreduce overlaps fine" from "the tp weight gather is the
-    unoverlapped remainder". Family.get(op, store) aggregates over the
-    trailing labels, so two-label readers see totals unchanged. On a
-    multi-process job the process rank rides as a trailing "host" label
-    (same prefix-aggregation contract; comm_overlap_ratio and
-    comm_axis_bytes index lv[2]/lv[3] positionally and are unaffected)."""
-    ov = "1" if overlapped else "0"
+    that must book here). `axis` names the MESH axis the collective
+    crosses ("dp"/"tp"/"sp"/"pp"/"ep") so the byte totals split per
+    parallelism lane — the signal that distinguishes "the dp grad
+    allreduce" from "the tp weight gather". Family.get(op, store)
+    aggregates over the trailing labels, so two-label readers see totals
+    unchanged. On a multi-process job the process rank rides as a trailing
+    "host" label (same prefix-aggregation contract; comm_axis_bytes and
+    the goodput ledger find "axis" by name and are unaffected)."""
     h = _host_label()
-    names = ("op", "store", "overlap", "axis", "host") if h \
-        else ("op", "store", "overlap", "axis")
-    vals = (op, store, ov, axis, h) if h else (op, store, ov, axis)
+    names = ("op", "store", "axis", "host") if h else ("op", "store", "axis")
+    vals = (op, store, axis, h) if h else (op, store, axis)
     counter("mx_comm_bytes_total", "Bytes moved by comm/collective ops",
             names).labels(*vals).inc(max(int(nbytes), 0))
     counter("mx_comm_calls_total", "Comm/collective operations",
@@ -774,65 +768,26 @@ def record_comm(op: str, nbytes: int, store: str = "",
                 names).labels(*vals).inc(seconds)
 
 
-# gradient/weight-collective kinds eligible for backward overlap — the
-# ratio denominator (kvstore push/pull and the pipeline's ppermute hops
-# have no "issue during backward" notion and would only dilute the
-# signal). The weight-sharded TP gather and the compute-partitioned
-# activation collectives count: both are per-step wire traffic a schedule
-# could in principle hide, and their per-axis remainder is the
-# weight-sharded-vs-partitioned acceptance signal.
-_OVERLAP_OPS = frozenset({
-    "allreduce", "reduce_scatter", "all_gather", "tp_weight_all_gather",
-    "tp_act_psum", "tp_act_all_gather", "tp_act_psum_scatter"})
-
-
-def comm_overlap_ratio(axis: Optional[str] = None) -> float:
-    """Fraction of gradient-collective wire traffic issued overlapped with
-    backward compute. Byte-weighted over mx_comm_bytes_total's
-    _OVERLAP_OPS series; since estimated collective seconds are
-    bytes / peak_bytes_per_second() (the roofline interval accounting's
-    conversion), the same number reads as the estimated-collective-time
-    overlap fraction. `axis` restricts the accounting to one mesh axis's
-    lane ("dp"/"tp"/"sp"/...): comm_overlap_ratio(axis="tp") == 0 with a
-    zero byte total means the tp lane moved nothing unoverlapped — how the
-    partitioned-TP tests assert the full-weight gather is gone. 0.0 when
-    nothing has been recorded."""
+def comm_bytes_by_axis() -> Dict[str, float]:
+    """mx_comm_bytes_total summed per mesh axis ("" for traffic booked
+    without one), the "axis" label found by name."""
     fam = get_metric("mx_comm_bytes_total")
-    if fam is None:
-        return 0.0
+    if fam is None or "axis" not in fam.labelnames:
+        return {}
+    at = fam.labelnames.index("axis")
     with _LOCK:
         series = list(fam._series.items())
-    total = overlapped = 0.0
+    out: Dict[str, float] = {}
     for lv, s in series:
-        if not lv or lv[0] not in _OVERLAP_OPS:
-            continue
-        if axis is not None and (len(lv) < 4 or lv[3] != axis):
-            continue
-        v = getattr(s, "value", 0.0)
-        total += v
-        if len(lv) > 2 and lv[2] == "1":
-            overlapped += v
-    return overlapped / total if total else 0.0
+        out[lv[at]] = out.get(lv[at], 0.0) + getattr(s, "value", 0.0)
+    return out
 
 
-def comm_axis_bytes(axis: str, overlapped: Optional[bool] = None) -> float:
-    """Total mx_comm_bytes_total booked on one mesh axis's lane, optionally
-    filtered to (non-)overlapped traffic. The partitioned-TP acceptance
-    check reads comm_axis_bytes("tp") A/B between the weight-sharded and
-    partitioned steps."""
-    fam = get_metric("mx_comm_bytes_total")
-    if fam is None:
-        return 0.0
-    with _LOCK:
-        series = list(fam._series.items())
-    total = 0.0
-    for lv, s in series:
-        if len(lv) < 4 or lv[3] != axis:
-            continue
-        if overlapped is not None and (lv[2] == "1") != overlapped:
-            continue
-        total += getattr(s, "value", 0.0)
-    return total
+def comm_axis_bytes(axis: str) -> float:
+    """Total mx_comm_bytes_total booked on one mesh axis's lane. The
+    partitioned-TP acceptance check reads comm_axis_bytes("tp") A/B between
+    the weight-sharded and partitioned steps."""
+    return comm_bytes_by_axis().get(axis, 0.0)
 
 
 def record_optimizer_state(nbytes: int, source: str = "trainer"):
@@ -1213,24 +1168,6 @@ def _sync_engine_stats():
     per-region roofline ledger refreshes its gauges here too."""
     from . import roofline as _roofline
     _roofline.export_metrics()
-    if get_metric("mx_comm_bytes_total") is not None:
-        gauge("mx_comm_overlap_ratio",
-              "Fraction of gradient-collective wire bytes (equivalently, "
-              "estimated collective seconds at the roofline bandwidth "
-              "peak) issued overlapped with backward compute") \
-            .set(comm_overlap_ratio())
-        # per-mesh-axis split of the same ratio: the tp lane going to ~0
-        # bytes (weight gather removed) vs staying a large unoverlapped
-        # remainder is the weight-sharded vs compute-partitioned signal
-        fam = get_metric("mx_comm_bytes_total")
-        with _LOCK:
-            axes = sorted({lv[3] for lv in fam._series
-                           if len(lv) > 3 and lv[3]})
-        for ax in axes:
-            gauge("mx_comm_overlap_ratio_axis",
-                  "Per-mesh-axis fraction of collective wire bytes issued "
-                  "overlapped with backward compute",
-                  ("axis",)).labels(ax).set(comm_overlap_ratio(axis=ax))
     try:
         from .. import engine as _engine
         st = _engine.cache_stats()

@@ -7,9 +7,9 @@ the run's *time went*:
 
     compute               wall not attributed to any badput category
                           (derived remainder; see the reconciliation rule)
-    comm_exposed          unoverlapped collective wire traffic converted
-                          to seconds at peak_bytes_per_second(), split per
-                          mesh axis (the PR 16 comm_axis_bytes accounting)
+    comm_exposed          collective wire traffic converted to seconds at
+                          peak_bytes_per_second(), split per mesh axis
+                          (telemetry.comm_bytes_by_axis)
     feed_stall            consumer waits on an empty DeviceFeed queue
                           (mx_feed_stall_seconds_total)
     dispatch_backpressure DispatchWindow admit()/drain() block time
@@ -267,30 +267,16 @@ def _compile_seconds() -> float:
         return 0.0
 
 
-def _comm_unoverlapped_bytes(t) -> Dict[str, float]:
-    """Per-mesh-axis unoverlapped wire bytes from mx_comm_bytes_total —
-    the exposed-comm numerator of the PR 16 per-axis overlap accounting."""
-    fam = t.get_metric("mx_comm_bytes_total")
-    if fam is None:
-        return {}
-    with t._LOCK:
-        series = list(fam._series.items())
-    out: Dict[str, float] = {}
-    for lv, s in series:
-        if len(lv) < 4 or lv[2] != "0":
-            continue
-        ax = lv[3] or "none"
-        out[ax] = out.get(ax, 0.0) + getattr(s, "value", 0.0)
-    return out
-
-
 def _snapshot_upstream(t) -> Dict[str, Any]:
     return {
         "feed_stall": _fam_sum(t, "mx_feed_stall_seconds_total"),
         "dispatch": _fam_sum(t, "mx_dispatch_wait_seconds_total"),
         "snapshot": _fam_sum(t, "mx_checkpoint_save_seconds_total"),
         "compile": _compile_seconds(),
-        "comm": _comm_unoverlapped_bytes(t),
+        # the exposed-comm numerator: no step body hides a collective
+        # behind compute by construction, so every byte booked counts
+        "comm": {ax or "none": v
+                 for ax, v in t.comm_bytes_by_axis().items()},
     }
 
 

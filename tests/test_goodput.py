@@ -314,6 +314,26 @@ def test_on_eviction_aggregates_and_stamps_recorder(tmp_path):
 # surfaces: prometheus, statusz, report, dump_json, disarmed path
 # ---------------------------------------------------------------------------
 
+def test_comm_exposed_books_every_byte_per_axis():
+    """No step body hides a collective behind compute, so every byte
+    booked on mx_comm_bytes_total counts as exposed, split by the "axis"
+    label wherever it sits among the labels (traffic without one under
+    "none")."""
+    telem.enable()
+    goodput.enable()
+    telem.record_step(8, source="t", seconds=0.01)   # the first snapshot
+    telem.record_comm("allreduce", 4 << 20, store="mesh", axis="dp")
+    telem.record_comm("tp_act_psum", 2 << 20, store="mesh", axis="tp")
+    telem.record_comm("push", 1 << 20, store="local")
+    telem.record_step(8, source="t", seconds=0.01)
+    bw = telem.peak_bytes_per_second()
+    axes = goodput.totals()["comm_exposed_axes"]
+    assert axes == pytest.approx({"dp": (4 << 20) / bw, "tp": (2 << 20) / bw,
+                                  "none": (1 << 20) / bw})
+    assert goodput.totals()["categories"]["comm_exposed"] == \
+        pytest.approx(sum(axes.values()))
+
+
 def test_prometheus_and_statusz_surfaces():
     telem.enable()
     goodput.enable()

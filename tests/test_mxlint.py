@@ -304,42 +304,6 @@ def reduce_bucket(stacked):
     assert _lint(tmp_path, "mxnet_tpu/x.py", src, ["donation-safety"]) == []
 
 
-def test_donation_understands_segment_vjp_kernel(tmp_path):
-    # parallel/overlap.py's segment-grad accumulator donates its carry;
-    # reading the dead accumulator after the fold is the classic
-    # microbatch-loop bug this decorator exists to catch
-    src = '''
-import jax.numpy as jnp
-
-@_segment_vjp_kernel(0)
-def _k_segment_grad_accum(acc, seg_flat):
-    return acc + seg_flat.astype(acc.dtype)
-
-def fold(acc, seg_flat):
-    new = _k_segment_grad_accum(acc, seg_flat)
-    return new + acc      # read-after-donate of the old accumulator
-'''
-    out = _lint(tmp_path, "mxnet_tpu/x.py", src, ["donation-safety"])
-    assert len(out) == 1 and "`acc`" in out[0].message
-
-
-def test_donation_segment_vjp_kernel_carry_is_clean(tmp_path):
-    # the documented pattern: the returned array REPLACES the carry
-    src = '''
-import jax.numpy as jnp
-
-@_segment_vjp_kernel(0)
-def _k_segment_grad_accum(acc, seg_flat):
-    return acc + seg_flat.astype(acc.dtype)
-
-def fold_all(acc, segs):
-    for seg in segs:
-        acc = _k_segment_grad_accum(acc, seg)
-    return acc
-'''
-    assert _lint(tmp_path, "mxnet_tpu/x.py", src, ["donation-safety"]) == []
-
-
 def test_donation_donor_names_are_scoped(tmp_path):
     # a donor binding named `fn` in one function must not poison an
     # unrelated local `fn` elsewhere (the false positive the real

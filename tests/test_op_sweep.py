@@ -293,6 +293,8 @@ def test_sweep_accounting():
     sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "tools"))
     import op_parity
 
+    if not os.path.isdir(op_parity.REF):
+        pytest.skip("reference tree /root/reference not present")
     refs = op_parity.ref_ops()
     tested = _tested_names()
     swept, elsewhere, exempt, unaccounted = [], [], [], []

@@ -95,27 +95,29 @@ def test_fused_step_under_both_plus_debug_nans():
     assert not sanitize.enabled()
 
 
-def test_overlapped_step_traces_under_tracer_leak_checker():
-    """The chunked-vjp overlapped step (ISSUE 10) holds K pullback closures
-    alive across the segment loop; the tracer-leak checker proves none of
-    them (nor the per-segment cotangent) escapes the trace."""
-    tr = _make_trainer(trainer_kw=dict(overlap_grads=True))
-    assert tr._overlap
+SHARD_MAP_BODIES = {"zero": dict(zero_update=True),
+                    "compressed": dict(compression={"type": "2bit"})}
+
+
+@pytest.mark.parametrize("body", sorted(SHARD_MAP_BODIES))
+def test_shard_map_step_traces_under_tracer_leak_checker(body):
+    """The zero and the 2-bit compressed steps trace their forward and
+    backward inside a shard_map body; the parameter swap must restore every
+    Parameter there too, or the leak checker raises."""
+    tr = _make_trainer(trainer_kw=SHARD_MAP_BODIES[body])
     x, y = nd.ones((4, 8)), nd.ones((4, 4))
     with _jax_flag("jax_check_tracer_leaks", True):
         loss0 = tr.step(x, y)
     assert np.isfinite(float(loss0))
 
 
-@pytest.mark.parametrize("zero", [False, True])
-def test_overlapped_step_dispatch_under_transfer_guard(zero):
-    """Overlapped dispatch stays transfer-free — the segment plan and
-    bucket specs are baked into the trace, nothing new crosses per step —
-    with the per-bucket collective riding either the plain or the
-    zero_update sharded tail."""
-    tr = _make_trainer(trainer_kw=dict(overlap_grads=True,
-                                       zero_update=zero))
-    assert tr._overlap
+@pytest.mark.parametrize("body", sorted(SHARD_MAP_BODIES))
+def test_shard_map_step_dispatch_under_transfer_guard(body):
+    """Their dispatch stays transfer-free: the bucket plan and the wd
+    vectors (zero), the residual carry and the threshold (compressed) are
+    device-resident or baked into the trace; nothing new crosses per
+    step."""
+    tr = _make_trainer(trainer_kw=SHARD_MAP_BODIES[body])
     x, y = nd.ones((4, 8)), nd.ones((4, 4))
     tr.step(x, y)  # trace+compile outside the guard
     with jax.transfer_guard("disallow"):

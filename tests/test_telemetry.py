@@ -403,7 +403,7 @@ def test_single_process_exposition_has_no_host_label_pinned():
     text = telem.scrape()
     assert "host=" not in text
     assert ('mx_comm_bytes_total{op="allreduce",store="mesh",'
-            'overlap="0",axis=""} 1024') in text
+            'axis=""} 1024') in text
     assert 'mx_step_seconds_count{source="t"} 2' in text
     assert 'mx_checkpoint_save_seconds{source="elastic"} 0.5' in text
 
@@ -419,16 +419,16 @@ def test_multi_process_host_label_is_trailing_and_aggregates():
     telem.record_checkpoint_save(0.5, 100)
     text = telem.scrape()
     assert ('mx_comm_bytes_total{op="allreduce",store="mesh",'
-            'overlap="0",axis="dp",host="3"} 2048') in text
+            'axis="dp",host="3"} 2048') in text
     assert 'mx_step_seconds_count{source="t",host="3"} 2' in text
     assert 'mx_checkpoint_save_seconds{source="elastic",host="3"} 0.5' \
         in text
     # prefix aggregation: two-label readers see the same totals
     assert telem.get_metric("mx_comm_bytes_total") \
         .get("allreduce", "mesh") == 2048
-    # positional lv[2]/lv[3] consumers are unaffected by the new label
+    # the per-axis readers find "axis" by name, whatever trails it
     assert telem.comm_axis_bytes("dp") == 2048
-    assert telem.comm_axis_bytes("dp", overlapped=False) == 2048
+    assert telem.comm_bytes_by_axis() == {"dp": 2048}
 
 
 def test_record_dispatch_wait_is_set_style():

@@ -3,7 +3,10 @@
 1/N optimizer-state footprint via the telemetry gauge, per-kind collective
 accounting, compile-cache keying per zero config, the compressed-wire
 reduce-scatter paths, and the bucket-planner / kvstore bucketed-pushpull
-mechanics the fused step shares with gluon Trainer."""
+mechanics the fused step shares with gluon Trainer. Also what the three
+step bodies (plain, zero, 2-bit compressed) must each hold: frozen leaves
+stay bit-equal, and the compiled step carries the collectives its body
+says it does."""
 import numpy as onp
 import pytest
 import jax
@@ -201,6 +204,95 @@ def test_collective_kind_counters(host_mesh8):
     rs_bf16 = zero_mod.reduce_scatter_wire_bytes(tr._zero_plan, 8,
                                                  "bfloat16")
     assert rs_bf16 == rs // 2
+
+
+def test_plain_step_books_one_allreduce_a_step(host_mesh8):
+    """The plain fused step books one all-reduce a step on the dp lane,
+    with the ring estimate's bytes, and nothing on any other lane."""
+    x, y = _batch()
+    telem.enable()
+    _, tr = _trainer(host_mesh8)
+    for steps in (1, 2, 3):
+        tr.step(x, y)
+        calls = telem.get_metric("mx_comm_calls_total")
+        assert calls.get("allreduce", "mesh") == steps
+        assert telem.comm_bytes_by_axis() == {
+            "dp": tr._grad_allreduce_bytes() * steps}
+    assert telem.comm_axis_bytes("tp") == 0
+
+
+# ---------------------------------------------------------------------------
+# the three step bodies: frozen leaves, and the collectives compiled in
+# ---------------------------------------------------------------------------
+
+BODIES = {"plain": {},
+          "zero": dict(zero_update=True, bucket_bytes=1024),
+          "compressed": dict(compression={"type": "2bit",
+                                          "threshold": 1e-3})}
+
+
+@pytest.mark.parametrize("body", sorted(BODIES))
+def test_frozen_params(host_mesh8, body):
+    """A grad_req='null' leaf is bit-equal after three steps of each step
+    body (under zero it stays out of every bucket and of the replicated
+    `extra` updates), and every other leaf moves."""
+    x, y = _batch()
+    mx.random.seed(7)
+    net = _mlp()
+    plist = list(net.collect_params().values())
+    frozen = 1  # the first Dense's bias
+    plist[frozen].grad_req = "null"
+    tr = DataParallelTrainer(net, _loss_fn, optimizer="sgd",
+                             optimizer_params={"learning_rate": 0.1},
+                             mesh=host_mesh8, **BODIES[body])
+    if body == "zero":
+        assert len(tr._zero_plan) > 1
+        assert all(frozen not in b.indices for b in tr._zero_plan)
+    before = [p.data().asnumpy() for p in plist]
+    for _ in range(3):
+        tr.step(x, y)
+    tr.sync()
+    after = [p.data().asnumpy() for p in plist]
+    for i, (b, a) in enumerate(zip(before, after)):
+        if i == frozen:
+            onp.testing.assert_array_equal(b, a)
+        else:
+            assert onp.abs(a - b).max() > 0, f"leaf {i} did not move"
+
+
+def _optimized_hlo(tr, x, y):
+    from jax.sharding import NamedSharding
+    from mxnet_tpu import random as _rng
+    xr = jax.device_put(x._data, NamedSharding(tr.mesh, P("dp")))
+    yr = jax.device_put(y._data, NamedSharding(tr.mesh, P("dp")))
+    rep = NamedSharding(tr.mesh, P())
+    key = jax.device_put(onp.asarray(_rng.next_key_raw()), rep)
+    lr, t, sc = (jax.device_put(onp.float32(v), rep)
+                 for v in (0.01, 1.0, 1.0))
+    fn = tr._get_step((xr.shape, str(xr.dtype), yr.shape, str(yr.dtype)))
+    return fn.lower(tr._params_raw, tr._opt_state, key, xr, yr,
+                    lr, t, sc).compile().as_text()
+
+
+@pytest.mark.parametrize("body,present,absent", [
+    ("plain", ("all-reduce",), ("reduce-scatter",)),
+    ("zero", ("reduce-scatter", "all-gather"), ())],
+    ids=["plain", "zero"])
+def test_step_hlo_collectives(host_mesh8, body, present, absent):
+    """The compiled step holds the exchange its body is built around: the
+    plain step the all-reduce XLA forms from the replicated gradients and
+    no reduce-scatter; the zero step a reduce-scatter and the gather-back
+    of the updated shards."""
+    x, y = _batch()
+    _, tr = _trainer(host_mesh8, optimizer="sgd", **BODIES[body])
+    text = _optimized_hlo(tr, x, y)
+
+    def count(op):
+        return text.count(op + "(") + text.count(op + "-start(")
+    for op in present:
+        assert count(op) >= 1, (body, op)
+    for op in absent:
+        assert count(op) == 0, (body, op)
 
 
 # ---------------------------------------------------------------------------
@@ -428,3 +520,54 @@ def test_gluon_trainer_batched_allreduce_path():
             losses.append(float(loss.asnumpy()))
         traj[kvstore] = losses
     onp.testing.assert_allclose(traj[None], traj["tpu"], rtol=1e-5)
+
+
+def _gluon_run(kvstore, bucket_env, monkeypatch, record=None):
+    monkeypatch.setenv("MXNET_TPU_BUCKET_BYTES", str(bucket_env))
+    rs = onp.random.RandomState(0)
+    x = nd.array(rs.uniform(-1, 1, (8, 16)).astype(onp.float32))
+    mx.random.seed(11)
+    net = _mlp()
+    trainer = gluon.Trainer(net.collect_params(), "sgd",
+                            {"learning_rate": 0.1}, kvstore=kvstore,
+                            update_on_kvstore=False)
+    losses = []
+    for step in range(3):
+        with mx.autograd.record():
+            out = net(x)
+            loss = nd.mean(nd.square(out))
+        loss.backward()
+        if step == 0 and record is not None:
+            trainer._init_kvstore()
+            orig = trainer._kvstore.pushpull
+
+            def spy(key, value, out=None, priority=0):
+                record.append(list(key) if isinstance(key, (list, tuple))
+                              else [key])
+                return orig(key, value, out=out, priority=priority)
+            trainer._kvstore.pushpull = spy
+        trainer.step(8)
+        losses.append(float(loss.asnumpy()))
+    return losses, [p.data().asnumpy()
+                    for p in net.collect_params().values()]
+
+
+def test_gluon_trainer_bucket_split_parity(monkeypatch):
+    """The per-bucket pushpull split (reverse declaration order) must be
+    byte-equivalent to the single fused call: same losses, same params."""
+    calls = []
+    # tiny cap: every parameter becomes its own bucket -> several calls
+    split = _gluon_run("tpu", 64, monkeypatch, record=calls)
+    fused = _gluon_run("tpu", 1 << 30, monkeypatch)
+    onp.testing.assert_allclose(split[0], fused[0], rtol=0, atol=0)
+    for a, b in zip(split[1], fused[1]):
+        onp.testing.assert_array_equal(a, b)
+    # 3 identical steps -> calls divide evenly into per-step runs
+    assert len(calls) % 3 == 0
+    per_step = len(calls) // 3
+    assert per_step > 2  # the split really split
+    # reverse declaration order within a step: later-declared (higher-key)
+    # buckets dispatch first, matching backward finalization order
+    run = calls[:per_step]
+    for prev, nxt in zip(run, run[1:]):
+        assert max(nxt) < min(prev)

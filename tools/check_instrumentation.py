@@ -59,11 +59,6 @@ METHOD_CHECKS = [
     # booked for every step that runs the sharded update
     ("parallel/data_parallel.py", "DataParallelTrainer",
      "_record_zero_telemetry", {"record_comm"}, "call"),
-    # backward-overlapped collectives (ISSUE 10): every overlapped step
-    # must book its per-bucket collective volume under the overlap label
-    # (the mx_comm_overlap_ratio gauge derives from exactly these series)
-    ("parallel/data_parallel.py", "DataParallelTrainer",
-     "_record_overlap_telemetry", {"record_comm"}, "call"),
     ("parallel/data_parallel.py", "DataParallelTrainer",
      "_record_telemetry", {"record_optimizer_state"}, "call"),
     ("parallel/pipeline.py", "PipelineTrainer", "step",
@@ -226,8 +221,8 @@ METHOD_CHECKS = [
      {"on_eviction"}, "call"),
     # compiled-HLO hazard audit (ISSUE 18): estimate_cost is THE audit
     # funnel — every AOT lower+compile must hand its optimized HLO to
-    # hlo_audit (a step artifact with a host callback / f64 promotion /
-    # lost overlap must fingerprint, never build silently); and every
+    # hlo_audit (a step artifact with a host callback / f64 promotion
+    # must fingerprint, never build silently); and every
     # StepProgram cost capture must thread its region so fingerprints
     # carry the same dp.step/pp.step labels the roofline ledger uses
     ("engine/__init__.py", None, "estimate_cost",
@@ -262,20 +257,10 @@ TEXT_CHECKS = [
      "volume on the 'sp' lane"),
     ("telemetry/__init__.py", "def comm_axis_bytes",
      "the registry must expose per-mesh-axis comm byte totals (the "
-     "dp-vs-tp-vs-sp split of mx_comm_overlap_ratio accounting)"),
-    ("telemetry/__init__.py", "mx_comm_overlap_ratio_axis",
-     "the registry must export the per-axis comm-overlap ratio gauge"),
+     "dp-vs-tp-vs-sp split the partitioned-tp acceptance reads)"),
     ("telemetry/__init__.py", "def record_optimizer_state",
      "the registry must expose the per-replica optimizer-state gauge "
      "(the zero-update memory acceptance signal)"),
-    ("telemetry/__init__.py", "mx_comm_overlap_ratio",
-     "the registry must export the comm-overlap ratio gauge (fraction of "
-     "collective bytes issued inside the backward — the overlapped step's "
-     "structural acceptance signal)"),
-    ("engine/xla_flags.py", "def ensure_overlap_flags",
-     "the engine must expose the async-collective XLA flag helper "
-     "(latency-hiding scheduler flags are frozen at backend init; the "
-     "overlapped step depends on them landing early)"),
     ("telemetry/__init__.py", "mx_feed_queue_depth",
      "the registry must export the async-feed queue-depth gauge"),
     ("telemetry/__init__.py", "mx_feed_stall_seconds_total",

@@ -10,12 +10,11 @@ donate from two sources:
   - local ``name = jax.jit(f, donate_argnums=(...))`` bindings (also
     ``@functools.partial(jax.jit, donate_argnums=...)`` decorators);
   - the framework's own ``@_update_kernel(a, b, ...)`` optimizer-kernel
-    decorator (optimizer/optimizer.py), its flat-bucket analog
-    ``@_sharded_update_kernel(a, ...)`` (parallel/zero.py), and the
-    segment-grad accumulator ``@_segment_vjp_kernel(a, ...)``
-    (parallel/overlap.py), whose positions ARE donate_argnums. A read of
-    the donated bucket — or of any view sliced out of it, since a
-    subscript read loads the base name — after the call is flagged.
+    decorator (optimizer/optimizer.py) and its flat-bucket analog
+    ``@_sharded_update_kernel(a, ...)`` (parallel/zero.py), whose
+    positions ARE donate_argnums. A read of the donated bucket — or of
+    any view sliced out of it, since a subscript read loads the base
+    name — after the call is flagged.
 
 At each call of a known donor it records the argument expressions sitting in
 donated positions, then flags any later *read* of the same expression in the
@@ -74,8 +73,7 @@ def _collect_donors(mod: ModuleInfo) -> Dict[str, Dict[str, Tuple[int, ...]]]:
                 if name == "partial" and dec.args \
                         and unparse(dec.args[0]).endswith("jit"):
                     pos = _donated_positions(dec)
-                elif name in ("_update_kernel", "_sharded_update_kernel",
-                              "_segment_vjp_kernel"):
+                elif name in ("_update_kernel", "_sharded_update_kernel"):
                     pos = tuple(a.value for a in dec.args
                                 if isinstance(a, ast.Constant)
                                 and isinstance(a.value, int))

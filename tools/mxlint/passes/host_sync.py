@@ -64,7 +64,7 @@ HOT_FUNCTIONS = [
     # ledger exists to expose, not cause
     ("mxnet_tpu/telemetry/goodput.py",
      r"(\b(_on_step|note_step|_snapshot_upstream|_fam_sum|"
-     r"_compile_seconds|_comm_unoverlapped_bytes|set_generation|"
+     r"_compile_seconds|set_generation|"
      r"set_pipeline_bubble)\b|_Ring\.append\b)"),
     # per-batch metric updates: accumulation must stay on device; the one
     # designed host sync is get()/get_global(), which are not hot-listed
