@@ -16,10 +16,11 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from .. import telemetry as _telem
 from ..base import env
 from .registry import register
 
-env.declare("MXNET_FLASH_ATTENTION_MIN_SEQ", 1024, int,
+env.declare("MXNET_FLASH_ATTENTION_MIN_SEQ", 512, int,
             "Sequence length from which the models' attention goes through "
             "_contrib_flash_attention instead of the plain scores-softmax "
             "path (ops/attention.py: use_flash)")
@@ -29,17 +30,37 @@ _NEG = -1e30  # finite mask: -inf makes exp(-inf - -inf) = nan on fully
 
 
 def flash_min_seq() -> int:
-    """The crossover length: below it the O(T^2) scores tensor is cheap and
-    the plain path is a few XLA fusions; from it up the flash kernels'
-    tiling pays (PERF.md, Findings of PR 29, has the measurements)."""
+    """The crossover length, 512 by default: from it up attention goes to
+    `flash_attention` (on the TPU the Pallas kernels, which beat the plain
+    path at 512 and at 1024: PERF.md, Findings of PR 29 and PR 31; where
+    `DataParallelTrainer`'s GSPMD step is traced for several devices, the
+    kernels' call is partitioned over its batch axis by a shard_map, the
+    route `flash_partitioned` of `mx_attention_route_total`). Below it
+    nothing has been measured and the plain scores-softmax path stays."""
     return env.get("MXNET_FLASH_ATTENTION_MIN_SEQ")
+
+
+def count_route(route: str):
+    """One count a traced (or eagerly run) attention layer in
+    `mx_attention_route_total`: `plain` where `use_flash` said no, `flash`
+    where the layer went to `flash_attention`, `flash_partitioned` where that
+    wrapped its kernels in a shard_map over the trainer's batch axis. Counted
+    while tracing; nothing of it is in the step."""
+    if _telem._ENABLED:
+        _telem.counter(
+            "mx_attention_route_total",
+            "Attention layers traced, by the route they took",
+            ("route",)).labels(route).inc()
 
 
 def use_flash(seq_len: int) -> bool:
     """Whether attention over `seq_len` positions takes the flash kernels:
     the one place the models (models/bert.py, models/hybrid_decoder.py,
     parallel/megatron.py) ask."""
-    return seq_len >= flash_min_seq()
+    flash = seq_len >= flash_min_seq()
+    if not flash:
+        count_route("plain")
+    return flash
 
 
 def _block_attn(q, k, v, bias, scale):

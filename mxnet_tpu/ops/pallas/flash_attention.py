@@ -33,6 +33,18 @@ dq += ds k takes the one transposed operand; dq accumulates in a float32
 scratch of T x d. Five products a block pair, every operand read once.
 At d = 64 every product half-fills the MXU (its 128-deep contraction or its
 128 output columns), and that is what bounds both calls at the cells' shapes.
+A head whose scores are one block pair (T, Tk <= 512: `_one_tile`) is handed
+no delta and keeps no O between the passes: its kernel holds the whole of
+p^T and dp^T and takes delta = colsum(p^T * dp^T), as autodiff of a softmax
+does, so every row of ds sums to zero before it is rounded (with delta from
+the rounded O it sums to that rounding error, which a bias in front of K
+collects over all rows). Longer heads read delta as above.
+
+Partitioning. XLA cannot partition a Mosaic call. Where the call is traced
+inside `DataParallelTrainer`'s GSPMD step for several devices
+(`ops/registry.py: batch_partition`), `flash_attention` wraps the custom_vjp
+function in a shard_map over the batch axis: the leading B * H is sharded
+with B major, forward and backward run per shard, no collective is added.
 
 Off-TPU (CPU tests) the same kernels run in interpret mode when
 MXNET_PALLAS_INTERPRET=1, else we fall back to the lax.scan implementation
@@ -49,6 +61,9 @@ from jax import lax
 
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+from jax.sharding import PartitionSpec as P
+
+from ...base import MXNetError
 
 _NEG = -1e30  # finite mask value: -inf breeds nans in exp(-inf - -inf)
 _LANES = 128
@@ -320,6 +335,11 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *carried, scale, causal,
     lax.fori_loop(0, G, head, None)
 
 
+# `_fwd` and `_bwd` are jitted so that the layers of a model share one trace of
+# the kernel and one lowering to Mosaic: traced anew for every layer, twelve
+# layers cost the step's build 2 s more on the v5e's host (PERF.md, PR 31)
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7))
 def _fwd(q, k, v, causal, scale, block_q, block_k, interpret):
     """-> o (BH, T, D), lse (BH, 1, Tp): T padded to whole blocks, on lanes."""
     BH, T, D = q.shape
@@ -371,9 +391,11 @@ def _fwd(q, k, v, causal, scale, block_q, block_k, interpret):
 # inside, scores transposed (k rows, q along lanes)
 # ---------------------------------------------------------------------------
 
-def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
-                dq_ref, dk_ref, dv_ref, dq_acc, *carried, scale, causal,
-                bq, bk, n_qc, n_kc):
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, *refs, scale, causal,
+                bq, bk, n_qc, n_kc, own_delta):
+    # a one-tile head (`_one_tile`) is handed no delta: it computes its own
+    delta_ref = None if own_delta else refs[0]
+    dq_ref, dk_ref, dv_ref, dq_acc, *carried = refs[0 if own_delta else 1:]
     G, cq, D = q_ref.shape
     ck = k_ref.shape[1]
     nqb, nkb = cq // bq, ck // bk
@@ -407,7 +429,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             qblk = q_ref[g, pl.ds(q0, bq), :]
             dob = do_ref[g, pl.ds(q0, bq), :]
             lse = lse_ref[g, :, pl.ds(q0, bq)]          # (1, bq)
-            delta = delta_ref[g, :, pl.ds(q0, bq)]
+            delta = None if own_delta else delta_ref[g, :, pl.ds(q0, bq)]
             st = lax.dot_general(kblk, qblk, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
             if masked:
@@ -423,6 +445,13 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 preferred_element_type=jnp.float32)
             dpt = lax.dot_general(vblk, dob, (((1,), (1,)), ((), ())),
                                   preferred_element_type=jnp.float32)
+            if own_delta:
+                # the tile is the head's whole p^T and dp^T: delta as
+                # autodiff of a softmax takes it, so every row of ds sums
+                # to zero before it is rounded (sum(dO * o) from the stored
+                # o leaves that o's rounding error in the sum, and sum_j dk_j
+                # is that error times q)
+                delta = jnp.sum(pt * dpt, axis=0, keepdims=True)
             dst = (pt * (dpt - delta)).astype(qblk.dtype)
             dk = dk + lax.dot_general(
                 dst, qblk, (((1,), (0,)), ((), ())),
@@ -484,16 +513,28 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     when(last, _write_dq)
 
 
+def _one_tile(T, Tk, block_q, block_k):
+    """Whether a head's scores are one block pair: the backward kernel then
+    holds the whole of p^T and dp^T and takes `delta` from them."""
+    return T <= block_q and Tk <= block_k
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10))
 def _bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k, interpret):
+    """`o` is None for a one-tile head, whose kernel computes its own delta."""
     BH, T, D = q.shape
     Tk = k.shape[1]
     cq, n_qc, ck, n_kc, G, step_bytes = _plan(
         True, BH, T, Tk, D, q.dtype, causal, block_q, block_k)
     Tp, Tkp = cq * n_qc, ck * n_kc
     assert lse.shape == (BH, 1, Tp), (lse.shape, Tp)
-    delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
-    # padded q rows: q = dO = 0 and delta = 0, so ds = p * (0 - 0) = 0
-    deltap = jnp.pad(delta, ((0, 0), (0, Tp - T)))[:, None, :]
+    own_delta = o is None
+    rows = [lse]
+    if not own_delta:
+        delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
+                        axis=-1)
+        # padded q rows: q = dO = 0 and delta = 0, so ds = p * (0 - 0) = 0
+        rows.append(jnp.pad(delta, ((0, 0), (0, Tp - T)))[:, None, :])
     qp = jnp.pad(q, ((0, 0), (0, Tp - T), (0, 0)))
     dop = jnp.pad(do, ((0, 0), (0, Tp - T), (0, 0)))
     kp = jnp.pad(k, ((0, 0), (0, Tkp - Tk), (0, 0)))
@@ -503,7 +544,8 @@ def _bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k, interpret):
         scratch += [pltpu.VMEM((ck, D), jnp.float32),
                     pltpu.VMEM((ck, D), jnp.float32)]
     kern = functools.partial(_bwd_kernel, scale=scale, causal=causal,
-                             bq=block_q, bk=block_k, n_qc=n_qc, n_kc=n_kc)
+                             bq=block_q, bk=block_k, n_qc=n_qc, n_kc=n_kc,
+                             own_delta=own_delta)
     q_spec = pl.BlockSpec((G, cq, D), lambda b, j, i: (b, i, 0),
                           memory_space=pltpu.VMEM)
     k_spec = pl.BlockSpec((G, ck, D), lambda b, j, i: (b, j, 0),
@@ -513,7 +555,7 @@ def _bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k, interpret):
     dq, dk, dv = pl.pallas_call(
         kern,
         grid=(BH // G, n_kc, n_qc),
-        in_specs=[q_spec, k_spec, k_spec, q_spec, row_spec, row_spec],
+        in_specs=[q_spec, k_spec, k_spec, q_spec] + [row_spec] * len(rows),
         out_specs=[
             pl.BlockSpec((G, Tp, D), lambda b, j, i: (b, 0, 0),
                          memory_space=pltpu.VMEM),
@@ -528,7 +570,7 @@ def _bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k, interpret):
         interpret=interpret,
         name="mx_flash_bwd",
         **_compiler_params(interpret, step_bytes),
-    )(qp, kp, vp, dop, lse, deltap)
+    )(qp, kp, vp, dop, *rows)
     return dq[:, :T], dk[:, :Tk], dv[:, :Tk]
 
 
@@ -544,7 +586,9 @@ def _flash(q3, k3, v3, causal, scale, block_q, block_k, interpret):
 
 def _flash_fwd(q3, k3, v3, causal, scale, block_q, block_k, interpret):
     o, lse = _fwd(q3, k3, v3, causal, scale, block_q, block_k, interpret)
-    return o, (q3, k3, v3, o, lse)
+    keep = None if _one_tile(q3.shape[1], k3.shape[1], block_q, block_k) \
+        else o
+    return o, (q3, k3, v3, keep, lse)
 
 
 def _flash_bwd(causal, scale, block_q, block_k, interpret, res, g):
@@ -564,12 +608,24 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     MXNET_PALLAS_INTERPRET=1); falls back to the lax.scan blockwise
     implementation elsewhere — same math, same signature. `block_q` and
     `block_k` are upper limits; the kernels walk blocks of whole lane tiles.
+    Under the trainer's multi-device GSPMD trace the kernels run per shard of
+    the batch (the module's docstring); B must divide over that axis.
     """
+    from .. import attention as _attn_ops
+    from ..registry import batch_partition
     B, H, T, D = q.shape
     if scale is None:
         scale = 1.0 / (D ** 0.5)
     on_tpu = _on_tpu(q)
-    if not (on_tpu or _use_interpret()):
+    kernels = on_tpu or _use_interpret()
+    # XLA cannot partition a Mosaic call by itself. Where the surrounding
+    # trace is the trainer's GSPMD step on several devices, each device runs
+    # the kernels, forward and backward, on its own rows of the batch: B is
+    # the major part of the leading B * H, so no collective is added
+    part = batch_partition.get()
+    partitioned = kernels and part is not None and part[0].size > 1
+    _attn_ops.count_route("flash_partitioned" if partitioned else "flash")
+    if not kernels:
         # The fallback is differentiated by jax AS WRITTEN (no custom_vjp):
         # its gradient contract — matches the dense-softmax VJP at every
         # shape, including T not a multiple of block_size and causal
@@ -578,14 +634,23 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
         # by tests/test_pallas_kernels.py::test_fallback_grad_*.
         # It keeps the 256 keys a block that every caller of the registered
         # op has had: the kernels' limits grew, CPU numerics did not move.
-        from ..attention import blockwise_attention
-        return blockwise_attention(q, k, v, causal=causal, scale=scale,
-                                   block_size=min(block_k, 256))
+        return _attn_ops.blockwise_attention(
+            q, k, v, causal=causal, scale=scale, block_size=min(block_k, 256))
     Tk = k.shape[2]
     bq, bk = _block(T, block_q), _block(Tk, block_k)
-    q3 = q.reshape(B * H, T, D)
-    k3 = k.reshape(B * H, Tk, D)
-    v3 = v.reshape(B * H, Tk, D)
-    out = _flash(q3, k3, v3, bool(causal), float(scale), int(bq), int(bk),
-                 not on_tpu)
+
+    def call(q3, k3, v3):
+        return _flash(q3, k3, v3, bool(causal), float(scale), int(bq),
+                      int(bk), not on_tpu)
+
+    if partitioned:
+        mesh, axis = part
+        if B % mesh.shape[axis]:
+            raise MXNetError(
+                f"flash attention: a batch of {B} rows cannot be divided "
+                f"over the {mesh.shape[axis]} devices of mesh axis {axis!r}")
+        call = jax.shard_map(call, mesh=mesh, in_specs=P(axis),
+                             out_specs=P(axis), check_vma=False)
+    out = call(q.reshape(B * H, T, D), k.reshape(B * H, Tk, D),
+               v.reshape(B * H, Tk, D))
     return out.reshape(B, H, T, D)

@@ -41,6 +41,15 @@ exec_platform: contextvars.ContextVar = contextvars.ContextVar(
     "mxnet_tpu_exec_platform", default=None)
 
 
+# (mesh, batch axis name) when the CURRENT trace is a GSPMD program whose batch
+# is sharded over that axis: set by DataParallelTrainer._build_step inside its
+# traced body, and by nothing that already traces inside a shard_map. An op
+# whose lowering XLA cannot partition by itself (a Mosaic kernel) reads it and
+# wraps its call in a shard_map over that axis (ops/pallas/flash_attention.py).
+batch_partition: contextvars.ContextVar = contextvars.ContextVar(
+    "mxnet_tpu_batch_partition", default=None)
+
+
 def _platform_of(arrays) -> Optional[str]:
     for a in arrays:
         try:

@@ -35,6 +35,7 @@ from ..telemetry import tracing as _tracing
 from ..gluon.block import HybridBlock, _AUX_STACK
 from ..gluon.parameter import Parameter
 from .. import optimizer as opt_mod
+from ..ops import registry as _op_registry
 from . import zero as _zero
 from .mesh import current_mesh, P
 from .step_program import StepProgram
@@ -735,8 +736,16 @@ class DataParallelTrainer:
                 pred = out if not isinstance(out, tuple) else out[0]
                 lossv = loss_raw(pred, y)
                 return lossv * loss_scale, (lossv, aux)
-            (_, (lossv, aux)), grads = jax.value_and_grad(
-                lossf, has_aux=True)(params)
+            # this body alone is a plain GSPMD program: an op XLA cannot
+            # partition (a Mosaic kernel) learns here over which axis the
+            # batch lies (the zero and compressed bodies trace inside a
+            # shard_map of their own and say nothing)
+            tok = _op_registry.batch_partition.set((mesh, batch_axis))
+            try:
+                (_, (lossv, aux)), grads = jax.value_and_grad(
+                    lossf, has_aux=True)(params)
+            finally:
+                _op_registry.batch_partition.reset(tok)
             if scaled:
                 inv = 1.0 / loss_scale
                 grads = [g * inv if jnp.issubdtype(g.dtype, jnp.floating) else g

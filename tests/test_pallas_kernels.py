@@ -6,6 +6,7 @@ monkeypatch) — the same kernel code the TPU executes, minus the hardware.
 Mirrors reference test style: check_consistency across implementations
 (python/mxnet/test_utils.py:1422).
 """
+import contextlib
 import os
 
 import numpy as np
@@ -239,6 +240,97 @@ def test_flash_plan_at_the_cells_shapes():
 
 
 # ---------------------------------------------------------------------------
+# a head whose scores are one tile computes its own delta (T, Tk <= 512: the
+# published BERT length); longer heads read sum(dO * o) as they did
+# ---------------------------------------------------------------------------
+
+def plain_attention(q, k, v, causal=False):
+    """The plain path as models/bert.py writes it, in the inputs' dtype."""
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+    if causal:
+        s = jnp.where(np.tril(np.ones(s.shape[-2:], bool)), s, -1e30)
+    return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(s, axis=-1), v)
+
+
+@pytest.mark.parametrize("BH,T,causal", [(8, 512, False), (4, 384, True)],
+                         ids=["bert_t512", "causal_t384"])
+def test_one_tile_head_computes_its_own_delta(interpret_mode, BH, T, causal):
+    """bfloat16, the cells' precision. The key third of BERT's fused bias has
+    no gradient under softmax: sum_j dk_j is rounding alone, and what AdamW
+    makes of it failed `correct` at T = 512 while delta came from the rounded
+    o (1.3 x the plain path's here). From the tile's own p and dp every row
+    of ds sums to zero before it is rounded, as autodiff's does."""
+    assert _plans(BH, T, T, 64, jnp.bfloat16, causal)[1][:2] == (1, 1)
+    rs = np.random.RandomState(T)
+    q, k, v, co = (jnp.asarray(rs.normal(0, 1, (1, BH, T, 64)), jnp.bfloat16)
+                   for _ in range(4))
+
+    def grads(attn, *args):
+        return jax.grad(lambda q, k, v: jnp.vdot(
+            attn(q, k, v, causal=causal).astype(jnp.float32),
+            co.astype(jnp.float32)), argnums=(0, 1, 2))(*args)
+
+    g = grads(flash_attention, q, k, v)
+    g_plain = grads(plain_attention, q, k, v)
+    g_ref = grads(naive_attention,
+                  *(x.astype(jnp.float32) for x in (q, k, v)))
+    for name, a, b in zip("qkv", g, g_ref):
+        np.testing.assert_allclose(
+            np.asarray(a, dtype=np.float32), np.asarray(b),
+            rtol=0.05, atol=0.05, err_msg=f"d{name}")
+
+    def key_bias_grad(dk):
+        return float(jnp.linalg.norm(jnp.sum(dk.astype(jnp.float32), axis=2)))
+
+    assert key_bias_grad(g[1]) <= 1.15 * key_bias_grad(g_plain[1])
+
+
+def _backward_eqns(T):
+    """(operands of the backward's Mosaic call, number of reduce_sums over a
+    (BH, T, d) product outside it) in the jaxpr of flash_attention's grad."""
+    x = jax.ShapeDtypeStruct((1, 2, T, 64), jnp.bfloat16)
+    jaxpr = jax.make_jaxpr(jax.grad(
+        lambda q, k, v: jnp.sum(flash_attention(q, k, v).astype(jnp.float32)),
+        argnums=(0, 1, 2)))(x, x, x)
+    operands, row_sums = [], 0
+
+    def walk(j):
+        nonlocal row_sums
+        for e in j.eqns:
+            if e.primitive.name == "pallas_call":
+                if e.params["name"] == "mx_flash_bwd":
+                    operands.append(len(e.invars))
+                continue
+            if e.primitive.name == "reduce_sum" \
+                    and e.invars[0].aval.shape == (2, T, 64):
+                row_sums += 1
+            for p in e.params.values():
+                inner = getattr(p, "jaxpr", p)
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    walk(inner)
+
+    walk(jaxpr.jaxpr)
+    return operands, row_sums
+
+
+def test_delta_source_follows_the_plan(interpret_mode):
+    """T = 512 hands the backward q, k, v, dO and lse, computes no
+    sum(dO * o) in XLA and keeps no o between the passes; T = 1024 (two
+    blocks a head) is handed delta as before."""
+    fa = _fa()
+    assert _backward_eqns(512) == ([5], 0)
+    assert _backward_eqns(1024) == ([6], 1)
+    q, k, v = (x.reshape(4, 512, 64) for x in _rand_qkv(9, T=512, D=64))
+    out, res = fa._flash_fwd(q, k, v, False, 0.125, 512, 512, True)
+    assert [None if r is None else r.shape for r in res] \
+        == [(4, 512, 64)] * 3 + [None, (4, 1, 512)]
+    q, k, v = (x.reshape(4, 1024, 64) for x in _rand_qkv(9, T=1024, D=64))
+    out, res = fa._flash_fwd(q, k, v, False, 0.125, 512, 512, True)
+    assert res[3].shape == out.shape == (4, 1024, 64)
+
+
+# ---------------------------------------------------------------------------
 # non-Pallas fallback gradient path (NO interpret fixture: on CPU
 # flash_attention routes to the blockwise lax.scan — the path every
 # CPU-trained model differentiates through)
@@ -408,16 +500,20 @@ def test_flash_kernels_lower_for_tpu(building_for_tpu, BH, T, causal):
 # a time may load the TPU's library, and every xdist worker imports this file).
 
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_2x2():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_2x2):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e_2x2.devices[0])
 
 
 @pytest.mark.parametrize("BH,T,causal", [
@@ -435,6 +531,63 @@ def test_flash_kernels_compile_for_v5e(building_for_tpu, one_chip, BH, T,
     # the statistics between the two calls: T on lanes, under 1 MB for all
     # heads where the column layout took 100 MB
     assert f"f32[{BH},1,{T}]" in text and f"f32[{BH},{T},1]" not in text
+
+
+@contextlib.contextmanager
+def trainer_context(mesh, axis="dp"):
+    """What DataParallelTrainer._build_step sets round its traced body."""
+    from mxnet_tpu.ops import registry
+    tok = registry.batch_partition.set((mesh, axis))
+    try:
+        yield
+    finally:
+        registry.batch_partition.reset(tok)
+
+
+def _flash_grad(q, k, v):
+    return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v).astype(jnp.float32)), argnums=(0, 1, 2))(q, k, v)
+
+
+def test_flash_kernels_compile_partitioned_for_v5e(building_for_tpu, v5e_2x2):
+    """bert_base_train_dp4's attention: the global batch of 128 sharded over
+    dp = 4 in the trainer's GSPMD step. XLA cannot partition a Mosaic call;
+    under the trainer's context the call is a shard_map over the batch axis
+    and each chip runs t512's own two calls, with no collective round them."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    mesh = Mesh(np.array(v5e_2x2.devices).reshape(4), ("dp",))
+    x = jax.ShapeDtypeStruct((128, 12, 512, 64), jnp.bfloat16,
+                             sharding=NamedSharding(mesh, P("dp")))
+
+    def in_context(q, k, v):
+        with trainer_context(mesh):
+            return _flash_grad(q, k, v)
+
+    text = jax.jit(in_context).lower(x, x, x).compile().as_text()
+    calls = [line for line in text.splitlines()
+             if "custom-call(" in line and "tpu_custom_call" in line]
+    assert len(calls) == 2, calls
+    assert all("bf16[384,512,64]" in c and "bf16[1536," not in c
+               for c in calls)
+    for collective in ("all-gather", "all-to-all", "collective-permute",
+                       "all-reduce"):
+        assert collective not in text, collective
+    # with no context the multi-device trace keeps jax's own refusal
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        jax.jit(_flash_grad).lower(x, x, x).compile()
+
+
+def test_partitioned_batch_must_divide(building_for_tpu):
+    from jax.sharding import Mesh
+    from mxnet_tpu.base import MXNetError
+    mesh = Mesh(np.array(jax.devices("cpu")[:4]), ("dp",))
+    x = jax.ShapeDtypeStruct((6, 2, 512, 64), jnp.bfloat16)
+    with trainer_context(mesh), \
+            pytest.raises(MXNetError, match="6 rows.*4 devices"):
+        jax.eval_shape(_flash_grad, x, x, x)
+    # a mesh of one device partitions nothing
+    with trainer_context(Mesh(np.array(jax.devices("cpu")[:1]), ("dp",))):
+        assert "shard_map" not in str(jax.make_jaxpr(_flash_grad)(x, x, x))
 
 
 def test_fused_optimizer_kernels_lower_for_tpu(monkeypatch):
