@@ -19,12 +19,13 @@ from .bert import (BertModel, BertEncoder, TransformerEncoderCell,
 from .moe_transformer import (MoEPositionwiseFFN, MoETransformerCell,
                               MoETransformerLM, moe_transformer_tiny)
 from .hybrid_decoder import (HybridDecoder, HybridDecoderLayer, Mamba2Mixer,
-                             GroupedQueryAttention, SwiGLU,
-                             hybrid_decoder_tiny)
+                             GroupedQueryAttention, SwiGLU, HeldExpertsFFN,
+                             hybrid_decoder_tiny, windowed_moe_decoder_tiny)
 
 __all__ = ["get_model", "LeNet", "lenet", "MLP", "mlp", "BertModel",
            "BertEncoder", "TransformerEncoderCell", "bert_base", "bert_large",
            "bert_tiny", "MoEPositionwiseFFN", "MoETransformerCell",
            "MoETransformerLM", "moe_transformer_tiny", "HybridDecoder",
            "HybridDecoderLayer", "Mamba2Mixer", "GroupedQueryAttention",
-           "SwiGLU", "hybrid_decoder_tiny"]
+           "SwiGLU", "HeldExpertsFFN", "hybrid_decoder_tiny",
+           "windowed_moe_decoder_tiny"]

@@ -1,6 +1,9 @@
 """A decoder built from a configuration's `layer_types`: Mamba-2 state-space
 layers and grouped-KV attention layers side by side (IBM Granite 4.0-H,
-HF `GraniteMoeHybrid`, with no experts).
+HF `GraniteMoeHybrid`, with no experts); and, since PR 32, full and sliding
+window attention layers with per-layer head counts, rotary positions and a
+per-head output gate, over a dense or a sparse (routed experts beside a
+shared one) feed-forward, with a head of its own (poolside Laguna).
 
 No reference counterpart (MXNet 1.x has neither state-space layers nor
 grouped KV heads). With `e`, `r`, `s`, `l` the embedding, residual, attention
@@ -18,23 +21,45 @@ and logits multipliers:
                 dt = softplus(dt + dt_bias);  A = -exp(A_log)
                 (ops/ssm.py has the scan's and the convolution's equations)
 
+The attention kinds (`"full_attention"`, `"sliding_attention"`; H_l query
+heads of `head_dim` d over the KV heads, window W on the sliding kind):
+
+    q, k = rope(a Wq), rope(a Wk)         rotary positions, a set per kind
+    o_j  = softmax(q_j k^T / sqrt(d) + mask) v,   mask: t' <= t, and on the
+           sliding kind also t - t' < W
+    mixer = W_o concat_j(sigmoid(a W_g)_j * o_j)  the gate: one number a head
+
+and the sparse feed-forward (`mlp_layer_types[l] == "sparse"`), of which
+this chip holds `held` of the published experts (`parallel/moe.py:
+held_moe_ffn` has the routing):
+
+    ffn(b) = scaling * sum_{e in top-k, e held} w_e ffn_e(b) + ffn_shared(b)
+
 Every decoder layer may be a recomputed block (`HybridBlock.recompute`): under
 a fused trainer's step only the layers' inputs live from the forward pass to
 the backward one, which is what lets a model of this width train on one chip.
 `jax.named_scope`s name the groups a device trace is read by: `mx.embed`,
 `mx.mamba` (with `mx.conv1d` and `mx.ssd` inside it), `mx.attn`, `mx.ffn`,
-`mx.head`.
+`mx.head`; the new kinds' mixers are `mx.attn.full` and `mx.attn.window`
+(with `mx.rope` and the kernel call alone, `mx.flash.full` or
+`mx.flash.window`, inside), the sparse feed-forward `mx.moe` (with
+`mx.moe.route`, `mx.moe.experts` and `mx.moe.shared` inside it).
 """
 from __future__ import annotations
 
+import contextlib
+
 import jax
 
-from ..gluon.block import HybridBlock
+from .. import autograd
+from ..gluon.block import HybridBlock, defer_aux_update
 from ..gluon import nn
 from ..ops import attention as _attn_ops
+from ..ops.moe import HELD_REPORT
 
 __all__ = ["Mamba2Mixer", "GroupedQueryAttention", "SwiGLU",
-           "HybridDecoderLayer", "HybridDecoder", "hybrid_decoder_tiny"]
+           "HeldExpertsFFN", "HybridDecoderLayer", "HybridDecoder",
+           "hybrid_decoder_tiny", "windowed_moe_decoder_tiny"]
 
 
 def _dense(units, in_units):
@@ -94,34 +119,58 @@ class Mamba2Mixer(HybridBlock):
 
 
 class GroupedQueryAttention(HybridBlock):
-    """Causal self-attention without positions: `num_heads` query heads over
-    `num_kv_heads` key/value heads (each repeated for its queries, so the
-    attention kernels see equal head counts), softmax scale `scale` as
-    stated (None: 1/sqrt(d)), no bias. Through the flash kernels where
-    `ops.attention.use_flash(T)` says so, as models/bert.py."""
+    """Causal self-attention: `num_heads` query heads over `num_kv_heads`
+    key/value heads (each repeated for its queries, so the attention kernels
+    see equal head counts) of `head_dim` numbers (None: units / num_heads),
+    softmax scale `scale` as stated (None: 1/sqrt(d)), no bias.
 
-    def __init__(self, units, num_heads, num_kv_heads, scale=None, **kwargs):
+    `window`: a query sees only the `window` latest keys, itself among them
+    (None: every earlier key). `rope`: keyword arguments of
+    `_contrib_rotary_embedding`, applied to q and k (None: no positions).
+    `gate`: each head's output is multiplied by the sigmoid of one number
+    projected from the layer's input (head-wise gated attention,
+    arXiv:2505.06708). Through the flash kernels where
+    `ops.attention.use_flash(T)` says so, as models/bert.py (route `flash`,
+    or `flash_window` with a window); the plain scores-softmax route takes
+    the same mask. `kernel_scope` names the kernel call alone in a trace."""
+
+    def __init__(self, units, num_heads, num_kv_heads, scale=None,
+                 head_dim=None, window=None, rope=None, gate=False,
+                 kernel_scope=None, **kwargs):
         super().__init__(**kwargs)
-        assert units % num_heads == 0 and num_heads % num_kv_heads == 0
+        assert num_heads % num_kv_heads == 0
+        if head_dim is None:
+            assert units % num_heads == 0
+            head_dim = units // num_heads
         self._heads, self._kv_heads = num_heads, num_kv_heads
-        self._d = d = units // num_heads
+        self._d = d = head_dim
         self._scale = float(scale) if scale is not None else d ** -0.5
-        self.query = _dense(units, units)
+        self._window = None if window is None else int(window)
+        self._rope = None if rope is None else dict(rope)
+        self._kernel_scope = kernel_scope
+        self.query = _dense(num_heads * d, units)
         self.key = _dense(num_kv_heads * d, units)
         self.value = _dense(num_kv_heads * d, units)
-        self.proj = _dense(units, units)
+        self.gate = _dense(num_heads, units) if gate else None
+        self.proj = _dense(units, num_heads * d)
 
     def hybrid_forward(self, F, x):
         H, d, rep = self._heads, self._d, self._heads // self._kv_heads
         q, k, v = (F.transpose(F.reshape(p(x), shape=(0, 0, -4, -1, d)),
                                axes=(0, 2, 1, 3))           # (B, heads, T, d)
                    for p in (self.query, self.key, self.value))
+        if self._rope is not None:
+            q, k = (F._contrib_rotary_embedding(a, **self._rope)
+                    for a in (q, k))
         if rep > 1:
             k, v = F.repeat(k, repeats=rep, axis=1), \
                 F.repeat(v, repeats=rep, axis=1)
         if _attn_ops.use_flash(x.shape[1]):
-            out = F._contrib_flash_attention(q, k, v, causal=True,
-                                             scale=self._scale)
+            with jax.named_scope(self._kernel_scope) if self._kernel_scope \
+                    else contextlib.nullcontext():
+                out = F._contrib_flash_attention(
+                    q, k, v, causal=True, scale=self._scale,
+                    window=self._window)
         else:
             q2, k2, v2 = (F.reshape(a, shape=(-3, 0, 0)) for a in (q, k, v))
             scores = F.batch_dot(q2, k2, transpose_b=True) * self._scale
@@ -129,13 +178,21 @@ class GroupedQueryAttention(HybridBlock):
             pos = F.arange_like(F.cast(scores, dtype="float32"), axis=1)
             ahead = F.broadcast_lesser(F.expand_dims(pos, axis=1),
                                        F.expand_dims(pos, axis=0))
+            if self._window is not None:
+                behind = F.broadcast_greater_equal(
+                    F.expand_dims(pos, axis=1) - self._window,
+                    F.expand_dims(pos, axis=0))
+                ahead = ahead + behind
             scores = F.broadcast_add(
                 scores, F.expand_dims(F.cast(ahead * -1e30,
                                              dtype=scores.dtype), axis=0))
             out = F.batch_dot(F.softmax(scores, axis=-1), v2)
             out = F.reshape(out, shape=(-4, -1, H, 0, 0))   # (B, H, T, d)
-        out = F.reshape(F.transpose(out, axes=(0, 2, 1, 3)), shape=(0, 0, -3))
-        return self.proj(out)
+        out = F.transpose(out, axes=(0, 2, 1, 3))           # (B, T, H, d)
+        if self.gate is not None:
+            out = F.broadcast_mul(
+                out, F.expand_dims(F.sigmoid(self.gate(x)), axis=-1))
+        return self.proj(F.reshape(out, shape=(0, 0, -3)))
 
 
 class SwiGLU(HybridBlock):
@@ -155,71 +212,152 @@ class SwiGLU(HybridBlock):
         return self.ffn2(F.silu(g) * u)
 
 
+class HeldExpertsFFN(HybridBlock):
+    """The sparse feed-forward of a chip that holds `held` of a layer's
+    `published_experts` routed experts (`first_held` onwards) and the shared
+    expert whole: a router over all the published experts, `top_k` a token,
+    their weights renormalised over the `top_k` and scaled by `scaling`; the
+    held experts' part of the sum (`_contrib_held_moe_ffn`: dropless) beside
+    the shared expert's output, ungated. Every expert is a SwiGLU.
+
+    `routing` is state, not a weight: the last training step's report of the
+    layer in the order of `ops.moe.HELD_REPORT` (assignments kept here, the
+    largest and the mean load of a held expert, 1 where the exact dense path
+    ran). A fused step hands it on with the other aux outputs, as BatchNorm's
+    statistics, and reads nothing back; `routing.data()` does."""
+
+    def __init__(self, units, expert_hidden, shared_hidden, held,
+                 published_experts, top_k, scaling=1.0, first_held=0,
+                 **kwargs):
+        super().__init__(**kwargs)
+        self._op = dict(top_k=top_k, published_experts=published_experts,
+                        first_held=first_held, scaling=scaling)
+        self.router_weight = self.params.get(
+            "router_weight", shape=(published_experts, units))
+        self.experts_gate_up = self.params.get(
+            "experts_gate_up", shape=(held, units, 2 * expert_hidden))
+        self.experts_down = self.params.get(
+            "experts_down", shape=(held, expert_hidden, units))
+        self.routing = self.params.get(
+            "routing", shape=(len(HELD_REPORT),), init="zeros",
+            grad_req="null", differentiable=False)
+        self.shared = SwiGLU(units, shared_hidden)
+
+    def cast(self, dtype):
+        super().cast(dtype)
+        self.routing.cast("float32")    # counts: bfloat16 holds 256 exactly
+
+    def hybrid_forward(self, F, x, router_weight, experts_gate_up,
+                       experts_down, routing):
+        y, report = F._contrib_held_moe_ffn(x, router_weight, experts_gate_up,
+                                            experts_down, **self._op)
+        if autograd.is_training() or autograd.is_recording():
+            defer_aux_update(self.routing, report._data)
+        with jax.named_scope("mx.moe.shared"):
+            return y + self.shared(x)
+
+
+_MIXER_SCOPES = {"mamba": "mx.mamba", "attention": "mx.attn",
+                 "full_attention": "mx.attn.full",
+                 "sliding_attention": "mx.attn.window"}
+
+
 class HybridDecoderLayer(HybridBlock):
     """h + r * mixer(rms(h)), then h + r * ffn(rms(h)); `mixer` is a
-    Mamba-2 mixer or grouped-KV attention."""
+    Mamba-2 mixer or grouped-KV attention, `ffn` a SwiGLU of `hidden_size`
+    (None) or the block given (the sparse feed-forward)."""
 
     def __init__(self, kind, mixer, units, hidden_size, residual_multiplier,
-                 epsilon, **kwargs):
+                 epsilon, ffn=None, **kwargs):
         super().__init__(**kwargs)
-        self._mixer_scope = {"mamba": "mx.mamba", "attention": "mx.attn"}[kind]
+        self._mixer_scope = _MIXER_SCOPES[kind]
+        self._ffn_scope = "mx.ffn" if ffn is None else "mx.moe"
         self._r = residual_multiplier
         self.mixer_norm = nn.RMSNorm(epsilon=epsilon, in_channels=units)
         self.mixer = mixer
         self.ffn_norm = nn.RMSNorm(epsilon=epsilon, in_channels=units)
-        self.ffn = SwiGLU(units, hidden_size)
+        self.ffn = SwiGLU(units, hidden_size) if ffn is None else ffn
 
     def hybrid_forward(self, F, x):
         with jax.named_scope(self._mixer_scope):
             x = x + self._r * self.mixer(self.mixer_norm(x))
-        with jax.named_scope("mx.ffn"):
+        with jax.named_scope(self._ffn_scope):
             return x + self._r * self.ffn(self.ffn_norm(x))
 
 
 class HybridDecoder(HybridBlock):
-    """Token ids (B, T) -> logits (B, T, vocab_size) over the tied table.
+    """Token ids (B, T) -> logits (B, T, vocab_size) over the tied table, or
+    over a head of its own (`tie_head=False`).
 
-    `layer_types` lists "mamba" or "attention" per layer; `recompute` makes
-    every layer a recomputed block. A `vocab_size` below the published one
-    is this chip's rows of a table divided by rows: ids, logits and loss are
-    over the slice."""
+    `layer_types` lists "mamba", "attention" (causal, no positions: both
+    Granite's), "full_attention" or "sliding_attention" per layer;
+    `num_heads` is one number or a number per layer; `head_dim` the heads'
+    size where it is not units / num_heads; `window` the sliding kind's;
+    `rope` {"full_attention": .., "sliding_attention": ..} the rotary
+    parameters of each kind; `gate` the per-head output gate.
+    `mlp_layer_types` lists "dense" or "sparse" per layer (None: all dense,
+    of `hidden_size`); `moe` holds `HeldExpertsFFN`'s arguments. `recompute`
+    makes every layer a recomputed block. A `vocab_size` below the published
+    one is this chip's rows of a table divided by rows: ids, logits and loss
+    are over the slice."""
 
     def __init__(self, vocab_size, units, hidden_size, layer_types, num_heads,
-                 num_kv_heads, mamba_heads, mamba_head_dim, mamba_state,
-                 mamba_conv=4, mamba_groups=1, mamba_chunk=256,
-                 mamba_conv_bias=True, embedding_multiplier=1.0,
-                 residual_multiplier=1.0, attention_multiplier=None,
-                 logits_scaling=1.0, epsilon=1e-5, recompute=True, **kwargs):
+                 num_kv_heads, mamba_heads=None, mamba_head_dim=None,
+                 mamba_state=None, mamba_conv=4, mamba_groups=1,
+                 mamba_chunk=256, mamba_conv_bias=True,
+                 embedding_multiplier=1.0, residual_multiplier=1.0,
+                 attention_multiplier=None, logits_scaling=1.0, epsilon=1e-5,
+                 recompute=True, head_dim=None, window=None, rope=None,
+                 gate=False, mlp_layer_types=None, moe=None, tie_head=True,
+                 **kwargs):
         super().__init__(**kwargs)
         self._vocab, self._units = vocab_size, units
         self._e, self._l = embedding_multiplier, logits_scaling
         self.embed_weight = self.params.get("embed_weight",
                                             shape=(vocab_size, units))
+        self.head_weight = None if tie_head else self.params.get(
+            "head_weight", shape=(vocab_size, units))
         self.layers = nn.HybridSequential()
-        for kind in layer_types:
+        for i, kind in enumerate(layer_types):
+            heads = num_heads if isinstance(num_heads, int) else num_heads[i]
             if kind == "mamba":
                 mixer = Mamba2Mixer(units, mamba_heads, mamba_head_dim,
                                     mamba_state, mamba_conv, mamba_groups,
                                     mamba_chunk, mamba_conv_bias, epsilon)
             elif kind == "attention":
-                mixer = GroupedQueryAttention(units, num_heads, num_kv_heads,
+                mixer = GroupedQueryAttention(units, heads, num_kv_heads,
                                               attention_multiplier)
+            elif kind in ("full_attention", "sliding_attention"):
+                sliding = kind == "sliding_attention"
+                mixer = GroupedQueryAttention(
+                    units, heads, num_kv_heads, attention_multiplier,
+                    head_dim=head_dim, window=window if sliding else None,
+                    rope=(rope or {}).get(kind), gate=gate,
+                    kernel_scope="mx.flash.window" if sliding
+                    else "mx.flash.full")
             else:
-                raise ValueError(f"layer type {kind!r}: 'mamba' or 'attention'")
-            layer = HybridDecoderLayer(kind, mixer, units, hidden_size,
-                                       residual_multiplier, epsilon)
+                raise ValueError(
+                    f"layer type {kind!r}: 'mamba', 'attention', "
+                    "'full_attention' or 'sliding_attention'")
+            sparse = mlp_layer_types is not None \
+                and mlp_layer_types[i] == "sparse"
+            layer = HybridDecoderLayer(
+                kind, mixer, units, hidden_size, residual_multiplier, epsilon,
+                ffn=HeldExpertsFFN(units, **moe) if sparse else None)
             self.layers.add(layer.recompute() if recompute else layer)
         self.norm = nn.RMSNorm(epsilon=epsilon, in_channels=units)
 
-    def hybrid_forward(self, F, ids, embed_weight):
+    def hybrid_forward(self, F, ids, embed_weight, head_weight=None):
         with jax.named_scope("mx.embed"):
             h = F.Embedding(ids, embed_weight, input_dim=self._vocab,
                             output_dim=self._units) * self._e
         h = self.layers(h)
         with jax.named_scope("mx.head"):
-            return F.FullyConnected(self.norm(h), embed_weight, no_bias=True,
-                                    num_hidden=self._vocab,
-                                    flatten=False) / self._l
+            return F.FullyConnected(
+                self.norm(h),
+                embed_weight if head_weight is None else head_weight,
+                no_bias=True, num_hidden=self._vocab,
+                flatten=False) / self._l
 
 
 def hybrid_decoder_tiny(vocab_size=256, **kw):
@@ -230,5 +368,26 @@ def hybrid_decoder_tiny(vocab_size=256, **kw):
                 mamba_state=16, mamba_chunk=8, embedding_multiplier=12.0,
                 residual_multiplier=0.22, attention_multiplier=1 / 16,
                 logits_scaling=8.0)
+    args.update(kw)
+    return HybridDecoder(vocab_size, **args)
+
+
+def windowed_moe_decoder_tiny(vocab_size=256, **kw):
+    """A dense full-attention layer and then a sliding and a full layer over
+    sparse feed-forwards, at toy widths: 6 and 4 query heads of 16 over 2 KV
+    heads, window 8, partial YaRN and plain rotary positions, 4 of 16
+    experts held, 3 a token, beside a shared one; untied head."""
+    args = dict(
+        units=32, hidden_size=64,
+        layer_types=("full_attention", "sliding_attention", "full_attention"),
+        mlp_layer_types=("dense", "sparse", "sparse"), num_heads=(4, 6, 4),
+        num_kv_heads=2, head_dim=16, window=8, gate=True, tie_head=False,
+        rope={"full_attention": dict(base=500000.0, rotary_dim=8,
+                                     yarn_factor=8.0, yarn_original_length=32,
+                                     attention_factor=1.2),
+              "sliding_attention": dict(base=10000.0)},
+        moe=dict(expert_hidden=16, shared_hidden=16, held=4,
+                 published_experts=16, top_k=3, scaling=2.5),
+        epsilon=1e-6)
     args.update(kw)
     return HybridDecoder(vocab_size, **args)

@@ -26,3 +26,5 @@ from . import quantized  # noqa: F401
 from . import detection_extra  # noqa: F401
 from . import dgl_ops    # noqa: F401
 from . import ssm        # noqa: F401
+from . import rotary     # noqa: F401
+from . import moe        # noqa: F401

@@ -7,6 +7,12 @@ src/operator/contrib/transformer.cc:650-819). Implemented as lax.scan over
 key blocks with log-sum-exp accumulation in f32 — O(T) memory, MXU-sized
 matmul blocks; the ring variant rotates kv shards with ppermute so comm
 overlaps compute on the ICI ring.
+
+A causal call may take a `window` (PR 32): query t then sees the keys
+t - window < t' <= t. The Pallas kernels, the blockwise scan and the models'
+plain scores-softmax route apply the same mask; a windowed layer's route is
+counted as `flash_window` in `mx_attention_route_total`. Positions are not
+this module's: `ops/rotary.py` turns q and k before they come here.
 """
 from __future__ import annotations
 
@@ -43,8 +49,9 @@ def flash_min_seq() -> int:
 def count_route(route: str):
     """One count a traced (or eagerly run) attention layer in
     `mx_attention_route_total`: `plain` where `use_flash` said no, `flash`
-    where the layer went to `flash_attention`, `flash_partitioned` where that
-    wrapped its kernels in a shard_map over the trainer's batch axis. Counted
+    where the layer went to `flash_attention` (`flash_window` where it took
+    a window with it), `flash_partitioned` where that wrapped its kernels in
+    a shard_map over the trainer's batch axis. Counted
     while tracing; nothing of it is in the step."""
     if _telem._ENABLED:
         _telem.counter(
@@ -77,8 +84,10 @@ def _block_attn(q, k, v, bias, scale):
 
 
 def blockwise_attention(q, k, v, block_size: int = 512, causal: bool = False,
-                        scale: Optional[float] = None):
-    """Flash-style attention via lax.scan over key blocks."""
+                        scale: Optional[float] = None,
+                        window: Optional[int] = None):
+    """Flash-style attention via lax.scan over key blocks. `window` (with
+    `causal`): query t sees the keys t - window < t' <= t."""
     B, H, T, D = q.shape
     scale = scale if scale is not None else (1.0 / (D ** 0.5))
     block_size = min(block_size, k.shape[2])
@@ -100,6 +109,8 @@ def blockwise_attention(q, k, v, block_size: int = 512, causal: bool = False,
         mask = k_pos < Tk
         if causal:
             mask = jnp.logical_and(mask, q_pos >= k_pos)
+            if window is not None:
+                mask = jnp.logical_and(mask, q_pos - k_pos < window)
         bias = jnp.where(mask, 0.0, _NEG)[None, None]
         num, den, m = _block_attn(qf, kblk.astype(jnp.float32), vblk, bias, scale)
         new_max = jnp.maximum(acc_max, m)
@@ -119,14 +130,17 @@ def blockwise_attention(q, k, v, block_size: int = 512, causal: bool = False,
 
 
 @register("_contrib_flash_attention")
-def flash_attention_op(q, k, v, *, causal=False, block_size=512, scale=None):
+def flash_attention_op(q, k, v, *, causal=False, block_size=512, scale=None,
+                       window=None):
     """Registered op form so the eager autograd tape records its VJP.
     Dispatches to the Pallas TPU kernel (ops/pallas/flash_attention.py)
     when on TPU; the lax.scan blockwise path elsewhere. `scale` multiplies
-    q k^T (None: 1/sqrt(d))."""
+    q k^T (None: 1/sqrt(d)); `window` (with `causal`) keeps the keys
+    t - window < t' <= t of query t."""
     from .pallas.flash_attention import flash_attention as _pallas_flash
     return _pallas_flash(q, k, v, causal=causal, scale=scale,
-                         block_q=block_size, block_k=block_size)
+                         block_q=block_size, block_k=block_size,
+                         window=window)
 
 
 def ring_attention(q, k, v, axis_name: str, causal: bool = False,

@@ -12,11 +12,20 @@ ZeRO gradient collectives use (EQuARX, arXiv:2506.17615) — see
 
 End-to-end training of these layers lives in ``mxnet_tpu.recipes.moe``
 (docs/large_models.md); this module stays a pure function library.
+
+Beside the capacity-gated layers stands ``held_moe_ffn`` (PR 32): the
+dropless layer of a chip that is told which experts of a published layer it
+holds. It routes over all of them, keeps every assignment that falls on its
+own and computes their part of the sum by grouped products; on one chip it
+has no exchange. It is an op (``_contrib_held_moe_ffn``) of gluon blocks on
+the normal path (``models/hybrid_decoder.py`` through
+``DataParallelTrainer``), not of ``MoETrainer``.
 """
 from __future__ import annotations
 
 import contextlib
 import functools
+import math
 from typing import List, Optional, Tuple
 
 import jax
@@ -290,6 +299,127 @@ def load_balancing_loss(logits, top_k: int = 2):
     me = jnp.mean(probs, axis=0)                            # mean router prob
     ce = jnp.mean(jax.nn.one_hot(idx[:, 0], E), axis=0)     # token fraction
     return E * jnp.sum(me * ce)
+
+
+# ---------------------------------------------------------------------------
+# The dropless layer of one chip's share of the experts
+# ---------------------------------------------------------------------------
+
+def held_rows(n_tokens: int, top_k: int, held: int, published_experts: int):
+    """(the rows of the sorted path's buffer; the most assignments that can
+    fall on the experts held). The buffer is half again what uniform routing
+    sends here (n_tokens * top_k * held / published_experts), rounded up to
+    a power of two; the bound is every token on as many held experts as it
+    may choose."""
+    most = n_tokens * min(top_k, held)
+    expected = n_tokens * top_k * held / published_experts
+    rows = 1 << max(math.ceil(1.5 * expected) - 1, 0).bit_length()
+    return min(rows, most), most
+
+
+def held_moe_ffn(x, router_w, w_gate_up, w_down, *, top_k: int,
+                 published_experts: int, first_held: int = 0,
+                 scaling: float = 1.0, return_aux: bool = False):
+    """Dropless top-k expert layer of a chip that holds `held` of the
+    `published_experts` experts of a layer, `first_held` onwards: one
+    chip's part of an expert-parallel layer, without its exchange.
+
+    x (N, D); router_w (published_experts, D); w_gate_up (held, D, 2 F);
+    w_down (held, F, D): SwiGLU experts, `[g, u] = x W_gate_up`,
+    `W_down (silu(g) * u)`. Returns (N, D) in x's type:
+
+        p = softmax(x router_w^T) over ALL published experts, in float32
+        S = the top_k largest;  w_e = scaling * p_e / sum_{e' in S} p_e'
+        y = sum_{e in S, e held here} w_e ffn_e(x)
+
+    What the experts held elsewhere would add is left out: it is their
+    chips' part of the sum. No assignment to a held expert is ever dropped,
+    whatever the routing, and shapes stay static: the kept assignments are
+    sorted by expert, and where the row buffer of `held_rows` holds them all
+    its tokens are gathered, multiplied by groups (`lax.ragged_dot`: the
+    products cost in proportion to the buffer, not to tokens x held) and the
+    weighted rows scatter-added. Where a step's routing sends more here than
+    the buffer holds, a `lax.cond` takes the exact dense path instead: every
+    held expert over every token, times its combine weight (zero where not
+    routed). Where the buffer reaches the bound there is no dense path. Both
+    paths are recomputed in the backward pass (`jax.checkpoint`), so the
+    `cond` keeps neither's residuals.
+
+    `return_aux`: also {"kept": assignments that fell on held experts,
+    "max_load", "mean_load": of a held expert, "exact": whether the dense
+    path ran}, device values that nothing has to read back."""
+    N, D = x.shape
+    held, _, two_f = w_gate_up.shape
+    F = two_f // 2
+    if router_w.shape[0] != published_experts \
+            or first_held + held > published_experts:
+        raise ValueError(
+            f"a router over {router_w.shape[0]} experts and experts "
+            f"{first_held}..{first_held + held - 1} held, of a layer that "
+            f"publishes {published_experts}")
+    n_rows, most = held_rows(N, top_k, held, published_experts)
+    f32 = jnp.float32
+
+    with jax.named_scope("mx.moe.route"):
+        logits = jnp.dot(x.astype(f32), router_w.astype(f32).T,
+                         precision=lax.Precision.HIGHEST)
+        vals, idx = lax.top_k(jax.nn.softmax(logits, axis=-1), top_k)
+        weight = scaling * vals / jnp.sum(vals, axis=-1, keepdims=True)
+        local = idx.astype(jnp.int32) - first_held           # (N, k)
+        here = jnp.logical_and(local >= 0, local < held)
+        # kept assignments first, by expert; the others behind them
+        key = jnp.where(here, local, held).reshape(-1)
+        counts = jnp.sum(key[:, None] == jnp.arange(held)[None, :], axis=0,
+                         dtype=jnp.int32)                     # (held,)
+        kept = jnp.sum(counts)
+        skey, order = lax.sort_key_val(
+            key, jnp.arange(N * top_k, dtype=jnp.int32))
+
+    def swiglu(rows_in, gate_up, down):
+        gu = gate_up(rows_in)
+        return down((jax.nn.silu(gu[:, :F]) * gu[:, F:]).astype(x.dtype))
+
+    def sorted_rows(x, w_gate_up, w_down, weight):
+        with jax.named_scope("mx.moe.experts"):
+            chosen, valid = order[:n_rows], skey[:n_rows] < held
+            token = chosen // top_k
+            # the buffer's unused tail goes to the last group with its rows
+            # zeroed, so the groups cover every row (what a grouped product
+            # does with rows past its groups is its own affair: on the TPU
+            # it leaves them as they were) and the tail adds nothing
+            groups = counts.at[held - 1].add(jnp.maximum(n_rows - kept, 0))
+            y = swiglu(jnp.where(valid[:, None], x[token], 0),
+                       lambda a: lax.ragged_dot(a, w_gate_up, groups),
+                       lambda a: lax.ragged_dot(a, w_down, groups))
+            w_row = jnp.where(valid, weight.reshape(-1)[chosen], 0.0)
+            y = y.astype(f32) * w_row[:, None]
+            return jnp.zeros((N, D), f32).at[token].add(y).astype(x.dtype)
+
+    def every_row(x, w_gate_up, w_down, weight):
+        with jax.named_scope("mx.moe.experts"):
+            combine = jnp.sum(jnp.where(
+                local[:, :, None] == jnp.arange(held)[None, None, :],
+                weight[:, :, None], 0.0), axis=1)             # (N, held)
+
+            def one(acc, ws):
+                gate_up, down, w_e = ws
+                y = swiglu(x, lambda a: a @ gate_up, lambda a: a @ down)
+                return acc + w_e[:, None] * y.astype(f32), None
+
+            acc, _ = lax.scan(one, jnp.zeros((N, D), f32),
+                              (w_gate_up, w_down, combine.T))
+            return acc.astype(x.dtype)
+
+    exact = kept > n_rows
+    if n_rows < most:
+        y = lax.cond(exact, jax.checkpoint(every_row),
+                     jax.checkpoint(sorted_rows), x, w_gate_up, w_down, weight)
+    else:
+        y = jax.checkpoint(sorted_rows)(x, w_gate_up, w_down, weight)
+    if not return_aux:
+        return y
+    return y, {"kept": kept, "max_load": jnp.max(counts),
+               "mean_load": kept.astype(f32) / held, "exact": exact}
 
 
 # ---------------------------------------------------------------------------

@@ -533,6 +533,25 @@ def test_flash_kernels_compile_for_v5e(building_for_tpu, one_chip, BH, T,
     assert f"f32[{BH},1,{T}]" in text and f"f32[{BH},{T},1]" not in text
 
 
+@pytest.mark.parametrize("BH,window", [
+    pytest.param(72, 512, id="laguna_sliding_layer"),
+    pytest.param(48, None, id="laguna_full_layer"),
+])
+def test_flash_kernels_compile_for_v5e_at_t8192(building_for_tpu, one_chip,
+                                                BH, window):
+    """laguna_s_2_1_train_t8192's two kinds of layer: heads of 128 over
+    8,192 positions stream in two chunks a side, with and without the
+    window of 512."""
+    x = jax.ShapeDtypeStruct((1, BH, 8192, 128), jnp.bfloat16,
+                             sharding=one_chip)
+    grad = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+        q, k, v, causal=True, window=window).astype(jnp.float32)),
+        argnums=(0, 1, 2))
+    text = jax.jit(grad).lower(x, x, x).compile().as_text()
+    assert text.count("tpu_custom_call") >= 2
+    assert f"f32[{BH},1,8192]" in text
+
+
 @contextlib.contextmanager
 def trainer_context(mesh, axis="dp"):
     """What DataParallelTrainer._build_step sets round its traced body."""
