@@ -11,8 +11,9 @@ overlaps compute on the ICI ring.
 A causal call may take a `window` (PR 32): query t then sees the keys
 t - window < t' <= t. The Pallas kernels, the blockwise scan and the models'
 plain scores-softmax route apply the same mask; a windowed layer's route is
-counted as `flash_window` in `mx_attention_route_total`. Positions are not
-this module's: `ops/rotary.py` turns q and k before they come here.
+counted as `flash_window` in `mx_attention_route_total`, and the schedule
+the kernels chose for a layer in `mx_attention_schedule_total` (PR 33).
+Positions are not this module's: `ops/rotary.py` turns q and k before they come here.
 """
 from __future__ import annotations
 
@@ -46,18 +47,27 @@ def flash_min_seq() -> int:
     return env.get("MXNET_FLASH_ATTENTION_MIN_SEQ")
 
 
-def count_route(route: str):
+def count_route(route: str, schedule: Optional[str] = None):
     """One count a traced (or eagerly run) attention layer in
     `mx_attention_route_total`: `plain` where `use_flash` said no, `flash`
     where the layer went to `flash_attention` (`flash_window` where it took
     a window with it), `flash_partitioned` where that wrapped its kernels in
-    a shard_map over the trainer's batch axis. Counted
+    a shard_map over the trainer's batch axis. Where the layer took the
+    Pallas kernels, `mx_attention_schedule_total` counts beside it the
+    schedule their plan chose from the call's shapes (`resident`, `band`,
+    `tiled`: `ops/pallas/flash_attention.py: _plan`). Counted
     while tracing; nothing of it is in the step."""
     if _telem._ENABLED:
         _telem.counter(
             "mx_attention_route_total",
             "Attention layers traced, by the route they took",
             ("route",)).labels(route).inc()
+        if schedule is not None:
+            _telem.counter(
+                "mx_attention_schedule_total",
+                "Attention layers traced onto the flash kernels, by the "
+                "schedule the kernels' plan chose",
+                ("schedule",)).labels(schedule).inc()
 
 
 def use_flash(seq_len: int) -> bool:

@@ -118,7 +118,7 @@ def test_flash_bf16(interpret_mode):
 # ---------------------------------------------------------------------------
 # the schedule's branches: resident heads (several a grid step, more than one
 # grid step), loops that stop at the diagonal, padded lengths, T != Tk, and
-# the streamed route that long or wide sequences take
+# the chunk pairs (`tiled`) that long or wide sequences are cut into
 # ---------------------------------------------------------------------------
 
 def _fa():
@@ -131,46 +131,54 @@ def _fa():
 def _plans(BH, T, Tk, D, dtype, causal, limit=512):
     """The forward's and the backward's (q chunks, k chunks, heads a step)
     as `flash_attention` would plan them: one chunk a side is the resident
-    route."""
+    schedule."""
     fa = _fa()
     bq, bk = fa._block(T, limit), fa._block(Tk, limit)
-    return [(n_qc, n_kc, G) for _, n_qc, _, n_kc, G, _ in (
+    return [(p.n_qc, p.n_kc, p.G) for p in (
         fa._plan(backward, BH, T, Tk, D, jnp.dtype(dtype), causal, bq, bk)
         for backward in (False, True))]
 
 
-# (B*H, T, Tk, D, causal, dtype, streamed, least grid steps of several heads)
+# (B*H, T, Tk, D, causal, dtype, chunks a side (q, k), least grid steps of
+# several heads)
 _SCHEDULE_CASES = [
-    pytest.param(8, 1024, 1024, 64, False, np.float32, False, 2,
+    pytest.param(8, 1024, 1024, 64, False, np.float32, (1, 1), 2,
                  id="bert_cell_shape"),
-    pytest.param(4, 2048, 2048, 64, True, np.float32, False, 0,
+    pytest.param(4, 2048, 2048, 64, True, np.float32, (1, 1), 0,
                  id="granite_cell_shape"),
-    pytest.param(8, 1024, 1024, 64, False, jnp.bfloat16, False, 2,
+    pytest.param(8, 1024, 1024, 64, False, jnp.bfloat16, (1, 1), 2,
                  id="bert_cell_shape_bf16"),
-    pytest.param(4, 2048, 2048, 64, True, jnp.bfloat16, False, 2,
+    pytest.param(4, 2048, 2048, 64, True, jnp.bfloat16, (1, 1), 2,
                  id="granite_cell_shape_bf16"),
-    pytest.param(2, 1000, 1000, 64, True, np.float32, False, 0,
+    pytest.param(2, 1000, 1000, 64, True, np.float32, (1, 1), 0,
                  id="padded_causal"),
-    pytest.param(2, 300, 700, 32, False, np.float32, False, 0,
+    pytest.param(2, 300, 700, 32, False, np.float32, (1, 1), 0,
                  id="cross_attention"),
-    pytest.param(2, 4096, 4096, 64, True, jnp.bfloat16, False, 0,
-                 id="resident_too_long_to_unroll"),
-    pytest.param(1, 2400, 2400, 128, True, np.float32, True, 0,
-                 id="streamed_causal"),
-    pytest.param(1, 640, 2400, 128, False, np.float32, True, 0,
-                 id="streamed_keys_cross"),
+    # 64 block pairs, causal: two chunks of 2,048 a side, three live pairs
+    pytest.param(2, 4096, 4096, 64, True, jnp.bfloat16, (2, 2), 0,
+                 id="causal_too_long_to_unroll"),
+    # chunks of 1,536: a diagonal, a below-diagonal and a dead pair, T padded
+    pytest.param(1, 2400, 2400, 128, True, np.float32, (2, 2), 0,
+                 id="tiled_causal"),
+    pytest.param(1, 640, 2400, 128, False, np.float32, (1, 2), 0,
+                 id="tiled_keys_cross"),
+    pytest.param(1, 2400, 640, 128, False, np.float32, (2, 1), 0,
+                 id="tiled_queries_cross"),
+    # not causal, resident and of 25 block pairs: rolled, its bounds static
+    pytest.param(2, 2400, 2400, 64, False, jnp.bfloat16, (1, 1), 0,
+                 id="resident_rolled"),
 ]
 
 
-@pytest.mark.parametrize("BH,T,Tk,D,causal,dtype,streamed,min_steps",
+@pytest.mark.parametrize("BH,T,Tk,D,causal,dtype,chunks,min_steps",
                          _SCHEDULE_CASES)
 def test_flash_schedule_branches(interpret_mode, BH, T, Tk, D, causal, dtype,
-                                 streamed, min_steps):
+                                 chunks, min_steps):
     """Forward and backward of every branch of the schedule against the
     dense softmax in float32; bfloat16 inputs at test_flash_bf16's
     tolerance."""
     (n_q, n_k, G), _ = _plans(BH, T, Tk, D, dtype, causal)
-    assert (n_q > 1 or n_k > 1) == streamed, (n_q, n_k)
+    assert (n_q, n_k) == chunks
     if min_steps:
         # several heads a forward step, and more than one grid step
         assert G >= 2 and BH // G >= min_steps, (BH, G)
@@ -221,13 +229,16 @@ def test_flash_residual_statistics_are_lane_dense(interpret_mode):
 def test_flash_plan_at_the_cells_shapes():
     fa = _fa()
     bf16 = jnp.bfloat16
-    # resident up to T = 4096 at d = 64 in bfloat16, streamed beyond; the
+    # resident while a head's rows fit and both walks unroll (up to 16 block
+    # pairs under `causal`), in chunk pairs of 4 x 4 blocks beyond; the
     # cells: four heads a forward step and two a backward step (t1024), two
     # and one under the causal mask at T = 2048 (granite)
     assert _plans(192, 1024, 1024, 64, bf16, False) == [(1, 1, 4), (1, 1, 2)]
     assert _plans(64, 2048, 2048, 64, bf16, True) == [(1, 1, 2), (1, 1, 1)]
-    assert _plans(16, 4096, 4096, 64, bf16, True)[1][:2] == (1, 1)
-    assert _plans(8, 8192, 8192, 64, bf16, True) == [(2, 2, 1), (2, 2, 1)]
+    assert _plans(16, 4096, 4096, 64, bf16, False)[1][:2] == (1, 1)
+    assert _plans(16, 4096, 4096, 64, bf16, True) == [(2, 2, 1), (2, 2, 1)]
+    assert _plans(8, 8192, 8192, 64, bf16, True) == [(4, 4, 1), (4, 4, 1)]
+    assert _plans(8, 8192, 8192, 64, bf16, False) == [(4, 4, 1), (4, 4, 1)]
     for BH in (192, 64, 7, 1):
         for flops in (1e6, 3e8, 1e10):
             G = fa._heads_per_step(BH, 2 << 20, flops)
@@ -540,8 +551,8 @@ def test_flash_kernels_compile_for_v5e(building_for_tpu, one_chip, BH, T,
 def test_flash_kernels_compile_for_v5e_at_t8192(building_for_tpu, one_chip,
                                                 BH, window):
     """laguna_s_2_1_train_t8192's two kinds of layer: heads of 128 over
-    8,192 positions stream in two chunks a side, with and without the
-    window of 512."""
+    8,192 positions, the window of 512 as a band in two chunks, the full
+    causal head in 4 x 4 chunk pairs."""
     x = jax.ShapeDtypeStruct((1, BH, 8192, 128), jnp.bfloat16,
                              sharding=one_chip)
     grad = jax.grad(lambda q, k, v: jnp.sum(flash_attention(
