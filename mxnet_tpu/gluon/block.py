@@ -359,16 +359,22 @@ class HybridBlock(Block):
     # whether `recompute()` was asked of this block (an instance attribute
     # once set, so it enters the structural fingerprint of those blocks only)
     _recompute = False
+    _recompute_keep = ()
 
-    def recompute(self, active=True):
+    def recompute(self, active=True, keep=()):
         """Recompute this block's forward in the backward pass instead of
         keeping its activations, wherever the block is traced into an
         enclosing program (a fused trainer's step, a hybridized parent):
         only the block's inputs live from the forward pass to the backward
         one. A property of the model, set where the model is built; the
         eager tape is not affected (its `MXNET_TPU_REMAT_BWD` is its own).
-        Returns the block."""
+        `keep`: names (`jax.ad_checkpoint.checkpoint_name`) of values inside
+        the block that are carried from the forward pass all the same and
+        not computed again: a small discrete result (the keys a learned
+        sparse attention selected) that a second computation must not be
+        free to round otherwise. Returns the block."""
         self._recompute = bool(active)
+        self._recompute_keep = tuple(keep)
         self.clear_cache()
         return self
 
@@ -401,7 +407,10 @@ class HybridBlock(Block):
             aux_params[:] = [p for p, _ in aux]
             return tuple(flat), tuple(v for _, v in aux)
 
-        flat, aux_vals = jax.checkpoint(run)(*[raw[i] for i in traced])
+        policy = {"policy": jax.checkpoint_policies.save_only_these_names(
+            *self._recompute_keep)} if self._recompute_keep else {}
+        flat, aux_vals = jax.checkpoint(run, **policy)(
+            *[raw[i] for i in traced])
         for p, v in zip(aux_params, aux_vals):
             defer_aux_update(p, v)
         return jax.tree_util.tree_unflatten(

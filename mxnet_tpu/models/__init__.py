@@ -20,7 +20,8 @@ from .moe_transformer import (MoEPositionwiseFFN, MoETransformerCell,
                               MoETransformerLM, moe_transformer_tiny)
 from .hybrid_decoder import (HybridDecoder, HybridDecoderLayer, Mamba2Mixer,
                              GroupedQueryAttention, SwiGLU, HeldExpertsFFN,
-                             hybrid_decoder_tiny, windowed_moe_decoder_tiny)
+                             hybrid_decoder_tiny, windowed_moe_decoder_tiny,
+                             indexed_moe_decoder_tiny)
 
 __all__ = ["get_model", "LeNet", "lenet", "MLP", "mlp", "BertModel",
            "BertEncoder", "TransformerEncoderCell", "bert_base", "bert_large",
@@ -28,4 +29,4 @@ __all__ = ["get_model", "LeNet", "lenet", "MLP", "mlp", "BertModel",
            "MoETransformerLM", "moe_transformer_tiny", "HybridDecoder",
            "HybridDecoderLayer", "Mamba2Mixer", "GroupedQueryAttention",
            "SwiGLU", "HeldExpertsFFN", "hybrid_decoder_tiny",
-           "windowed_moe_decoder_tiny"]
+           "windowed_moe_decoder_tiny", "indexed_moe_decoder_tiny"]

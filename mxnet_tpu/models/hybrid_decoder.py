@@ -3,7 +3,10 @@ layers and grouped-KV attention layers side by side (IBM Granite 4.0-H,
 HF `GraniteMoeHybrid`, with no experts); and, since PR 32, full and sliding
 window attention layers with per-layer head counts, rotary positions and a
 per-head output gate, over a dense or a sparse (routed experts beside a
-shared one) feed-forward, with a head of its own (poolside Laguna).
+shared one) feed-forward, with a head of its own (poolside Laguna); and,
+since PR 34, attention over the keys a learned indexer selects for each
+query, with a QK norm and sectioned rotary positions, over routed experts
+without a shared one (Kwai Keye-VL-2.0's language model).
 
 No reference counterpart (MXNet 1.x has neither state-space layers nor
 grouped KV heads). With `e`, `r`, `s`, `l` the embedding, residual, attention
@@ -29,11 +32,25 @@ heads of `head_dim` d over the KV heads, window W on the sliding kind):
            sliding kind also t - t' < W
     mixer = W_o concat_j(sigmoid(a W_g)_j * o_j)  the gate: one number a head
 
+The indexed kind (`"indexed_attention"`; `ops/sparse_select.py` has the
+indexer's equations and how the set is found and carried):
+
+    q, k = rope(rms_head(a Wq)), rope(rms_head(a Wk))   QK norm: each head's
+           d numbers normed, one weight for all query heads, one for all key
+           heads; positions in sections (`ops/rotary.py`)
+    S_t  = the top_k keys t' <= t of largest indexer score I[t, t'], from
+           qI, kI = layer_norm(..), wI projected from stop_gradient(a);
+           the indexer's leaves are frozen (`grad_req` null): a top-k hands
+           no gradient back
+    o_j  = softmax over t' in S_t of (q_j k^T / sqrt(d)) v
+
 and the sparse feed-forward (`mlp_layer_types[l] == "sparse"`), of which
 this chip holds `held` of the published experts (`parallel/moe.py:
 held_moe_ffn` has the routing):
 
     ffn(b) = scaling * sum_{e in top-k, e held} w_e ffn_e(b) + ffn_shared(b)
+
+(no `ffn_shared` where the model has none: `shared_hidden=None`).
 
 Every decoder layer may be a recomputed block (`HybridBlock.recompute`): under
 a fused trainer's step only the layers' inputs live from the forward pass to
@@ -43,7 +60,10 @@ the backward one, which is what lets a model of this width train on one chip.
 `mx.head`; the new kinds' mixers are `mx.attn.full` and `mx.attn.window`
 (with `mx.rope` and the kernel call alone, `mx.flash.full` or
 `mx.flash.window`, inside), the sparse feed-forward `mx.moe` (with
-`mx.moe.route`, `mx.moe.experts` and `mx.moe.shared` inside it).
+`mx.moe.route`, `mx.moe.experts` and `mx.moe.shared` inside it); the indexed
+kind's mixer is `mx.attn.sparse`, with `mx.qknorm`, `mx.rope`, `mx.index`
+(the indexer's projections, `mx.index.score`, `mx.index.select`,
+`mx.index.unpack`) and the kernel call alone, `mx.flash.select`, inside.
 """
 from __future__ import annotations
 
@@ -56,10 +76,12 @@ from ..gluon.block import HybridBlock, defer_aux_update
 from ..gluon import nn
 from ..ops import attention as _attn_ops
 from ..ops.moe import HELD_REPORT
+from ..ops.sparse_select import KEEP_NAME, SELECT_REPORT
 
 __all__ = ["Mamba2Mixer", "GroupedQueryAttention", "SwiGLU",
            "HeldExpertsFFN", "HybridDecoderLayer", "HybridDecoder",
-           "hybrid_decoder_tiny", "windowed_moe_decoder_tiny"]
+           "hybrid_decoder_tiny", "windowed_moe_decoder_tiny",
+           "indexed_moe_decoder_tiny"]
 
 
 def _dense(units, in_units):
@@ -132,11 +154,27 @@ class GroupedQueryAttention(HybridBlock):
     arXiv:2505.06708). Through the flash kernels where
     `ops.attention.use_flash(T)` says so, as models/bert.py (route `flash`,
     or `flash_window` with a window); the plain scores-softmax route takes
-    the same mask. `kernel_scope` names the kernel call alone in a trace."""
+    the same mask. `kernel_scope` names the kernel call alone in a trace.
+
+    `qk_norm`: the epsilon of an RMS norm over each head of q and of k, one
+    weight for all query heads and one for all key heads, before the
+    positions (None: none). `sections`: the rotary positions come in that
+    many rows, each turning its section of the frequency pairs
+    (`_contrib_rotary_embedding`'s `sections`; the rows are the text's).
+    `indexer`: {"heads", "head_dim", "top_k", "chunk", "rope", "epsilon"}: a
+    query attends to the `top_k` keys that an indexer of `heads` small heads
+    over one key head selects (`_contrib_indexer_select`; route
+    `flash_select`), from the layer's input with its gradient stopped. The
+    indexer's leaves are frozen, since a top-k hands no gradient back, and
+    ride a fused step as weights without optimizer state. `selection` is
+    state like `HeldExpertsFFN.routing`: the last training step's report in
+    the order of `ops.sparse_select.SELECT_REPORT` (keys kept a query, tiles
+    of the selection with nothing kept)."""
 
     def __init__(self, units, num_heads, num_kv_heads, scale=None,
                  head_dim=None, window=None, rope=None, gate=False,
-                 kernel_scope=None, **kwargs):
+                 kernel_scope=None, qk_norm=None, sections=None,
+                 indexer=None, **kwargs):
         super().__init__(**kwargs)
         assert num_heads % num_kv_heads == 0
         if head_dim is None:
@@ -147,29 +185,80 @@ class GroupedQueryAttention(HybridBlock):
         self._scale = float(scale) if scale is not None else d ** -0.5
         self._window = None if window is None else int(window)
         self._rope = None if rope is None else dict(rope)
+        if sections is not None:
+            self._rope["sections"] = tuple(sections)
         self._kernel_scope = kernel_scope
         self.query = _dense(num_heads * d, units)
         self.key = _dense(num_kv_heads * d, units)
         self.value = _dense(num_kv_heads * d, units)
         self.gate = _dense(num_heads, units) if gate else None
         self.proj = _dense(units, num_heads * d)
+        self.query_norm = self.key_norm = None
+        if qk_norm is not None:
+            self.query_norm = nn.RMSNorm(epsilon=qk_norm, in_channels=d)
+            self.key_norm = nn.RMSNorm(epsilon=qk_norm, in_channels=d)
+        self._indexer = None if indexer is None else dict(indexer)
+        if indexer is not None:
+            hi, di = indexer["heads"], indexer["head_dim"]
+            self._indexer.setdefault("chunk", 512)
+            self.index_query = _dense(hi * di, units)
+            self.index_key = _dense(di, units)
+            self.index_key_norm = nn.LayerNorm(
+                epsilon=indexer.get("epsilon", 1e-6), in_channels=di)
+            self.index_weight = _dense(hi, units)
+            for block in (self.index_query, self.index_key,
+                          self.index_key_norm, self.index_weight):
+                for p in block.collect_params().values():
+                    p.grad_req = "null"
+            self.selection = self.params.get(
+                "selection", shape=(len(SELECT_REPORT),), init="zeros",
+                grad_req="null", differentiable=False)
 
-    def hybrid_forward(self, F, x):
+    def cast(self, dtype):
+        super().cast(dtype)
+        if self._indexer is not None:
+            self.selection.cast("float32")      # a count and a mean of counts
+
+    def _select(self, F, x):
+        """int8 (B, T, T): the keys the indexer keeps for each query."""
+        ix = self._indexer
+        with jax.named_scope("mx.index"):
+            a = F.stop_gradient(x)
+            qi = F.transpose(F.reshape(self.index_query(a),
+                                       shape=(0, 0, -4, -1, ix["head_dim"])),
+                             axes=(0, 2, 1, 3))              # (B, Hi, T, di)
+            ki = F.expand_dims(self.index_key_norm(self.index_key(a)), axis=1)
+            if ix.get("rope") is not None:
+                qi, ki = (F._contrib_rotary_embedding(t, **ix["rope"])
+                          for t in (qi, ki))
+            packed, report = F._contrib_indexer_select(
+                qi, F.reshape(ki, shape=(0, -3, 0)), self.index_weight(a),
+                top_k=ix["top_k"], chunk=ix["chunk"])
+            if autograd.is_training() or autograd.is_recording():
+                defer_aux_update(self.selection, report._data)
+            with jax.named_scope("mx.index.unpack"):
+                return F._contrib_selection_unpack(packed, keys=x.shape[1])
+
+    def hybrid_forward(self, F, x, selection=None):
         H, d, rep = self._heads, self._d, self._heads // self._kv_heads
         q, k, v = (F.transpose(F.reshape(p(x), shape=(0, 0, -4, -1, d)),
                                axes=(0, 2, 1, 3))           # (B, heads, T, d)
                    for p in (self.query, self.key, self.value))
+        if self.query_norm is not None:
+            with jax.named_scope("mx.qknorm"):
+                q, k = self.query_norm(q), self.key_norm(k)
         if self._rope is not None:
             q, k = (F._contrib_rotary_embedding(a, **self._rope)
                     for a in (q, k))
         if rep > 1:
             k, v = F.repeat(k, repeats=rep, axis=1), \
                 F.repeat(v, repeats=rep, axis=1)
+        select = () if self._indexer is None else (self._select(F, x),)
         if _attn_ops.use_flash(x.shape[1]):
             with jax.named_scope(self._kernel_scope) if self._kernel_scope \
                     else contextlib.nullcontext():
                 out = F._contrib_flash_attention(
-                    q, k, v, causal=True, scale=self._scale,
+                    q, k, v, *select, causal=True, scale=self._scale,
                     window=self._window)
         else:
             q2, k2, v2 = (F.reshape(a, shape=(-3, 0, 0)) for a in (q, k, v))
@@ -183,9 +272,13 @@ class GroupedQueryAttention(HybridBlock):
                     F.expand_dims(pos, axis=1) - self._window,
                     F.expand_dims(pos, axis=0))
                 ahead = ahead + behind
-            scores = F.broadcast_add(
-                scores, F.expand_dims(F.cast(ahead * -1e30,
-                                             dtype=scores.dtype), axis=0))
+            bias = F.expand_dims(F.cast(ahead * -1e30, dtype=scores.dtype),
+                                 axis=0)
+            if select:      # (B, T, T) -> a row of it for each of its heads
+                bias = F.broadcast_add(bias, F.repeat(
+                    F.cast(select[0] == 0, dtype=scores.dtype) * -1e30,
+                    repeats=H, axis=0))
+            scores = F.broadcast_add(scores, bias)
             out = F.batch_dot(F.softmax(scores, axis=-1), v2)
             out = F.reshape(out, shape=(-4, -1, H, 0, 0))   # (B, H, T, d)
         out = F.transpose(out, axes=(0, 2, 1, 3))           # (B, T, H, d)
@@ -218,7 +311,8 @@ class HeldExpertsFFN(HybridBlock):
     expert whole: a router over all the published experts, `top_k` a token,
     their weights renormalised over the `top_k` and scaled by `scaling`; the
     held experts' part of the sum (`_contrib_held_moe_ffn`: dropless) beside
-    the shared expert's output, ungated. Every expert is a SwiGLU.
+    the shared expert's output, ungated (`shared_hidden=None`: the model has
+    no shared expert). Every expert is a SwiGLU.
 
     `routing` is state, not a weight: the last training step's report of the
     layer in the order of `ops.moe.HELD_REPORT` (assignments kept here, the
@@ -241,7 +335,8 @@ class HeldExpertsFFN(HybridBlock):
         self.routing = self.params.get(
             "routing", shape=(len(HELD_REPORT),), init="zeros",
             grad_req="null", differentiable=False)
-        self.shared = SwiGLU(units, shared_hidden)
+        self.shared = None if shared_hidden is None \
+            else SwiGLU(units, shared_hidden)
 
     def cast(self, dtype):
         super().cast(dtype)
@@ -253,13 +348,16 @@ class HeldExpertsFFN(HybridBlock):
                                             experts_down, **self._op)
         if autograd.is_training() or autograd.is_recording():
             defer_aux_update(self.routing, report._data)
+        if self.shared is None:
+            return y
         with jax.named_scope("mx.moe.shared"):
             return y + self.shared(x)
 
 
 _MIXER_SCOPES = {"mamba": "mx.mamba", "attention": "mx.attn",
                  "full_attention": "mx.attn.full",
-                 "sliding_attention": "mx.attn.window"}
+                 "sliding_attention": "mx.attn.window",
+                 "indexed_attention": "mx.attn.sparse"}
 
 
 class HybridDecoderLayer(HybridBlock):
@@ -290,7 +388,9 @@ class HybridDecoder(HybridBlock):
     over a head of its own (`tie_head=False`).
 
     `layer_types` lists "mamba", "attention" (causal, no positions: both
-    Granite's), "full_attention" or "sliding_attention" per layer;
+    Granite's), "full_attention", "sliding_attention" or
+    "indexed_attention" (over the keys an indexer selects: `indexer`, with
+    `qk_norm` and `sections` `GroupedQueryAttention`'s arguments) per layer;
     `num_heads` is one number or a number per layer; `head_dim` the heads'
     size where it is not units / num_heads; `window` the sliding kind's;
     `rope` {"full_attention": .., "sliding_attention": ..} the rotary
@@ -309,7 +409,7 @@ class HybridDecoder(HybridBlock):
                  attention_multiplier=None, logits_scaling=1.0, epsilon=1e-5,
                  recompute=True, head_dim=None, window=None, rope=None,
                  gate=False, mlp_layer_types=None, moe=None, tie_head=True,
-                 **kwargs):
+                 qk_norm=False, sections=None, indexer=None, **kwargs):
         super().__init__(**kwargs)
         self._vocab, self._units = vocab_size, units
         self._e, self._l = embedding_multiplier, logits_scaling
@@ -335,16 +435,27 @@ class HybridDecoder(HybridBlock):
                     rope=(rope or {}).get(kind), gate=gate,
                     kernel_scope="mx.flash.window" if sliding
                     else "mx.flash.full")
+            elif kind == "indexed_attention":
+                mixer = GroupedQueryAttention(
+                    units, heads, num_kv_heads, attention_multiplier,
+                    head_dim=head_dim, rope=(rope or {}).get(kind), gate=gate,
+                    kernel_scope="mx.flash.select",
+                    qk_norm=epsilon if qk_norm else None, sections=sections,
+                    indexer=indexer)
             else:
                 raise ValueError(
                     f"layer type {kind!r}: 'mamba', 'attention', "
-                    "'full_attention' or 'sliding_attention'")
+                    "'full_attention', 'sliding_attention' or "
+                    "'indexed_attention'")
             sparse = mlp_layer_types is not None \
                 and mlp_layer_types[i] == "sparse"
             layer = HybridDecoderLayer(
                 kind, mixer, units, hidden_size, residual_multiplier, epsilon,
                 ffn=HeldExpertsFFN(units, **moe) if sparse else None)
-            self.layers.add(layer.recompute() if recompute else layer)
+            # an indexed layer carries its selection to the backward pass
+            keep = (KEEP_NAME,) if kind == "indexed_attention" else ()
+            self.layers.add(layer.recompute(keep=keep) if recompute
+                            else layer)
         self.norm = nn.RMSNorm(epsilon=epsilon, in_channels=units)
 
     def hybrid_forward(self, F, ids, embed_weight, head_weight=None):
@@ -388,6 +499,27 @@ def windowed_moe_decoder_tiny(vocab_size=256, **kw):
               "sliding_attention": dict(base=10000.0)},
         moe=dict(expert_hidden=16, shared_hidden=16, held=4,
                  published_experts=16, top_k=3, scaling=2.5),
+        epsilon=1e-6)
+    args.update(kw)
+    return HybridDecoder(vocab_size, **args)
+
+
+def indexed_moe_decoder_tiny(vocab_size=256, **kw):
+    """Two indexed-attention layers over sparse feed-forwards without a
+    shared expert, at toy widths: 4 query heads over 2 KV heads of 16 with a
+    QK norm and rotary positions in sections 2/3/3, an indexer of 2 heads of
+    8 that keeps 8 keys a query (of the 32 of a toy sequence), 4 of 16
+    experts held, 3 a token; untied head."""
+    args = dict(
+        units=32, hidden_size=64,
+        layer_types=("indexed_attention",) * 2,
+        mlp_layer_types=("sparse",) * 2, num_heads=4, num_kv_heads=2,
+        head_dim=16, tie_head=False, qk_norm=True, sections=(2, 3, 3),
+        rope={"indexed_attention": dict(base=1e7)},
+        indexer=dict(heads=2, head_dim=8, top_k=8, chunk=8,
+                     rope=dict(base=1e7)),
+        moe=dict(expert_hidden=16, shared_hidden=None, held=4,
+                 published_experts=16, top_k=3),
         epsilon=1e-6)
     args.update(kw)
     return HybridDecoder(vocab_size, **args)

@@ -28,3 +28,4 @@ from . import dgl_ops    # noqa: F401
 from . import ssm        # noqa: F401
 from . import rotary     # noqa: F401
 from . import moe        # noqa: F401
+from . import sparse_select  # noqa: F401

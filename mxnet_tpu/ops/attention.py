@@ -14,6 +14,14 @@ plain scores-softmax route apply the same mask; a windowed layer's route is
 counted as `flash_window` in `mx_attention_route_total`, and the schedule
 the kernels chose for a layer in `mx_attention_schedule_total` (PR 33).
 Positions are not this module's: `ops/rotary.py` turns q and k before they come here.
+
+A causal call may instead take a selection (PR 34): `select` int8 (B, T, T),
+not zero where query t keeps key t' (learned sparse attention: an indexer
+scores the keys and `ops/sparse_select.py` keeps the best `top_k` a query).
+A key is then live where causal and kept. The Pallas kernels mask every
+block they visit by the selection's tile, the blockwise scan and the models'
+plain route take the same mask; the route is counted as `flash_select`. A
+QK norm, like positions, is the model's (`models/hybrid_decoder.py`).
 """
 from __future__ import annotations
 
@@ -95,9 +103,10 @@ def _block_attn(q, k, v, bias, scale):
 
 def blockwise_attention(q, k, v, block_size: int = 512, causal: bool = False,
                         scale: Optional[float] = None,
-                        window: Optional[int] = None):
+                        window: Optional[int] = None, select=None):
     """Flash-style attention via lax.scan over key blocks. `window` (with
-    `causal`): query t sees the keys t - window < t' <= t."""
+    `causal`): query t sees the keys t - window < t' <= t. `select`
+    (B, T, Tk): not zero where query t keeps key t' (with the other masks)."""
     B, H, T, D = q.shape
     scale = scale if scale is not None else (1.0 / (D ** 0.5))
     block_size = min(block_size, k.shape[2])
@@ -113,7 +122,7 @@ def blockwise_attention(q, k, v, block_size: int = 512, causal: bool = False,
     q_pos = jnp.arange(T)[:, None]
 
     def body(carry, inp):
-        i, kblk, vblk = inp
+        i, kblk, vblk = inp[:3]
         acc_num, acc_den, acc_max = carry
         k_pos = i * block_size + jnp.arange(block_size)[None, :]
         mask = k_pos < Tk
@@ -121,7 +130,11 @@ def blockwise_attention(q, k, v, block_size: int = 512, causal: bool = False,
             mask = jnp.logical_and(mask, q_pos >= k_pos)
             if window is not None:
                 mask = jnp.logical_and(mask, q_pos - k_pos < window)
-        bias = jnp.where(mask, 0.0, _NEG)[None, None]
+        if select is None:
+            bias = jnp.where(mask, 0.0, _NEG)[None, None]
+        else:
+            bias = jnp.where(jnp.logical_and(mask[None, None],
+                                             inp[3][:, None]), 0.0, _NEG)
         num, den, m = _block_attn(qf, kblk.astype(jnp.float32), vblk, bias, scale)
         new_max = jnp.maximum(acc_max, m)
         corr_old = jnp.exp(acc_max - new_max)
@@ -135,22 +148,27 @@ def blockwise_attention(q, k, v, block_size: int = 512, causal: bool = False,
     zero_like_q = qf * 0.0
     zero_col = zero_like_q[..., :1]
     acc = (zero_like_q, zero_col, zero_col + _NEG)
-    (num, den, _), _ = lax.scan(body, acc, (jnp.arange(nblk), kb, vb))
+    xs = (jnp.arange(nblk), kb, vb)
+    if select is not None:
+        kept = jnp.pad(select != 0, ((0, 0), (0, 0), (0, pad)))
+        xs += (jnp.moveaxis(kept.reshape(B, T, nblk, block_size), 2, 0),)
+    (num, den, _), _ = lax.scan(body, acc, xs)
     return (num / jnp.maximum(den, 1e-30)).astype(q.dtype)
 
 
 @register("_contrib_flash_attention")
-def flash_attention_op(q, k, v, *, causal=False, block_size=512, scale=None,
-                       window=None):
+def flash_attention_op(q, k, v, select=None, *, causal=False, block_size=512,
+                       scale=None, window=None):
     """Registered op form so the eager autograd tape records its VJP.
     Dispatches to the Pallas TPU kernel (ops/pallas/flash_attention.py)
     when on TPU; the lax.scan blockwise path elsewhere. `scale` multiplies
     q k^T (None: 1/sqrt(d)); `window` (with `causal`) keeps the keys
-    t - window < t' <= t of query t."""
+    t - window < t' <= t of query t; `select` int8 (B, T, T) (with `causal`)
+    the keys it marks."""
     from .pallas.flash_attention import flash_attention as _pallas_flash
     return _pallas_flash(q, k, v, causal=causal, scale=scale,
                          block_q=block_size, block_k=block_size,
-                         window=window)
+                         window=window, select=select)
 
 
 def ring_attention(q, k, v, axis_name: str, causal: bool = False,
