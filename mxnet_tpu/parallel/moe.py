@@ -33,6 +33,8 @@ import jax.numpy as jnp
 import numpy as _np
 from jax import lax
 
+from ..ops.pallas.flash_attention import _on_tpu
+from ..ops.pallas.grouped_matmul import routed_swiglu
 from .mesh import axis_size as _axis_size
 
 
@@ -336,9 +338,13 @@ def held_moe_ffn(x, router_w, w_gate_up, w_down, *, top_k: int,
     chips' part of the sum. No assignment to a held expert is ever dropped,
     whatever the routing, and shapes stay static: the kept assignments are
     sorted by expert, and where the row buffer of `held_rows` holds them all
-    its tokens are gathered, multiplied by groups (`lax.ragged_dot`: the
-    products cost in proportion to the buffer, not to tokens x held) and the
-    weighted rows scatter-added. Where a step's routing sends more here than
+    its tokens are gathered, multiplied by groups (the kernels of
+    `ops/pallas/grouped_matmul.py`, the SwiGLU and the combine weight inside
+    them) and the weighted rows summed by token in float32 (one more
+    grouped product, over the kept rows sorted by token). That path costs
+    by `kept`, the assignments that fell here this step, not by the buffer:
+    no kernel visits a row tile past the last kept row. Where a step's
+    routing sends more here than
     the buffer holds, a `lax.cond` takes the exact dense path instead: every
     held expert over every token, times its combine weight (zero where not
     routed). Where the buffer reaches the bound there is no dense path. Both
@@ -375,25 +381,15 @@ def held_moe_ffn(x, router_w, w_gate_up, w_down, *, top_k: int,
         skey, order = lax.sort_key_val(
             key, jnp.arange(N * top_k, dtype=jnp.int32))
 
-    def swiglu(rows_in, gate_up, down):
-        gu = gate_up(rows_in)
-        return down((jax.nn.silu(gu[:, :F]) * gu[:, F:]).astype(x.dtype))
-
     def sorted_rows(x, w_gate_up, w_down, weight):
         with jax.named_scope("mx.moe.experts"):
             chosen, valid = order[:n_rows], skey[:n_rows] < held
-            token = chosen // top_k
-            # the buffer's unused tail goes to the last group with its rows
-            # zeroed, so the groups cover every row (what a grouped product
-            # does with rows past its groups is its own affair: on the TPU
-            # it leaves them as they were) and the tail adds nothing
-            groups = counts.at[held - 1].add(jnp.maximum(n_rows - kept, 0))
-            y = swiglu(jnp.where(valid[:, None], x[token], 0),
-                       lambda a: lax.ragged_dot(a, w_gate_up, groups),
-                       lambda a: lax.ragged_dot(a, w_down, groups))
+            # the grouped products and the sums by token stop at `kept`:
+            # what lies behind in the buffer is never read, and what comes
+            # back for it (the combine weights' cotangent) is selected away
             w_row = jnp.where(valid, weight.reshape(-1)[chosen], 0.0)
-            y = y.astype(f32) * w_row[:, None]
-            return jnp.zeros((N, D), f32).at[token].add(y).astype(x.dtype)
+            return routed_swiglu(x, w_gate_up, w_down, w_row,
+                                 chosen // top_k, counts, not _on_tpu(x))
 
     def every_row(x, w_gate_up, w_down, weight):
         with jax.named_scope("mx.moe.experts"):
@@ -403,7 +399,8 @@ def held_moe_ffn(x, router_w, w_gate_up, w_down, *, top_k: int,
 
             def one(acc, ws):
                 gate_up, down, w_e = ws
-                y = swiglu(x, lambda a: a @ gate_up, lambda a: a @ down)
+                gu = x @ gate_up
+                y = (jax.nn.silu(gu[:, :F]) * gu[:, F:]).astype(x.dtype) @ down
                 return acc + w_e[:, None] * y.astype(f32), None
 
             acc, _ = lax.scan(one, jnp.zeros((N, D), f32),

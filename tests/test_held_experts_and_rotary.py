@@ -90,6 +90,150 @@ def test_the_buffer_follows_the_routing(bias, kept, exact):
     assert abs(int(aux["kept"]) - kept) <= 2 and bool(aux["exact"]) is exact
 
 
+# -- the grouped products stop at `kept` (PR 35) ----------------------------------------
+
+def _swiglu_rows(rows, w_gate_up, w_down, counts, w_row):
+    """`grouped_swiglu`'s equations, expert by expert, over the rows that
+    belong to one; zeros behind them."""
+    f32 = jnp.float32
+    out, lo = jnp.zeros(rows.shape, f32), 0
+    for e, n in enumerate(counts):
+        gu = rows[lo:lo + n].astype(f32) @ w_gate_up[e].astype(f32)
+        f = gu.shape[1] // 2
+        y = (jax.nn.silu(gu[:, :f]) * gu[:, f:]) @ w_down[e].astype(f32)
+        out = out.at[lo:lo + n].set(w_row[lo:lo + n, None] * y)
+        lo += n
+    return out
+
+
+_GROUPS = {                      # 64 rows, 4 experts, row tiles of 16
+    "well_under_the_buffer": (5, 9, 3, 7),
+    "an_expert_with_no_row": (20, 0, 17, 0),
+    "no_row_at_all": (0, 0, 0, 0),
+    "one_expert_with_every_row": (0, 0, 41, 0),
+    "edges_off_the_tile": (17, 13, 1, 30),
+    "edges_on_the_tile": (16, 0, 32, 16),
+    "the_buffer_full": (23, 9, 31, 1),
+    "one_tile_for_all": (2, 1, 1, 3),
+}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("counts", _GROUPS.values(), ids=_GROUPS.keys())
+def test_grouped_swiglu_against_its_equations(monkeypatch, counts, dtype):
+    """The kernels, in interpret mode, on a buffer whose rows past `kept`
+    are NaN going in: the kept rows and every gradient as the equations
+    give them, the weights' gradients of an expert without a row zero, and
+    no NaN in anything that is summed over rows."""
+    from mxnet_tpu.ops.pallas import grouped_matmul as gm
+    monkeypatch.setattr(gm, "_ROW_TILE", 16)
+    rows, d, f, kept = 64, 32, 16, sum(counts)
+    rs = np.random.RandomState(kept)
+    arr = lambda scale, *s: jnp.asarray(rs.normal(0, scale, s), dtype)
+    x, w_gu, w_d = arr(1, rows, d), arr(0.3, 4, d, 2 * f), arr(0.3, 4, f, d)
+    w_row = jnp.asarray(rs.uniform(0.2, 1, rows), jnp.float32)
+    t = jnp.asarray(rs.normal(0, 1, (rows, d)), jnp.float32)
+    live = (jnp.arange(rows) < kept)
+    poison = lambda a: jnp.where(live.reshape((-1,) + (1,) * (a.ndim - 1)),
+                                 a, jnp.nan)
+
+    def got(x, w_gu, w_d, w_row):
+        z = gm.grouped_swiglu(poison(x), w_gu, w_d,
+                              jnp.asarray(counts, jnp.int32), poison(w_row),
+                              True)
+        return jnp.where(live[:, None], z, 0).astype(jnp.float32)
+
+    def want(x, w_gu, w_d, w_row):
+        return _swiglu_rows(x, w_gu, w_d, counts, w_row)
+
+    args = (x, w_gu, w_d, w_row)
+    tol = dict(rtol=2e-5, atol=2e-6) if dtype == "float32" \
+        else dict(rtol=3e-2, atol=3e-2)
+    np.testing.assert_allclose(np.asarray(got(*args)),
+                               np.asarray(want(*args)), **tol)
+    grads = jax.grad(lambda *a: jnp.sum(got(*a) * t), argnums=(0, 1, 2, 3))
+    wants = jax.grad(lambda *a: jnp.sum(want(*a) * t), argnums=(0, 1, 2, 3))
+    for g, w in zip(grads(*args), wants(*args)):
+        g, w = np.asarray(g, np.float32), np.asarray(w, np.float32)
+        assert np.isfinite(g).all()
+        np.testing.assert_allclose(
+            g, w, rtol=tol["rtol"],
+            atol=tol["atol"] * max(float(np.abs(w).max()), 1e-3))
+    # the poison was there: what the kernels leave behind `kept` is not zeros
+    h, = gm._gate_up(poison(x), w_gu, jnp.asarray(counts, jnp.int32), False,
+                     True)
+    assert kept == rows or bool(jnp.isnan(h[kept:].astype(jnp.float32)).all())
+
+
+def test_the_visits_of_a_buffer():
+    """Tiles of 16 rows, groups of 17, 0, 13, 30 in a buffer of 96: the
+    first expert's rows lie in tiles 0 and 1, the third's in 1, the
+    fourth's in 1 to 3; tiles 4 and 5 are never visited."""
+    from mxnet_tpu.ops.pallas import grouped_matmul as gm
+    counts = jnp.asarray([17, 0, 13, 30], jnp.int32)
+    gid, tid, n, starts, ends = gm.visits(counts, 96, 16)
+    assert int(n[0]) == 6 and gid.shape == tid.shape == (6 + 4 - 1,)
+    assert gid.tolist()[:6] == [0, 0, 2, 3, 3, 3]
+    assert tid.tolist()[:6] == [0, 1, 1, 1, 2, 3]
+    assert gid.tolist()[6:] == [3] * 3 and tid.tolist()[6:] == [3] * 3
+    assert starts.tolist() == [0, 17, 17, 30] \
+        and ends.tolist() == [17, 17, 30, 60]
+    gid, tid, n, _, _ = gm.visits(counts, 96, 16, empty=True)
+    assert int(n[0]) == 7 and gid.tolist()[:7] == [0, 0, 1, 2, 3, 3, 3]
+    assert tid.tolist()[:7] == [0, 1, 1, 1, 1, 2, 3]
+
+
+def _routed(kind):
+    """Layers whose routing is planted: which held experts get rows."""
+    x, router_w, w_gate_up, w_down = _planted(5, 3.0)
+    if kind == "one_expert_with_every_row":     # 96 rows on expert 0
+        router_w = router_w.at[0].set(1.0).at[1:HELD].set(-1.0)
+    elif kind == "an_expert_with_no_row":
+        router_w = router_w.at[2].set(-1.0)
+    elif kind == "the_buffer_full":     # 32 tokens x 4 held = the 128 rows
+        x = x.at[32:].multiply(-1.0)
+        router_w = router_w.at[:HELD].set(1.0)
+    return x, router_w, w_gate_up, w_down
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("tile", [16, 64])
+@pytest.mark.parametrize("kind,kept,most", [
+    ("well_under_the_buffer", 66, 29),
+    ("one_expert_with_every_row", N, N),
+    ("an_expert_with_no_row", 55, 29),
+    ("the_buffer_full", 128, 32)])
+def test_the_sorted_path_stops_at_kept(monkeypatch, kind, kept, most, tile,
+                                       dtype):
+    """The sorted path with its products stopping at `kept`, the rows
+    behind left as the kernels leave them (NaN in interpret mode): the
+    layer's output and the gradient of every operand are the equations',
+    and its report is what the routing says."""
+    from mxnet_tpu.ops.pallas import grouped_matmul as gm
+    monkeypatch.setattr(gm, "_ROW_TILE", tile)
+    args = tuple(a.astype(dtype) for a in _routed(kind))
+    wide = tuple(a.astype(jnp.float32) for a in args)
+    y, aux = _held(*args, return_aux=True)
+    assert y.dtype == args[0].dtype and not bool(aux["exact"])
+    assert [float(aux[k]) for k in ("kept", "max_load", "mean_load")] \
+        == [kept, most, kept / HELD]
+    tol = dict(rtol=2e-5, atol=2e-6) if dtype == "float32" \
+        else dict(rtol=3e-2, atol=3e-2)
+    np.testing.assert_allclose(np.asarray(y, np.float32),
+                               np.asarray(dense_over_held(*wide)), **tol)
+    t = jnp.asarray(np.random.RandomState(8).normal(0, 1, y.shape), "f")
+    grads = jax.grad(lambda *a: jnp.sum(_held(*a).astype("f") * t),
+                     argnums=(0, 1, 2, 3))(*args)
+    want = jax.grad(lambda *a: jnp.sum(dense_over_held(*a) * t),
+                    argnums=(0, 1, 2, 3))(*wide)
+    for g, w in zip(grads, want):
+        g = np.asarray(g, np.float32)
+        assert np.isfinite(g).all()
+        np.testing.assert_allclose(
+            g, np.asarray(w), rtol=10 * tol["rtol"],
+            atol=tol["atol"] * float(jnp.abs(w).max()))
+
+
 def test_the_cells_buffer():
     """The cell: 8,192 tokens, 10 of 256, 8 held: 2,560 expected, 4,096
     rows, of a bound of 65,536."""
@@ -122,13 +266,29 @@ def test_no_assignment_is_dropped_when_every_row_goes_to_held_experts():
                                rtol=2e-4, atol=1e-5)
 
 
+def _layer_conds(jaxpr):
+    """The layer's own `lax.cond`s (a kernel's `pl.when` is one too, inside
+    its call)."""
+    found = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            continue
+        found += eqn.primitive.name == "cond"
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else (value,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    found += _layer_conds(sub)
+    return found
+
+
 def test_a_buffer_that_holds_the_bound_has_no_second_path():
     """A chip that holds every expert: the buffer is the bound."""
     x, router_w, w_gate_up, w_down = _layer(2, held=E)
     assert moe.held_rows(N, K, E, E) == (N * K, N * K)
-    text = str(jax.make_jaxpr(_held)(x, router_w, w_gate_up, w_down))
-    assert "cond" not in text
-    assert "cond" in str(jax.make_jaxpr(_held)(*_layer(2)))
+    whole = jax.make_jaxpr(_held)(x, router_w, w_gate_up, w_down)
+    assert _layer_conds(whole.jaxpr) == 0
+    assert _layer_conds(jax.make_jaxpr(_held)(*_layer(2)).jaxpr) == 1
 
 
 def test_the_shares_add_up_to_the_whole_layer():

@@ -563,6 +563,39 @@ def test_flash_kernels_compile_for_v5e_at_t8192(building_for_tpu, one_chip,
     assert f"f32[{BH},1,8192]" in text
 
 
+@pytest.mark.parametrize("rows,D,F,experts,tokens", [
+    pytest.param(32768, 2048, 768, 16, 16384,
+                 id="keye_vl2_30b_a3b_train_t16384"),
+    pytest.param(4096, 3072, 1024, 8, 8192, id="laguna_s_2_1_train_t8192"),
+])
+def test_grouped_swiglu_compiles_for_v5e(one_chip, rows, D, F, experts,
+                                         tokens):
+    """The held-experts layer's sorted path at its two cells' shapes: the
+    five kernels of `ops/pallas/grouped_matmul.py` (a whole expert's weights
+    resident, row tiles of 512) fit Mosaic's VMEM and its tiling, forward
+    and backward."""
+    from mxnet_tpu.ops.pallas.grouped_matmul import routed_swiglu
+    shaped = lambda shape, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(
+        shape, dtype, sharding=one_chip)
+    args = (shaped((tokens, D)), shaped((experts, D, 2 * F)),
+            shaped((experts, F, D)), shaped((rows,), jnp.float32),
+            shaped((rows,), jnp.int32), shaped((experts,), jnp.int32))
+    fwd = jax.jit(lambda *a: routed_swiglu(*a, False)).lower(*args)
+    # gate-and-up, down, and the rows summed by token (a grouped product too)
+    assert fwd.compile().as_text().count("tpu_custom_call") == 3
+    grad = jax.grad(lambda *a: jnp.sum(routed_swiglu(*a, False)
+                                       .astype(jnp.float32)),
+                    argnums=(0, 1, 2, 3))
+    text = jax.jit(grad).lower(*args).compile().as_text()
+    # gate-and-up with g and u kept, the two backward products, two
+    # gradients of weights and the rows' gradient summed by token; the down
+    # product's output is read by nothing
+    for name in ("mx_moe_gate_up", "mx_moe_down_bwd", "mx_moe_gate_up_bwd",
+                 "mx_moe_dweights"):
+        assert name in text
+    assert text.count("tpu_custom_call") >= 6
+
+
 @contextlib.contextmanager
 def trainer_context(mesh, axis="dp"):
     """What DataParallelTrainer._build_step sets round its traced body."""
