@@ -16,7 +16,10 @@ the jax_graft analog of that shared engine state:
     (weight/optimizer-state aliasing a la arXiv:2004.13336's weight-update
     sharding — donated inputs alias their outputs in-place on TPU);
   - hit/miss/trace/compile-time/donation counters surfaced through
-    ``profiler.compilation_stats()`` so cache regressions are visible.
+    ``profiler.compilation_stats()`` so cache regressions are visible;
+  - one jax.monitoring listener (``_BuildListener``) that leaves a ``build``
+    record in the tracing ring for every program the process traces, lowers,
+    compiles or loads, and sums their seconds into ``compile_seconds``.
 """
 from __future__ import annotations
 
@@ -69,7 +72,9 @@ _STATS = {
     "misses": 0,          # lookups that required a fresh build
     "traces": 0,          # python-level retraces of cached forwards
     "compiles": 0,        # artifact builds (one per miss that completed)
-    "compile_seconds": 0.0,
+    "compile_seconds": 0.0,  # every stage of every build record (below):
+                             # what the process spent tracing, lowering,
+                             # compiling and loading programs, whoever built
     "fwd_executions": 0,  # compiled forward invocations (gluon cached path)
     "bwd_executions": 0,  # compiled pullback invocations (no fwd recompute)
     "donated_updates": 0, # optimizer update calls that donated buffers
@@ -330,21 +335,146 @@ def estimate_cost(jitted, *args, kind: str = "artifact",
 
 @contextmanager
 def compile_timer(name: str = "build"):
-    """Times an artifact build; feeds both the stats dict and the profiler's
-    aggregate table (category 'compilation')."""
+    """Counts an artifact build (``compiles``) and times it for the
+    profiler's aggregate table (category 'compilation'). The seconds are not
+    added to ``compile_seconds``: the build records hold every program the
+    construction builds, and would count it twice."""
     t0 = time.perf_counter()
     try:
         yield
     finally:
         t1 = time.perf_counter()
-        with _LOCK:
-            _STATS["compiles"] += 1
-            _STATS["compile_seconds"] += t1 - t0
+        _bump("compiles")
         try:
             from .. import profiler as _profiler
             _profiler._record(name, "compilation", t0, t1)
         except Exception:
             pass
+
+
+# ---------------------------------------------------------------------------
+# Build records: one for every program the process builds
+# ---------------------------------------------------------------------------
+
+# jax reports each stage of a build with the function's name
+# (jax/_src/dispatch.py): a scalar at its start, a duration at its end
+_STAGES = {"/jax/core/compile/jaxpr_trace_duration": (0, "trace"),
+           "/jax/core/compile/jaxpr_to_mlir_module_duration": (1, "lower"),
+           "/jax/core/compile/backend_compile_duration": (2, "compile")}
+_CACHE_ASKED = "/jax/compilation_cache/compile_requests_use_cache"
+_CACHE_RETRIEVAL = "/jax/compilation_cache/cache_retrieval_time_sec"
+
+
+class _BuildListener:
+    """Puts jax.monitoring's events together, by thread, into one ``build``
+    record a program and appends it to the tracing ring, armed or not::
+
+        {"kind": "build", "name": "mx.build", "fun": "step", "ts": ..,
+         "dur": .., "phases": {"trace": s, "lower": s, "compile": s,
+         "cache_load": s}, "cache": "hit" | "miss" | "off", "thread": ident}
+
+    ``ts`` is ``time.perf_counter`` at the first stage's start (stamped at
+    the callback, the stage's duration taken off: jax's own stamps are
+    ``time.time``); ``dur`` is the sum of the stages, what jax does between
+    them left out. ``compile`` is the backend's duration less the persistent
+    cache's retrieval on a hit, ``cache_load`` that retrieval; a stage jax
+    did not report reads 0 (a function lowered again that was traced
+    before; ``jitted.lower()`` without a compile). ``cache`` is ``"off"``
+    where no cache directory is set. The record enters the ring when its
+    first stage ends and later stages are added to it in place: the next
+    stage in order of the same function on the same thread is the same
+    program, anything else a new one. A stage that runs inside another on
+    the same thread (a jitted function traced into an outer trace, an eager
+    op under a trace) is part of that stage and leaves nothing of its own,
+    so the records' seconds never overlap and sum to ``compile_seconds``.
+    Armed, each stage is also a ``record_span`` child of the current span.
+    What call a build ran under is found when read
+    (``tracing.parent_of``)."""
+
+    def __init__(self):
+        self._tls = threading.local()
+
+    def _state(self) -> Dict[str, Any]:
+        st = self._tls.__dict__
+        if not st:
+            st.update(depth=0, build=None, stage=-1, asked=False, load=None)
+        return st
+
+    def on_scalar(self, event: str, value, **kw) -> None:
+        if event in _STAGES:
+            st = self._state()
+            st["depth"] += 1
+            if st["depth"] == 1:
+                st["asked"], st["load"] = False, None
+
+    def on_event(self, event: str, **kw) -> None:
+        if event == _CACHE_ASKED:
+            st = self._state()
+            if st["depth"] == 1:
+                st["asked"] = True
+
+    def on_duration(self, event: str, secs: float, **kw) -> None:
+        order, stage = _STAGES.get(event, (None, None))
+        if stage is None:
+            if event == _CACHE_RETRIEVAL:
+                st = self._state()
+                if st["depth"] == 1:
+                    st["load"] = secs
+            return
+        st = self._state()
+        # (a listener registered inside an open stage sees its end alone)
+        st["depth"] = max(st["depth"] - 1, 0)
+        if st["depth"]:
+            return
+        now = time.perf_counter()
+        from ..telemetry import tracing
+        fun = str(kw.get("fun_name", ""))
+        if fun.startswith("jit(") and fun.endswith(")"):
+            fun = fun[4:-1]   # lower and compile name the function jit(f)
+        build = st["build"]
+        if build is not None and fun == "<unknown>":
+            fun = build["fun"]   # a partial's lowering does not say its name
+        if build is None or build["fun"] != fun or st["stage"] >= order:
+            build = st["build"] = {
+                "kind": "build", "name": "mx.build", "fun": fun,
+                "ts": now - secs, "dur": 0.0,
+                "phases": {"trace": 0.0, "lower": 0.0, "compile": 0.0,
+                           "cache_load": 0.0},
+                "cache": "off", "thread": threading.get_ident()}
+            tracing._append(build)
+        st["stage"] = order
+        phases = build["phases"]
+        if stage == "compile":
+            load = st["load"]
+            if load is not None:
+                load = min(max(load, 0.0), secs)
+                phases["cache_load"] = load
+                phases["compile"] = secs - load
+                build["cache"] = "hit"
+            else:
+                phases["compile"] = secs
+                if st["asked"] and persistent_cache_dir():
+                    build["cache"] = "miss"
+            st["build"] = None
+        else:
+            phases[stage] = secs
+        build["dur"] += secs
+        _bump("compile_seconds", secs)
+        tracing.record_span("mx.build." + stage, now - secs, now, fun=fun)
+
+
+_BUILDS = _BuildListener()
+
+
+def _listen_to_builds() -> None:
+    import jax.monitoring as monitoring
+    monitoring.register_scalar_listener(_BUILDS.on_scalar)
+    monitoring.register_event_listener(_BUILDS.on_event)
+    monitoring.register_event_duration_secs_listener(_BUILDS.on_duration)
+
+
+# once, here: the module that owns cache_stats and the persistent cache
+_listen_to_builds()
 
 
 # ---------------------------------------------------------------------------

@@ -17,6 +17,7 @@ written back after the compiled call returns.
 """
 from __future__ import annotations
 
+import contextlib
 import re
 import threading
 from collections import OrderedDict
@@ -37,6 +38,8 @@ from .. import random as _rng
 from .. import telemetry as _telem
 from .parameter import Parameter, ParameterDict, DeferredInitializationError
 
+_tracing = _telem.tracing
+
 
 # ---------------------------------------------------------------------------
 # Aux-state side-channel (BatchNorm moving stats etc.)
@@ -52,6 +55,56 @@ _SYM_PARAM_NAMES: list = []
 
 def in_trace() -> bool:
     return _TRACE_DEPTH[0] > 0
+
+
+# ---------------------------------------------------------------------------
+# Set-up on record: net init and deferred init (docs/observability.md)
+# ---------------------------------------------------------------------------
+
+_OPEN_SETUP = threading.local()  # names of the setup records open on a thread
+
+
+@contextlib.contextmanager
+def _outermost(name: str, **attrs):
+    """The ``setup`` record ``name`` (`tracing.phased`) round the outermost
+    such call of this thread. A call nested in an open one yields None and
+    leaves no record: its time is the outer call's."""
+    if getattr(_OPEN_SETUP, name, False):
+        yield None
+        return
+    setattr(_OPEN_SETUP, name, True)
+    try:
+        with _tracing.phased("setup", name, **attrs) as rec:
+            yield rec
+    finally:
+        setattr(_OPEN_SETUP, name, False)
+
+
+def _phase(rec, name: str):
+    return contextlib.nullcontext() if rec is None else rec.phase(name)
+
+
+@contextlib.contextmanager
+def _cold_start(block):
+    """`mx.block.deferred_init`: the record of a forward that found deferred
+    parameters, opened in the cold branch only (a forward whose parameters
+    are ready never comes here). Phases `probe` (shape inference), `finish`
+    (the initializers of the pending leaves) and `forward` (the call itself,
+    eager, an op a program); `params`: the leaves it finished. One record
+    for the outermost cold forward of a call: a hybridized net's first call
+    leaves one, an unhybridized container one for each child that was cold
+    (the container itself never takes the branch)."""
+    with _outermost("mx.block.deferred_init") as rec:
+        if rec is None:
+            yield None
+            return
+        pending = [p for p in block.collect_params().values()
+                   if p._deferred_init is not None]
+        try:
+            yield rec
+        finally:
+            rec.set_attr("params", sum(p._deferred_init is None
+                                       for p in pending))
 
 
 def defer_aux_update(param: Parameter, new_raw):
@@ -204,8 +257,11 @@ class Block:
     # -- lifecycle -----------------------------------------------------------
     def initialize(self, init=None, ctx=None, verbose=False, force_reinit=False):
         from .. import initializer as init_mod
-        self.collect_params().initialize(init or init_mod.Uniform(), ctx,
-                                         verbose, force_reinit)
+        params = self.collect_params()
+        # set-up on record: `mx.block.initialize`, entry to return
+        with _outermost("mx.block.initialize", params=len(params)):
+            params.initialize(init or init_mod.Uniform(), ctx, verbose,
+                              force_reinit)
 
     def hybridize(self, active=True, **kwargs):
         for child in self._children.values():
@@ -438,16 +494,18 @@ class HybridBlock(Block):
     def infer_shape(self, *args):
         """Layers override to resolve deferred param shapes from inputs."""
 
-    def _ensure_params_ready(self, args):
+    def _ensure_params_ready(self, args, rec=None):
         params = self.collect_params()
         pending = [p for p in params.values() if p._deferred_init is not None]
         if not pending:
             return
         # run shape inference down the tree by a dry eager call per block
-        self._shape_probe(*args)
-        for p in pending:
-            if p._deferred_init is not None:
-                p._finish_deferred_init()
+        with _phase(rec, "probe"):
+            self._shape_probe(*args)
+        with _phase(rec, "finish"):
+            for p in pending:
+                if p._deferred_init is not None:
+                    p._finish_deferred_init()
 
     def _shape_probe(self, *args):
         """Default probe: call infer_shape hooks recursively by executing the
@@ -495,8 +553,10 @@ class HybridBlock(Block):
         try:
             return run(*args)
         except DeferredInitializationError:
-            self._ensure_params_ready(list(args))
-            return run(*args)
+            with _cold_start(self) as rec:
+                self._ensure_params_ready(list(args), rec)
+                with _phase(rec, "forward"):
+                    return run(*args)
 
     def _forward_unhybridized(self, *args):
         kwargs = {}
@@ -504,11 +564,24 @@ class HybridBlock(Block):
             try:
                 kwargs[name] = p.data()
             except DeferredInitializationError:
-                self.infer_shape(*args)
-                if p._deferred_init is not None:
-                    p._finish_deferred_init()
-                kwargs[name] = p.data()
+                return self._forward_cold(*args)
         return self.hybrid_forward(nd, *args, **kwargs)
+
+    def _forward_cold(self, *args):
+        """`_forward_unhybridized` of a block whose own parameters are
+        deferred: infer their shapes from the inputs, run their
+        initializers, then the forward."""
+        with _cold_start(self) as rec:
+            with _phase(rec, "probe"):
+                self.infer_shape(*args)
+            with _phase(rec, "finish"):
+                for p in self._reg_params.values():
+                    if p._data is None and p._deferred_init is not None:
+                        p._finish_deferred_init()
+            with _phase(rec, "forward"):
+                kwargs = {name: p.data()
+                          for name, p in self._reg_params.items()}
+                return self.hybrid_forward(nd, *args, **kwargs)
 
     def _forward_symbolic(self, *args):
         """Trace this block into a Symbol graph. Parameter Variables are

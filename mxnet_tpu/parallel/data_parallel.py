@@ -305,200 +305,212 @@ class DataParallelTrainer:
                  batch_axis_name: str = "dp", dtype=None, data_spec=None,
                  compression=None, zero_update=None, bucket_bytes=None,
                  comm_dtype=None):
-        self.net = net
-        # Mixed precision: dtype="bfloat16" (or "float16") runs forward/backward
-        # in low precision with fp32 master weights + fp32 optimizer math —
-        # the TPU-native analog of reference AMP (python/mxnet/contrib/amp/).
-        self.compute_dtype = None
-        if dtype is None:
-            # amp.init() makes low-precision the session default
-            try:
-                from ..contrib.amp import amp as _amp
-                dtype = _amp.target_dtype()
-            except ImportError:
-                pass
-        if dtype is not None and jnp.dtype(dtype) != jnp.dtype(jnp.float32):
-            self.compute_dtype = jnp.dtype(dtype)
-            if self.compute_dtype not in (jnp.dtype(jnp.bfloat16),
-                                          jnp.dtype(jnp.float16)):
-                raise MXNetError(
-                    "dtype must be float32/bfloat16/float16, got %r" % dtype)
-        # fp16 needs dynamic loss scaling (grads under 2^-24 flush to zero);
-        # bf16/f32 don't — scaler stays None and the step skips that logic
-        self._scaler = None
-        if self.compute_dtype == jnp.dtype(jnp.float16):
-            from ..contrib.amp.loss_scaler import LossScaler
-            self._scaler = LossScaler()
-        self.mesh = mesh if mesh is not None else current_mesh()
-        # computed once: the mesh never changes after construction, and the
-        # per-step placement helpers sit on the hot path
-        self._multiprocess = any(d.process_index != jax.process_index()
-                                 for d in self.mesh.devices.flat)
-        self.batch_axis = batch_axis_name
-        # input PartitionSpec; default = batch over the dp axis only. Pass
-        # e.g. P('dp', 'sp') to also shard the sequence dim (context parallel).
-        self.data_spec = data_spec if data_spec is not None else P(batch_axis_name)
-        self.optimizer = optimizer if isinstance(optimizer, opt_mod.Optimizer) \
-            else opt_mod.create(optimizer, **(optimizer_params or {}))
-        self._init_fn, self._update_fn = functional_optimizer(self.optimizer)
-        self._lazy_update_fn = functional_lazy_update(self.optimizer)
-        self.loss = loss
-        deferred = [p.name for p in net.collect_params().values()
-                    if p._data is None and p._deferred_init is not None]
-        if deferred:
-            raise MXNetError(
-                "net has deferred-init parameters (%s…); run one eager "
-                "forward pass before constructing DataParallelTrainer"
-                % deferred[0])
-        self._plist = [p for p in net.collect_params().values()
-                       if p._data is not None]
-        self._trainable = [p.grad_req != "null" for p in self._plist]
-        self._lazy = [self._lazy_update_fn is not None and
-                      getattr(p, "grad_stype", "default") == "row_sparse"
-                      for p in self._plist]
-        self._params_raw = [p._data._data for p in self._plist]
-        self._t = 0
-        # bounded in-flight dispatch (MXNET_TPU_INFLIGHT_STEPS): step()
-        # returns without blocking and the window back-pressures on the
-        # (i-K)th step's outputs — the reference dependency engine's
-        # pending-op bound, realized over jax async dispatch
-        self._window = _feed.DispatchWindow(name="dp")
-        self._dp_degree = int(dict(self.mesh.shape).get(batch_axis_name, 1))
-        self._ar_bytes: Optional[int] = None
-        self._rs_bytes: Optional[int] = None   # zero: reduce-scatter wire
-        self._ag_bytes: Optional[int] = None   # zero: all-gather wire
-        self._opt_bytes: Optional[int] = None  # per-replica state footprint
-        self._wds = [self.optimizer._get_wd(i)
-                     for i in range(len(self._plist))]
+        # set-up on record (docs/observability.md): the call is the span
+        # mx.dp.init, each phase a child span and a field of its record
+        with _tracing.phased("setup", "mx.dp.init",
+                             source="data_parallel") as rec:
+            with rec.phase("collect"):
+                self.net = net
+                # Mixed precision: dtype="bfloat16" (or "float16") runs forward/backward
+                # in low precision with fp32 master weights + fp32 optimizer math —
+                # the TPU-native analog of reference AMP (python/mxnet/contrib/amp/).
+                self.compute_dtype = None
+                if dtype is None:
+                    # amp.init() makes low-precision the session default
+                    try:
+                        from ..contrib.amp import amp as _amp
+                        dtype = _amp.target_dtype()
+                    except ImportError:
+                        pass
+                if dtype is not None and jnp.dtype(dtype) != jnp.dtype(jnp.float32):
+                    self.compute_dtype = jnp.dtype(dtype)
+                    if self.compute_dtype not in (jnp.dtype(jnp.bfloat16),
+                                                  jnp.dtype(jnp.float16)):
+                        raise MXNetError(
+                            "dtype must be float32/bfloat16/float16, got %r" % dtype)
+                # fp16 needs dynamic loss scaling (grads under 2^-24 flush to zero);
+                # bf16/f32 don't — scaler stays None and the step skips that logic
+                self._scaler = None
+                if self.compute_dtype == jnp.dtype(jnp.float16):
+                    from ..contrib.amp.loss_scaler import LossScaler
+                    self._scaler = LossScaler()
+                self.mesh = mesh if mesh is not None else current_mesh()
+                # computed once: the mesh never changes after construction, and the
+                # per-step placement helpers sit on the hot path
+                self._multiprocess = any(d.process_index != jax.process_index()
+                                         for d in self.mesh.devices.flat)
+                self.batch_axis = batch_axis_name
+                # input PartitionSpec; default = batch over the dp axis only. Pass
+                # e.g. P('dp', 'sp') to also shard the sequence dim (context parallel).
+                self.data_spec = data_spec if data_spec is not None else P(batch_axis_name)
+                self.optimizer = optimizer if isinstance(optimizer, opt_mod.Optimizer) \
+                    else opt_mod.create(optimizer, **(optimizer_params or {}))
+                self._init_fn, self._update_fn = functional_optimizer(self.optimizer)
+                self._lazy_update_fn = functional_lazy_update(self.optimizer)
+                self.loss = loss
+                deferred = [p.name for p in net.collect_params().values()
+                            if p._data is None and p._deferred_init is not None]
+                if deferred:
+                    raise MXNetError(
+                        "net has deferred-init parameters (%s…); run one eager "
+                        "forward pass before constructing DataParallelTrainer"
+                        % deferred[0])
+                self._plist = [p for p in net.collect_params().values()
+                               if p._data is not None]
+                self._trainable = [p.grad_req != "null" for p in self._plist]
+                self._lazy = [self._lazy_update_fn is not None and
+                              getattr(p, "grad_stype", "default") == "row_sparse"
+                              for p in self._plist]
+                self._params_raw = [p._data._data for p in self._plist]
+                self._t = 0
+                # bounded in-flight dispatch (MXNET_TPU_INFLIGHT_STEPS): step()
+                # returns without blocking and the window back-pressures on the
+                # (i-K)th step's outputs — the reference dependency engine's
+                # pending-op bound, realized over jax async dispatch
+                self._window = _feed.DispatchWindow(name="dp")
+                self._dp_degree = int(dict(self.mesh.shape).get(batch_axis_name, 1))
+                self._ar_bytes: Optional[int] = None
+                self._rs_bytes: Optional[int] = None   # zero: reduce-scatter wire
+                self._ag_bytes: Optional[int] = None   # zero: all-gather wire
+                self._opt_bytes: Optional[int] = None  # per-replica state footprint
+                self._wds = [self.optimizer._get_wd(i)
+                             for i in range(len(self._plist))]
 
-        # ZeRO-style sharded weight update (arXiv:2004.13336; parallel/zero)
-        if zero_update is None:
-            zero_update = bool(env.get("MXNET_TPU_ZERO"))
-        self._zero = bool(zero_update)
-        self._bucket_bytes = int(bucket_bytes if bucket_bytes is not None
-                                 else env.get("MXNET_TPU_BUCKET_BYTES"))
-        if comm_dtype is None:
-            comm_dtype = env.get("MXNET_TPU_COMM_DTYPE") or None
-        self._comm_dtype = _zero.canonical_comm_dtype(comm_dtype) \
-            if self._zero else None
+                # ZeRO-style sharded weight update (arXiv:2004.13336; parallel/zero)
+                if zero_update is None:
+                    zero_update = bool(env.get("MXNET_TPU_ZERO"))
+                self._zero = bool(zero_update)
+                self._bucket_bytes = int(bucket_bytes if bucket_bytes is not None
+                                         else env.get("MXNET_TPU_BUCKET_BYTES"))
+                if comm_dtype is None:
+                    comm_dtype = env.get("MXNET_TPU_COMM_DTYPE") or None
+                self._comm_dtype = _zero.canonical_comm_dtype(comm_dtype) \
+                    if self._zero else None
 
-        # shardings: params per their spec (default replicated)
-        self._param_shardings = [
-            NamedSharding(self.mesh, p.sharding if p.sharding is not None else P())
-            for p in self._plist]
-        self._params_raw = [self._place_param(w, s)
-                            for w, s in zip(self._params_raw,
-                                            self._param_shardings)]
-        # Optimizer state is created from the PLACED master weights, so each
-        # leaf is born with its final placement (zeros_like inherits the
-        # NamedSharding) — single-process included: the step jit requires
-        # params and opt_state co-located, and net init under mx.cpu() on a
-        # TPU-visible process otherwise leaves the state on the host. In
-        # multi-controller SPMD this doubles as the global-array lift
-        # (identical-per-process seeded state, the reference's rank-0
-        # broadcast contract). Zero mode instead shards the state 1/dp over
-        # flat fusion buckets.
-        if self._zero:
-            self._validate_zero(compression)
-            self._init_zero_state()
-        else:
-            self._zero_plan = ()
-            self._opt_state = [self._init_fn(w) if t else ()
-                               for w, t in zip(self._params_raw,
-                                               self._trainable)]
+                # shardings: params per their spec (default replicated)
+                self._param_shardings = [
+                    NamedSharding(self.mesh, p.sharding if p.sharding is not None else P())
+                    for p in self._plist]
+            with rec.phase("place_params"):
+                self._params_raw = [self._place_param(w, s)
+                                    for w, s in zip(self._params_raw,
+                                                    self._param_shardings)]
+                rec.set_attr("leaves", len(self._plist))
+                rec.set_attr("bytes", int(sum(
+                    w.nbytes for w in self._params_raw)))
+            with rec.phase("init_opt_state"):
+                # Optimizer state is created from the PLACED master weights, so each
+                # leaf is born with its final placement (zeros_like inherits the
+                # NamedSharding) — single-process included: the step jit requires
+                # params and opt_state co-located, and net init under mx.cpu() on a
+                # TPU-visible process otherwise leaves the state on the host. In
+                # multi-controller SPMD this doubles as the global-array lift
+                # (identical-per-process seeded state, the reference's rank-0
+                # broadcast contract). Zero mode instead shards the state 1/dp over
+                # flat fusion buckets.
+                if self._zero:
+                    self._validate_zero(compression)
+                    self._init_zero_state()
+                else:
+                    self._zero_plan = ()
+                    self._opt_state = [self._init_fn(w) if t else ()
+                                       for w, t in zip(self._params_raw,
+                                                       self._trainable)]
 
-        # 2-bit gradient compression with per-device error feedback
-        # (reference src/kvstore/gradient_compression.cc:60). Each device
-        # quantizes its LOCAL gradient (+ residual) to {-thr, 0, +thr}
-        # before the cross-dp reduce — the collective then carries the
-        # quantized tensor, like the reference's ps-lite push path. Needs
-        # explicit per-device semantics, so the compressed step runs the
-        # grad computation under shard_map over the dp axis; that is only
-        # well-defined for pure data parallelism (replicated params,
-        # batch-only data sharding), matching the reference's dist-DP scope.
-        self._compression = dict(compression) if compression else None
-        if self._compression:
-            ctype = self._compression.get("type", "2bit")
-            if ctype != "2bit":
-                raise MXNetError(f"unsupported gradient compression {ctype!r}")
-            bad = [p.name for p, s in zip(self._plist, self._param_shardings)
-                   if any(ax is not None for ax in s.spec)]
-            if bad or tuple(self.data_spec) != (self.batch_axis,):
-                raise MXNetError(
-                    "gradient compression requires pure data parallelism "
-                    "(replicated parameters, data sharded over the batch "
-                    f"axis only); offending params={bad[:3]} "
-                    f"data_spec={self.data_spec}")
-            sparse = [p.name for p, lz in zip(self._plist, self._lazy) if lz]
-            if sparse:
-                # a {-t,0,+t}-quantized gradient has no meaningful 'absent
-                # rows' — lazy semantics would silently change under
-                # compression (the reference also restricts compression to
-                # dense gradients, src/kvstore/kvstore_dist.h)
-                raise MXNetError(
-                    "gradient compression is incompatible with row_sparse "
-                    f"lazy-update parameters ({sparse[:3]}); use dense "
-                    "gradients or disable compression")
-            ndp = self.mesh.shape[self.batch_axis]
-            thr_sh = NamedSharding(self.mesh, P(self.batch_axis))
+            with rec.phase("compression"):
+                # 2-bit gradient compression with per-device error feedback
+                # (reference src/kvstore/gradient_compression.cc:60). Each device
+                # quantizes its LOCAL gradient (+ residual) to {-thr, 0, +thr}
+                # before the cross-dp reduce — the collective then carries the
+                # quantized tensor, like the reference's ps-lite push path. Needs
+                # explicit per-device semantics, so the compressed step runs the
+                # grad computation under shard_map over the dp axis; that is only
+                # well-defined for pure data parallelism (replicated params,
+                # batch-only data sharding), matching the reference's dist-DP scope.
+                self._compression = dict(compression) if compression else None
+                if self._compression:
+                    ctype = self._compression.get("type", "2bit")
+                    if ctype != "2bit":
+                        raise MXNetError(f"unsupported gradient compression {ctype!r}")
+                    bad = [p.name for p, s in zip(self._plist, self._param_shardings)
+                           if any(ax is not None for ax in s.spec)]
+                    if bad or tuple(self.data_spec) != (self.batch_axis,):
+                        raise MXNetError(
+                            "gradient compression requires pure data parallelism "
+                            "(replicated parameters, data sharded over the batch "
+                            f"axis only); offending params={bad[:3]} "
+                            f"data_spec={self.data_spec}")
+                    sparse = [p.name for p, lz in zip(self._plist, self._lazy) if lz]
+                    if sparse:
+                        # a {-t,0,+t}-quantized gradient has no meaningful 'absent
+                        # rows' — lazy semantics would silently change under
+                        # compression (the reference also restricts compression to
+                        # dense gradients, src/kvstore/kvstore_dist.h)
+                        raise MXNetError(
+                            "gradient compression is incompatible with row_sparse "
+                            f"lazy-update parameters ({sparse[:3]}); use dense "
+                            "gradients or disable compression")
+                    ndp = self.mesh.shape[self.batch_axis]
+                    thr_sh = NamedSharding(self.mesh, P(self.batch_axis))
 
-            def _zeros_on(shape, sharding):
-                # zeros are servable from every process: placement works on
-                # multi-host meshes where device_put cannot reach
-                # non-addressable devices
-                if not self._multiprocess:
-                    return jax.device_put(jnp.zeros(shape, jnp.float32),
-                                          sharding)
-                def _shard_zeros(idx, _s=shape):
-                    dims = [len(range(*sl.indices(dim)))
-                            for sl, dim in zip(idx, _s)]
-                    return _np.zeros(tuple(dims), _np.float32)
-                return jax.make_array_from_callback(shape, sharding,
-                                                    _shard_zeros)
+                    def _zeros_on(shape, sharding):
+                        # zeros are servable from every process: placement works on
+                        # multi-host meshes where device_put cannot reach
+                        # non-addressable devices
+                        if not self._multiprocess:
+                            return jax.device_put(jnp.zeros(shape, jnp.float32),
+                                                  sharding)
+                        def _shard_zeros(idx, _s=shape):
+                            dims = [len(range(*sl.indices(dim)))
+                                    for sl, dim in zip(idx, _s)]
+                            return _np.zeros(tuple(dims), _np.float32)
+                        return jax.make_array_from_callback(shape, sharding,
+                                                            _shard_zeros)
 
-            self._comp_resid = [
-                _zeros_on((ndp,) + w.shape, thr_sh)
-                if t and jnp.issubdtype(w.dtype, jnp.floating) else
-                _zeros_on((ndp, 1), thr_sh)
-                for w, t in zip(self._params_raw, self._trainable)]
-        else:
-            self._comp_resid = []
+                    self._comp_resid = [
+                        _zeros_on((ndp,) + w.shape, thr_sh)
+                        if t and jnp.issubdtype(w.dtype, jnp.floating) else
+                        _zeros_on((ndp, 1), thr_sh)
+                        for w, t in zip(self._params_raw, self._trainable)]
+                else:
+                    self._comp_resid = []
 
-        # process-wide engine-cache key base: N trainers over one model
-        # structure and configuration share compiled step artifacts, while
-        # any change to the zero/bucket/comm-dtype (or precision, mesh,
-        # optimizer, compression) configuration compiles apart
-        # (docs/compilation.md "fused-step fingerprints")
-        self._step_key_base = (
-            "dp_step",
-            _engine.structural_fingerprint(net),
-            _engine.config_fingerprint(
-                optimizer=type(self.optimizer).__name__,
-                opt_conf=tuple(sorted(
-                    (k, repr(v)) for k, v in vars(self.optimizer).items()
-                    if isinstance(v, (int, float, bool, str, type(None))))),
-                wds=tuple(float(w) for w in self._wds),
-                loss=self.loss,
-                mesh=tuple(sorted(dict(self.mesh.shape).items())),
-                axis_order=tuple(self.mesh.axis_names),
-                devices=tuple(int(d.id) for d in self.mesh.devices.flat),
-                batch_axis=self.batch_axis,
-                data_spec=tuple(str(a) for a in self.data_spec),
-                param_specs=tuple(str(s.spec) for s in self._param_shardings),
-                trainable=tuple(self._trainable),
-                lazy=tuple(self._lazy),
-                compute_dtype=str(self.compute_dtype),
-                scaled=self._scaler is not None,
-                compression=tuple(sorted(self._compression.items()))
-                if self._compression else None,
-                zero=self._zero,
-                bucket_bytes=self._bucket_bytes if self._zero else None,
-                comm_dtype=self._comm_dtype))
-        # executables, cost captures and roofline regions live in the
-        # PROCESS-WIDE engine cache behind this program (parallel/
-        # step_program.py) — same-config trainers share compiles
-        self._program = StepProgram(
-            f"dp.step[{type(self.net).__name__}]", self._step_key_base)
+            with rec.phase("program"):
+                # process-wide engine-cache key base: N trainers over one model
+                # structure and configuration share compiled step artifacts, while
+                # any change to the zero/bucket/comm-dtype (or precision, mesh,
+                # optimizer, compression) configuration compiles apart
+                # (docs/compilation.md "fused-step fingerprints")
+                self._step_key_base = (
+                    "dp_step",
+                    _engine.structural_fingerprint(net),
+                    _engine.config_fingerprint(
+                        optimizer=type(self.optimizer).__name__,
+                        opt_conf=tuple(sorted(
+                            (k, repr(v)) for k, v in vars(self.optimizer).items()
+                            if isinstance(v, (int, float, bool, str, type(None))))),
+                        wds=tuple(float(w) for w in self._wds),
+                        loss=self.loss,
+                        mesh=tuple(sorted(dict(self.mesh.shape).items())),
+                        axis_order=tuple(self.mesh.axis_names),
+                        devices=tuple(int(d.id) for d in self.mesh.devices.flat),
+                        batch_axis=self.batch_axis,
+                        data_spec=tuple(str(a) for a in self.data_spec),
+                        param_specs=tuple(str(s.spec) for s in self._param_shardings),
+                        trainable=tuple(self._trainable),
+                        lazy=tuple(self._lazy),
+                        compute_dtype=str(self.compute_dtype),
+                        scaled=self._scaler is not None,
+                        compression=tuple(sorted(self._compression.items()))
+                        if self._compression else None,
+                        zero=self._zero,
+                        bucket_bytes=self._bucket_bytes if self._zero else None,
+                        comm_dtype=self._comm_dtype))
+                # executables, cost captures and roofline regions live in the
+                # PROCESS-WIDE engine cache behind this program (parallel/
+                # step_program.py) — same-config trainers share compiles
+                self._program = StepProgram(
+                    f"dp.step[{type(self.net).__name__}]", self._step_key_base)
 
     # -- ZeRO-style sharded update setup ------------------------------------
     def _validate_zero(self, compression):

@@ -18,11 +18,15 @@ of it*. One primitive, :func:`span`, marks a region of host code:
 
 :func:`phased` is the same primitive for a call that is cut into contiguous
 phases (the fused trainer's ``step``, the feed's producer): every phase is a
-child span, and the call appends one small **record** (kind ``"step"`` or
-``"batch"``: start, duration, seconds by phase) to the ring whether tracing
-is armed or not: one ``perf_counter`` read at each phase boundary and one
-GIL-atomic append a call. :func:`step_records` returns the records;
-:func:`spans` returns spans and events only.
+child span, and the call appends one small **record** (kind ``"step"``,
+``"batch"`` or ``"setup"``: start, duration, seconds by phase) to the ring
+whether tracing is armed or not: one ``perf_counter`` read at each phase
+boundary and one GIL-atomic append a call. A fourth kind, ``"build"``, is
+appended by the engine's jax.monitoring listener, one record for every
+program the process traces, lowers, compiles or loads
+(``engine._BuildListener``); :func:`parent_of` finds the call a build ran
+under. :func:`step_records` returns the records of every kind in
+:data:`RECORD_KINDS`; :func:`spans` returns spans and events only.
 
 The ring doubles as a black-box flight recorder. Armed, on preemption
 (``elastic.run``) or an unhandled exception (``sys.excepthook``/
@@ -75,7 +79,8 @@ __all__ = [
     "enable", "disable", "is_enabled",
     "span", "phased", "record_span", "event",
     "current", "attach", "new_root",
-    "spans", "step_records", "recent", "set_max_spans", "reset",
+    "spans", "step_records", "parent_of", "RECORD_KINDS", "recent",
+    "set_max_spans", "reset",
     "dump_chrome_trace", "dump_flight_recorder",
     "watch_step_time", "check_loss", "install_crash_hooks",
 ]
@@ -418,7 +423,15 @@ def _append(entry: Dict[str, Any]) -> None:
 # Ring access
 # ---------------------------------------------------------------------------
 
-_RECORD_KINDS = ("step", "batch")
+# The kinds of record the ring keeps beside spans and events. Public, so that
+# a reader can tell a program from before a kind (absent: nothing to read)
+# from one that lost its records (declared, none kept: an error).
+#   step   a DataParallelTrainer.step / run_steps call            (phased)
+#   batch  a batch the feed's producer made                       (phased)
+#   setup  a boundary set-up crosses before the first step:
+#          mx.block.initialize, mx.block.deferred_init, mx.dp.init (phased)
+#   build  a program the process built: mx.build (engine's listener)
+RECORD_KINDS = ("step", "batch", "setup", "build")
 
 
 def _entries() -> List[Dict[str, Any]]:
@@ -434,32 +447,59 @@ def _entries() -> List[Dict[str, Any]]:
 
 
 def spans() -> List[Dict[str, Any]]:
-    """The ring's spans and events (oldest first), without the step and
-    batch records."""
-    return [e for e in _entries() if e["kind"] not in _RECORD_KINDS]
+    """The ring's spans and events (oldest first), without the records."""
+    return [e for e in _entries() if e["kind"] not in RECORD_KINDS]
 
 
 def step_records(name: Optional[str] = None, since: Optional[float] = None,
                  until: Optional[float] = None) -> List[Dict[str, Any]]:
-    """The ring's step and batch records (see :func:`phased`), oldest first:
-    those named ``name`` (``"mx.dp.step"``, ``"mx.dp.run_steps"``,
-    ``"mx.feed.batch"``; all if None) that began in ``[since, until]``,
-    times on ``time.perf_counter``."""
-    return [e for e in _entries() if e["kind"] in _RECORD_KINDS
+    """The ring's records (the kinds of :data:`RECORD_KINDS`; see
+    :func:`phased`), oldest first: those named ``name`` (``"mx.dp.step"``,
+    ``"mx.dp.run_steps"``, ``"mx.feed.batch"``, ``"mx.dp.init"``,
+    ``"mx.block.initialize"``, ``"mx.block.deferred_init"``, ``"mx.build"``;
+    all if None) that began in ``[since, until]``, times on
+    ``time.perf_counter``."""
+    return [e for e in _entries() if e["kind"] in RECORD_KINDS
             and (name is None or e["name"] == name)
             and (since is None or e["ts"] >= since)
             and (until is None or e["ts"] <= until)]
 
 
+def parent_of(entry: Dict[str, Any],
+              records: Optional[List[Dict[str, Any]]] = None
+              ) -> Optional[Dict[str, Any]]:
+    """The call a record ran under: the innermost ``step`` or ``setup``
+    record of the same thread whose ``[ts, ts + dur]`` holds ``entry``'s
+    ``ts``, or None. This is how a ``build`` record is attributed: nothing
+    is stored at the build and the enclosing call pays nothing; the link is
+    found when read (a call appends its record when it returns, so a build
+    inside a call that is still open has no parent yet). ``records``: the
+    candidates (default: the ring's)."""
+    if records is None:
+        records = step_records()
+    ts, thread, found = entry["ts"], entry["thread"], None
+    for r in records:
+        if r["kind"] in ("step", "setup") and r["thread"] == thread \
+                and r is not entry and r["ts"] <= ts <= r["ts"] + r["dur"] \
+                and (found is None or r["ts"] >= found["ts"]):
+            found = r
+    return found
+
+
 def recent(n: Optional[int] = None) -> List[Dict[str, Any]]:
     """The trailing ``n`` entries, records included (default
-    MXNET_TPU_STATUSZ_EVENTS)."""
+    MXNET_TPU_STATUSZ_EVENTS). A ``build`` record among them also says which
+    call it ran under (``"under"``: that record's name, see
+    :func:`parent_of`)."""
     if n is None:
         n = int(env.get("MXNET_TPU_STATUSZ_EVENTS"))
     entries = _entries()
-    if n <= 0 or n >= len(entries):
-        return entries
-    return entries[-n:]
+    tail = entries if n <= 0 or n >= len(entries) else entries[-n:]
+    if any(e["kind"] == "build" for e in tail):
+        calls = [e for e in entries if e["kind"] in ("step", "setup")]
+        tail = [dict(e, under=(parent_of(e, calls) or {}).get("name"))
+                if e["kind"] == "build" else e for e in tail]
+    return tail
 
 
 def set_max_spans(n: int) -> None:

@@ -157,10 +157,12 @@ def test_hot_lists_cover_the_always_on_phase_bookkeeping():
     phased = [dp.qualname(fn) for fn in dp.functions()
               if ".phase(" in "\n".join(
                   dp.lines[fn.lineno - 1:fn.end_lineno])]
-    assert sorted(phased) == ["DataParallelTrainer.run_steps",
-                              "DataParallelTrainer.step"]
+    per_call = ["DataParallelTrainer.run_steps", "DataParallelTrainer.step"]
+    # (`__init__` is set-up's `mx.dp.init` record, ISSUE 36: once a trainer,
+    # it places the leaves and may wait for them)
+    assert sorted(phased) == ["DataParallelTrainer.__init__"] + per_call
     for fn in dp.functions():
-        if dp.qualname(fn) in phased:
+        if dp.qualname(fn) in per_call:
             assert host_sync._is_hot(dp, fn) and sync_in_loop._is_hot(dp, fn)
 
 

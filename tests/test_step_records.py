@@ -442,3 +442,311 @@ def test_spans_land_in_a_profiler_trace_with_nothing_armed(tmp_path):
             "mx.feed.queue_wait"} <= names
     assert not any(e[0].startswith("mx.feed.") and e[0] != "mx.feed.next"
                    for e in lines[main])
+
+
+# ---------------------------------------------------------------------------
+# set-up on record (ISSUE 36): a `build` record for every program the process
+# builds, `setup` records round net init and the trainer's placement
+# ---------------------------------------------------------------------------
+
+STAGES = ("trace", "lower", "compile", "cache_load")
+
+
+def _builds(fun=None, since=None):
+    return [b for b in tracing.step_records("mx.build", since=since)
+            if fun is None or b["fun"] == fun]
+
+
+def test_record_kinds_are_public_and_read_through_step_records():
+    assert tracing.RECORD_KINDS == ("step", "batch", "setup", "build")
+    with tracing.phased("setup", "mx.test.setup", leaves=3) as rec:
+        with rec.phase("a"):
+            pass
+    (r,) = tracing.step_records("mx.test.setup")
+    assert r["kind"] == "setup" and r["leaves"] == 3
+    _partition(r)
+    assert tracing.spans() == []        # records, not spans
+
+
+def test_a_jitted_function_leaves_one_build_record_and_a_second_call_none():
+    @jax.jit
+    def once_built_fn(x):
+        return jnp.tanh(x) * 3 + 1
+
+    x = jnp.ones((5, 7))
+    jax.block_until_ready(x)
+    t0 = time.perf_counter()
+    once_built_fn(x)
+    (b,) = _builds("once_built_fn", since=t0)
+    assert b["kind"] == "build" and b["name"] == "mx.build"
+    assert b["thread"] == threading.get_ident()
+    assert set(b["phases"]) == set(STAGES)
+    assert all(v >= 0.0 for v in b["phases"].values())
+    assert b["phases"]["trace"] > 0 and b["phases"]["lower"] > 0 \
+        and b["phases"]["compile"] > 0
+    assert sum(b["phases"].values()) == pytest.approx(b["dur"], rel=1e-9)
+    assert b["cache"] in ("off", "miss")    # nothing to load it from
+    assert b["phases"]["cache_load"] == 0.0
+    assert t0 <= b["ts"] <= time.perf_counter()
+    once_built_fn(x)
+    once_built_fn(x + 1)
+    assert len(_builds("once_built_fn", since=t0)) == 1
+    assert tracing.spans() == []            # disarmed: records alone
+
+
+def test_a_function_traced_into_another_leaves_no_record_of_its_own():
+    """A stage inside a stage of the same thread is part of it: the records'
+    seconds do not overlap, so they sum to `compile_seconds`."""
+    @jax.jit
+    def inner_of_nested(x):
+        return x * 2
+
+    @jax.jit
+    def outer_of_nested(x):
+        return inner_of_nested(x) + 1
+
+    from mxnet_tpu import engine
+    x = jnp.ones(3)
+    jax.block_until_ready(x)
+    t0 = time.perf_counter()
+    before = engine.cache_stats()["compile_seconds"]
+    outer_of_nested(x)
+    spent = engine.cache_stats()["compile_seconds"] - before
+    built = _builds(since=t0)
+    assert [b["fun"] for b in built] == ["outer_of_nested"]
+    assert spent == pytest.approx(built[0]["dur"], rel=1e-6)
+
+
+def test_lower_without_compile_is_a_build_of_the_stages_it_ran():
+    g = jax.jit(lambda x: x - 4)
+    t0 = time.perf_counter()
+    lowered = g.lower(jnp.ones(3))
+    (b,) = _builds("<lambda>", since=t0)
+    assert b["phases"]["trace"] > 0 and b["phases"]["lower"] > 0
+    assert b["phases"]["compile"] == 0.0
+    lowered.compile()                      # the same program: the same record
+    (b2,) = _builds("<lambda>", since=t0)
+    assert b2 is b and b["phases"]["compile"] > 0
+    assert sum(b["phases"].values()) == pytest.approx(b["dur"], rel=1e-9)
+
+
+def test_armed_each_stage_is_a_child_span():
+    tracing.enable()
+    with tracing.span("around_a_build") as sp:
+        jax.jit(lambda x: x * 5 - 1)(jnp.ones(2))
+    stages = [s for s in tracing.spans()
+              if s["name"].startswith("mx.build.")
+              and s["attrs"]["fun"] == "<lambda>"]
+    assert [s["name"] for s in stages] == ["mx.build.trace", "mx.build.lower",
+                                           "mx.build.compile"]
+    assert all(s["parent_id"] == sp.span_id for s in stages)
+
+
+_CACHE_SCRIPT = """
+import json, sys
+import jax, jax.numpy as jnp
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+from mxnet_tpu.telemetry import tracing
+f = jax.jit(lambda x: jnp.tanh(x) @ x.T + 2, )
+f(jnp.ones((16, 16)))
+(b,) = [b for b in tracing.step_records("mx.build") if b["fun"] == "<lambda>"]
+print(json.dumps(b))
+"""
+
+
+def test_a_process_fresh_build_reads_miss_then_hit(tmp_path):
+    import subprocess
+    import sys
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"))
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    got = []
+    for _ in range(2):
+        out = subprocess.run([sys.executable, "-c", _CACHE_SCRIPT], env=env,
+                             cwd=root, capture_output=True, text=True,
+                             timeout=120)
+        assert out.returncode == 0, out.stderr[-2000:]
+        got.append(json.loads(out.stdout.strip().splitlines()[-1]))
+    first, second = got
+    assert first["cache"] == "miss" and first["phases"]["cache_load"] == 0.0
+    assert second["cache"] == "hit" and second["phases"]["cache_load"] > 0
+    for b in got:
+        assert all(v >= 0 for v in b["phases"].values())
+        assert sum(b["phases"].values()) == pytest.approx(b["dur"], rel=1e-9)
+
+
+def test_a_build_is_attributed_to_its_own_threads_call():
+    started, built = threading.Event(), threading.Event()
+
+    def other():
+        started.wait(10)
+        jax.jit(lambda x: x * 7 + 2)(jnp.ones(4))     # no call round it
+        built.set()
+
+    t = threading.Thread(target=other)
+    t.start()
+    t0 = time.perf_counter()
+    with tracing.phased("step", "mx.test.call") as rec:
+        with rec.phase("wait"):
+            started.set()
+            assert built.wait(60)
+        with rec.phase("build"):
+            jax.jit(lambda x: x * 9 - 2)(jnp.ones(4))
+    t.join(10)
+    assert not t.is_alive()
+    (call,) = tracing.step_records("mx.test.call")
+    mine, theirs = [], []
+    for b in _builds("<lambda>", since=t0):
+        (mine if b["thread"] == threading.get_ident() else theirs).append(b)
+    assert len(mine) == 1 and len(theirs) == 1
+    assert call["ts"] <= theirs[0]["ts"] <= call["ts"] + call["dur"]
+    assert tracing.parent_of(theirs[0]) is None
+    assert tracing.parent_of(mine[0]) is call
+    # /statusz's view says the same
+    under = {b["thread"]: b["under"] for b in tracing.recent(64)
+             if b["kind"] == "build" and b["fun"] == "<lambda>"}
+    assert under == {mine[0]["thread"]: "mx.test.call",
+                     theirs[0]["thread"]: None}
+
+
+def test_parent_of_is_the_innermost_call():
+    tid = threading.get_ident()
+
+    def rec(kind, name, ts, dur, thread=tid):
+        return {"kind": kind, "name": name, "ts": ts, "dur": dur,
+                "phases": {}, "thread": thread}
+    outer, inner = rec("setup", "outer", 10.0, 5.0), rec("step", "in", 11.0, 2.0)
+    batch, away = rec("batch", "b", 11.0, 2.0), rec("step", "x", 11.0, 2.0, 1)
+    calls = [outer, inner, batch, away]
+    build = rec("build", "mx.build", 11.5, 0.1)
+    assert tracing.parent_of(build, calls) is inner
+    assert tracing.parent_of(dict(build, ts=13.5), calls) is outer
+    assert tracing.parent_of(dict(build, ts=15.5), calls) is None
+    assert tracing.parent_of(inner, calls) is outer
+
+
+def test_the_steady_state_builds_nothing():
+    tr = _trainer()
+    x, y = _batch(rows=10)
+    tr.step(x, y)
+    tr.drain()
+    t1 = time.perf_counter()
+    for _ in range(20):
+        tr.step(x, y)
+    tr.drain()
+    assert len(tracing.step_records("mx.dp.step", since=t1)) == 20
+    assert _builds(since=t1) == []
+
+
+def test_the_first_step_holds_its_build_and_compile_seconds_counts_it():
+    from mxnet_tpu import engine
+    tr = _trainer()
+    engine.reset_stats()
+    t0 = time.perf_counter()
+    tr.step(*_batch(rows=12))       # a signature no other test compiles
+    tr.drain()
+    (first,) = tracing.step_records("mx.dp.step", since=t0)
+    (step,) = [b for b in _builds("step", since=t0)
+               if tracing.parent_of(b) is first]
+    launch = first["phases"]["launch"]
+    assert first["ts"] <= step["ts"] and step["dur"] <= launch
+    assert step["dur"] > 0.5 * launch      # the launch IS the build
+    spent = engine.cache_stats()["compile_seconds"]
+    assert spent >= step["dur"]
+    assert spent == pytest.approx(sum(b["dur"] for b in _builds(since=t0)),
+                                  rel=1e-6)
+    engine.reset_stats()
+    assert engine.cache_stats()["compile_seconds"] == 0.0
+
+
+def test_compile_timer_counts_the_artifact_and_not_its_seconds():
+    from mxnet_tpu import engine
+    engine.reset_stats()
+    with engine.compile_timer("test:artifact"):
+        time.sleep(0.02)
+    st = engine.cache_stats()
+    assert st["compiles"] == 1 and st["compile_seconds"] == 0.0
+
+
+def test_dp_init_is_on_record_with_the_nets_leaves_and_bytes():
+    t0 = time.perf_counter()
+    tr = _trainer()
+    (r,) = tracing.step_records("mx.dp.init", since=t0)
+    assert r["kind"] == "setup" and r["source"] == "data_parallel"
+    assert list(r["phases"]) == ["collect", "place_params", "init_opt_state",
+                                 "compression", "program"]
+    _partition(r)
+    params = tr.net.collect_params().values()
+    assert r["leaves"] == len(params) == 4
+    assert r["bytes"] == sum(
+        int(onp.prod(p.shape)) * 4 for p in params) == (8 * 16 + 16
+                                                        + 16 * 4 + 4) * 4
+    # the placement's programs ran under it
+    assert all(tracing.parent_of(b) is r for b in _builds(since=r["ts"])
+               if b["ts"] <= r["ts"] + r["dur"]
+               and b["thread"] == r["thread"])
+
+
+def _dense_net(in_units):
+    net = gluon.nn.HybridSequential()
+    net.add(gluon.nn.Dense(16, activation="relu", in_units=in_units[0]),
+            gluon.nn.Dense(4, in_units=in_units[1]))
+    return net
+
+
+@pytest.mark.parametrize("hybridize", [True, False])
+def test_deferred_init_is_on_record_once_and_only_when_cold(hybridize):
+    x = nd.zeros((2, 8))
+    net = _dense_net((0, 16))       # the first layer's input width unknown
+    t0 = time.perf_counter()
+    net.initialize()
+    (init,) = tracing.step_records("mx.block.initialize", since=t0)
+    assert init["kind"] == "setup" and init["params"] == 4
+    if hybridize:
+        net.hybridize()
+    net(x)
+    (cold,) = tracing.step_records("mx.block.deferred_init", since=t0)
+    assert cold["kind"] == "setup" and cold["params"] == 1
+    assert list(cold["phases"]) == ["probe", "finish", "forward"]
+    _partition(cold)
+    assert cold["ts"] >= init["ts"] + init["dur"]
+    if hybridize:
+        # the cached graph is a function of its own: built under the record
+        # (the eager ops of the plain net were built by earlier tests)
+        assert any(tracing.parent_of(b) is cold for b in _builds(since=t0))
+    net(x)
+    net(x)
+    assert len(tracing.step_records("mx.block.deferred_init", since=t0)) == 1
+
+
+@pytest.mark.parametrize("hybridize", [True, False])
+def test_a_net_whose_shapes_were_given_is_never_cold(hybridize):
+    t0 = time.perf_counter()
+    net = _dense_net((8, 16))
+    net.initialize()
+    if hybridize:
+        net.hybridize()
+    net(nd.zeros((2, 8)))
+    assert len(tracing.step_records("mx.block.initialize", since=t0)) == 1
+    assert tracing.step_records("mx.block.deferred_init", since=t0) == []
+
+
+def test_a_nested_initialize_leaves_the_outermost_record_alone():
+    from mxnet_tpu.gluon import block as block_mod
+
+    class Outer(gluon.nn.HybridSequential):
+        def initialize(self, *a, **kw):
+            with block_mod._outermost("mx.block.initialize",
+                                      params=len(self.collect_params())):
+                for child in self._children.values():
+                    child.initialize(*a, **kw)
+
+    net = Outer()
+    net.add(gluon.nn.Dense(3, in_units=2), gluon.nn.Dense(3, in_units=3))
+    t0 = time.perf_counter()
+    net.initialize()
+    (only,) = tracing.step_records("mx.block.initialize", since=t0)
+    assert only["params"] == 4
+    net[0].initialize(force_reinit=True)    # the name is free again
+    assert len(tracing.step_records("mx.block.initialize", since=t0)) == 2

@@ -181,6 +181,26 @@ METHOD_CHECKS = [
      {"phase"}, "call"),
     ("engine/async_feed.py", "DeviceFeed", "_produce",
      {"phased"}, "call"),
+    # (ISSUE 36) set-up on record: the trainer's construction is the span
+    # mx.dp.init with its phases, net init and the cold forward go through
+    # the one helper that opens mx.block.initialize / mx.block.deferred_init,
+    # and the engine's listener is what makes the build records
+    ("parallel/data_parallel.py", "DataParallelTrainer", "__init__",
+     {"phased"}, "call"),
+    ("parallel/data_parallel.py", "DataParallelTrainer", "__init__",
+     {"phase"}, "call"),
+    ("gluon/block.py", "Block", "initialize",
+     {"_outermost"}, "call"),
+    ("gluon/block.py", None, "_cold_start",
+     {"_outermost"}, "call"),
+    ("gluon/block.py", None, "_outermost",
+     {"phased"}, "call"),
+    ("gluon/block.py", "HybridBlock", "forward",
+     {"_cold_start"}, "call"),
+    ("gluon/block.py", "HybridBlock", "_forward_cold",
+     {"_cold_start"}, "call"),
+    ("engine/__init__.py", None, "_listen_to_builds",
+     {"register_event_duration_secs_listener"}, "call"),
     ("engine/async_feed.py", "DeviceFeed", "next",
      {"span"}, "call"),
     ("engine/async_feed.py", "DispatchWindow", "admit",
