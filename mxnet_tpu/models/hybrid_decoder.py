@@ -62,8 +62,9 @@ the backward one, which is what lets a model of this width train on one chip.
 `mx.flash.window`, inside), the sparse feed-forward `mx.moe` (with
 `mx.moe.route`, `mx.moe.experts` and `mx.moe.shared` inside it); the indexed
 kind's mixer is `mx.attn.sparse`, with `mx.qknorm`, `mx.rope`, `mx.index`
-(the indexer's projections, `mx.index.score`, `mx.index.select`,
-`mx.index.unpack`) and the kernel call alone, `mx.flash.select`, inside.
+(the indexer's projections, `mx.index.select`: the `mx_index_select`
+kernels and the layouts round them, `mx.index.unpack`) and the kernel call
+alone, `mx.flash.select`, inside.
 """
 from __future__ import annotations
 
@@ -76,7 +77,7 @@ from ..gluon.block import HybridBlock, defer_aux_update
 from ..gluon import nn
 from ..ops import attention as _attn_ops
 from ..ops.moe import HELD_REPORT
-from ..ops.sparse_select import KEEP_NAME, SELECT_REPORT
+from ..ops.sparse_select import KEEP_NAME, LAYER_REPORT
 
 __all__ = ["Mamba2Mixer", "GroupedQueryAttention", "SwiGLU",
            "HeldExpertsFFN", "HybridDecoderLayer", "HybridDecoder",
@@ -168,7 +169,7 @@ class GroupedQueryAttention(HybridBlock):
     indexer's leaves are frozen, since a top-k hands no gradient back, and
     ride a fused step as weights without optimizer state. `selection` is
     state like `HeldExpertsFFN.routing`: the last training step's report in
-    the order of `ops.sparse_select.SELECT_REPORT` (keys kept a query, tiles
+    the order of `ops.sparse_select.LAYER_REPORT` (keys kept a query, tiles
     of the selection with nothing kept)."""
 
     def __init__(self, units, num_heads, num_kv_heads, scale=None,
@@ -211,7 +212,7 @@ class GroupedQueryAttention(HybridBlock):
                 for p in block.collect_params().values():
                     p.grad_req = "null"
             self.selection = self.params.get(
-                "selection", shape=(len(SELECT_REPORT),), init="zeros",
+                "selection", shape=(len(LAYER_REPORT),), init="zeros",
                 grad_req="null", differentiable=False)
 
     def cast(self, dtype):
@@ -235,7 +236,8 @@ class GroupedQueryAttention(HybridBlock):
                 qi, F.reshape(ki, shape=(0, -3, 0)), self.index_weight(a),
                 top_k=ix["top_k"], chunk=ix["chunk"])
             if autograd.is_training() or autograd.is_recording():
-                defer_aux_update(self.selection, report._data)
+                defer_aux_update(self.selection,
+                                 report._data[:len(LAYER_REPORT)])
             with jax.named_scope("mx.index.unpack"):
                 return F._contrib_selection_unpack(packed, keys=x.shape[1])
 

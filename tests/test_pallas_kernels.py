@@ -596,6 +596,25 @@ def test_grouped_swiglu_compiles_for_v5e(one_chip, rows, D, F, experts,
     assert text.count("tpu_custom_call") >= 6
 
 
+def test_indexer_select_compiles_for_v5e(building_for_tpu, one_chip):
+    """keye_vl2_30b_a3b_train_t16384's key selection: the scores of 16
+    heads of 64 and their exact top-k in VMEM (`ops/pallas/index_select.py`,
+    128 queries a step against up to 16,384 keys, an 8 MB scratch), one
+    kernel a group of chunks, the packed set written in place."""
+    from mxnet_tpu.ops.sparse_select import indexer_select
+    shaped = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                                 sharding=one_chip)
+    fn = jax.jit(lambda q, k, w: indexer_select(q, k, w, top_k=2048,
+                                                chunk=512))
+    compiled = fn.lower(shaped(1, 16, 16384, 64), shaped(1, 16384, 64),
+                        shaped(1, 16384, 16)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 8 and "mx_index_select" in text
+    # no float32 scores leave the kernels: the step's own memory is its
+    # inputs, the packed set and the reports
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
 @contextlib.contextmanager
 def trainer_context(mesh, axis="dp"):
     """What DataParallelTrainer._build_step sets round its traced body."""

@@ -10,6 +10,7 @@ indexer through the fused trainer."""
 import importlib
 import importlib.machinery
 import importlib.util
+import math
 import os
 import re
 
@@ -19,6 +20,7 @@ import numpy as np
 import pytest
 
 from mxnet_tpu.ops import sparse_select as S
+from mxnet_tpu.ops.pallas import index_select as K
 from mxnet_tpu.ops.attention import blockwise_attention
 from mxnet_tpu.ops.rotary import rotary_embedding, rotary_inv_freq
 
@@ -76,6 +78,7 @@ def test_selection_is_lax_top_ks_set(B, H, T, d, top_k, chunk):
     assert got.dtype == np.int8 and set(np.unique(got)) <= {0, 1}
     assert not np.triu(got[0], 1).any()                 # causal
     assert report[0] == pytest.approx(rows.mean(), rel=1e-6)
+    assert report.shape == (len(S.SELECT_REPORT),)
 
 
 @pytest.mark.parametrize("T,top_k,chunk", [(40, 8, 8), (37, 6, 16)])
@@ -102,6 +105,56 @@ def test_partial_ties_and_signed_zeros():
     w = jnp.round(_arr(rs, 2, 48, 2))
     got, _ = _selected(q, k, w, 7, 8)
     assert (got == np.asarray(dense_select(q, k, w, 7))).all()
+
+
+def test_the_kernels_own_block():
+    """The cell's indexer (16 heads of 64) at the kernel's own step of 128
+    queries: T of three steps that are not whole, `top_k` inside one."""
+    rs = np.random.RandomState(11)
+    T, top_k = 300, 100
+    q, k, w = _arr(rs, 1, 16, T, 64), _arr(rs, 1, T, 64), _arr(rs, 1, T, 16)
+    assert math.gcd(128, K._ROWS) == 128          # one step a chunk's rows
+    got, report = _selected(q, k, w, top_k, 128)
+    assert (got == np.asarray(dense_select(q, k, w, top_k))).all()
+    assert (got.sum(-1) == np.minimum(np.arange(T) + 1, top_k)).all()
+    assert report[2] == 0                   # no step ran the position passes
+
+
+def test_only_a_step_with_ties_on_its_cut_runs_the_position_passes():
+    """The first step's queries weigh every head by 0 (all their scores
+    tie), the second's by a positive weight (their scores differ): the
+    first keeps its first keys by position, the second its largest scores,
+    and one step of the two ran the position passes."""
+    rs = np.random.RandomState(12)
+    T, top_k = 256, 8
+    q, k = _arr(rs, 1, 2, T, 8), _arr(rs, 1, T, 8)
+    w = np.abs(rs.normal(0, 1, (1, T, 2))).astype(np.float32) + 0.1
+    w[:, :128] = 0
+    w = jnp.asarray(w)
+    got, report = _selected(q, k, w, top_k, 128)
+    assert (got == np.asarray(dense_select(q, k, w, top_k))).all()
+    pos = np.arange(128)
+    first = (pos[None, :] < top_k) & (pos[None, :] <= pos[:, None])
+    assert (got[0, :128, :128] == first).all() and not got[0, :128, 128:].any()
+    assert report[2] == 1
+
+
+def test_whole_number_inputs_give_the_xla_paths_bytes():
+    """With whole-number inputs every sum is exact in any order, so the
+    packed set is the one `lax.top_k` and `pack_selection` give, byte for
+    byte (many ties: the position passes run)."""
+    rs = np.random.RandomState(13)
+    B, H, T, d, top_k, chunk = 2, 3, 77, 8, 9, 16
+    q = jnp.round(2 * _arr(rs, B, H, T, d))
+    k = jnp.round(2 * _arr(rs, B, T, d))
+    w = jnp.round(2 * _arr(rs, B, T, H))
+    packed, report = S.indexer_select(q, k, w, top_k=top_k, chunk=chunk)
+    Tp = S.padded_keys(T, chunk)
+    want = S.pack_selection(jnp.pad(dense_select(q, k, w, top_k),
+                                    ((0, 0), (0, 0), (0, Tp - T))))
+    assert packed.dtype == want.dtype == jnp.uint8
+    assert (np.asarray(packed) == np.asarray(want)).all()
+    assert report[2] > 0
 
 
 def test_empty_tiles_are_counted():
@@ -309,19 +362,19 @@ def test_the_backward_pass_masks_by_the_forward_passs_set():
     named = [line for line in buf.getvalue().splitlines()
              if "mx.select" in line]
     assert len(named) == 2 and all("u8[2,32,4]" in n for n in named), named
-    # the bisection and the packing shift bits to the left and nothing else
-    # of the model does: as many of them in the gradient's program as in the
+    # the scores and the selection are the `mx_index_select` kernels, one a
+    # group of chunks: as many calls in the gradient's program as in the
     # forward pass alone
     text = str(jax.make_jaxpr(jax.grad(loss))(leaves))
     forward = str(jax.make_jaxpr(loss)(leaves))
-    loops = lambda t: t.count("shift_left")
-    assert loops(forward) > 0 and loops(text) == loops(forward)
+    calls = lambda t: t.count("mx_index_select")
+    assert calls(forward) == 2 * 4 and calls(text) == calls(forward)
     # without the name kept, the backward pass scores and selects again
     for layer in net.layers._children.values() \
             if hasattr(net.layers, "_children") else []:
         layer.recompute(keep=())
     again = str(jax.make_jaxpr(jax.grad(_loss_of(net)[0]))(leaves))
-    assert loops(again) == 2 * loops(forward)
+    assert calls(again) > calls(forward)
 
 
 def test_the_indexer_is_frozen_and_gets_no_gradient():
