@@ -55,7 +55,8 @@ def flash_min_seq() -> int:
     return env.get("MXNET_FLASH_ATTENTION_MIN_SEQ")
 
 
-def count_route(route: str, schedule: Optional[str] = None):
+def count_route(route: str, schedule: Optional[str] = None,
+                wide: Optional[str] = None):
     """One count a traced (or eagerly run) attention layer in
     `mx_attention_route_total`: `plain` where `use_flash` said no, `flash`
     where the layer went to `flash_attention` (`flash_window` where it took
@@ -63,7 +64,10 @@ def count_route(route: str, schedule: Optional[str] = None):
     a shard_map over the trainer's batch axis. Where the layer took the
     Pallas kernels, `mx_attention_schedule_total` counts beside it the
     schedule their plan chose from the call's shapes (`resident`, `band`,
-    `tiled`: `ops/pallas/flash_attention.py: _plan`). Counted
+    `tiled`: `ops/pallas/flash_attention.py: _plan`), and
+    `mx_attention_wide_head_total` the `tiled` layers whose heads are wider
+    than one lane tile and whose backward therefore walks smaller blocks
+    than the forward, by `wide` (`"512->256"`: `_wide_blocks`). Counted
     while tracing; nothing of it is in the step."""
     if _telem._ENABLED:
         _telem.counter(
@@ -76,6 +80,13 @@ def count_route(route: str, schedule: Optional[str] = None):
                 "Attention layers traced onto the flash kernels, by the "
                 "schedule the kernels' plan chose",
                 ("schedule",)).labels(schedule).inc()
+        if wide is not None:
+            _telem.counter(
+                "mx_attention_wide_head_total",
+                "Attention layers traced onto the flash kernels whose "
+                "backward walks smaller blocks for heads wider than a lane "
+                "tile, by the blocks",
+                ("blocks",)).labels(wide).inc()
 
 
 def use_flash(seq_len: int) -> bool:

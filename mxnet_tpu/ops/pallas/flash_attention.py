@@ -36,6 +36,18 @@ then overlaps one block's products with the next block's elementwise work
 Heads a grid step (`_heads_per_step`) are chosen for resident heads so that a
 step holds `_STEP_FLOPS` of products; a chunked head takes one.
 
+Width. The backward of a `tiled` head wider than one lane tile (latent
+attention's 192) walks blocks of at most `_WIDE_BLOCK` rows (`_wide_blocks`);
+its forward walks the blocks any head's does. On the v5e at 32 x 8192 x 192/128 the
+backward took 24.8 ms a call in blocks of 512 and 12.3 in blocks of 256, at
+256 as at 192, while a head of 128 takes 7.8 ms in blocks of 512: a block
+pair of 512 x 512 over a wide head, its float32 dk carry beside the pair's
+float32 scores and their gradients, runs at half the rate (PERF.md,
+Findings). Its forward stays at 512 (7.1 ms a call at 256, 5.3 at 512). The
+backward's chunks may then pad T to another length than the forward's, and
+`_bwd` reads `lse` at its own. A head of up to one tile takes the blocks it
+always took (`mx_attention_wide_head_total` counts the layers that do not).
+
 Forward: scores as (q rows, k lanes); the running max, sum and output are
 loop carries; only blocks that cross the diagonal or the end of the keys are
 masked. `lse` leaves as (heads, 1, T): T along lanes.
@@ -125,6 +137,7 @@ _VMEM_BLOCK_BYTES = 16 << 20
 _VMEM_LIMIT_MAX = 100 << 20     # of v5e's 128 MiB
 _STEP_FLOPS = 1e9               # products a grid step should hold at least
 _UNROLL_PAIRS = 16              # block pairs of a head that still unroll
+_WIDE_BLOCK = 256               # the most rows a wide head's backward block
 
 
 def pallas_available() -> bool:
@@ -174,6 +187,15 @@ def _block(L, limit):
     1000, 1024 or 2400; 384 for 1100. (A band's block: `_band_block`.)"""
     blocks = [_LANES * b for b in range(1, max(limit // _LANES, 1) + 1)]
     return min(blocks, key=lambda b: (_ceil_to(L, b) * (1 + 200 / b), -b))
+
+
+def _wide_blocks(D, block_q, block_k):
+    """The blocks a chunked head's backward walks: at most `_WIDE_BLOCK`
+    rows where q and k are wider than one lane tile (the module's
+    docstring, "Width"), else the forward's."""
+    if D <= _LANES:
+        return block_q, block_k
+    return min(block_q, _WIDE_BLOCK), min(block_k, _WIDE_BLOCK)
 
 
 def _row_bytes(D, dtype, Dv=None):
@@ -253,7 +275,8 @@ def _plan(backward, BH, T, Tk, D, dtype, causal, block_q, block_k,
     pipeline buffers. `Dv`: the width of v (and of o, dO, dv) where it is
     not q's and k's `D` (latent attention: 192 and 128); the bytes count
     each operand at its own width, and `Dv` None or `D` is the plan of one
-    width."""
+    width. A `tiled` backward of a head wider than one lane tile walks the
+    smaller blocks of `_wide_blocks`."""
     plan = _plan_unselected(backward, BH, T, Tk, D, dtype, causal, block_q,
                             block_k, window, Dv)
     if not select:
@@ -298,6 +321,8 @@ def _plan_unselected(backward, BH, T, Tk, D, dtype, causal, block_q, block_k,
         nbytes = 2 * own * Dp * isz + 4 * 8 * (c + h * b) * 4 \
             + backward * c * n * Dp * (2 * isz + 4)
         return Plan("band", b, b, c, n, c, n, 1, nbytes, h)
+    if backward:
+        block_q, block_k = _wide_blocks(D, block_q, block_k)
     side, b = max(1, math.isqrt(_UNROLL_PAIRS)), block_k
     if causal:
         # equal chunks on both sides; Tk may differ from T
@@ -1124,6 +1149,11 @@ def _bwd(q, k, v, o, lse, do, causal, scale, block_q, block_k, interpret,
                  window, select is not None, Dv)
     cq, n_qc, ck, n_kc, G = plan.cq, plan.n_qc, plan.ck, plan.n_kc, plan.G
     Tp, Tkp = cq * n_qc, ck * n_kc
+    if lse.shape[2] != Tp:
+        # a wide head's backward chunks (`_wide_blocks`) pad T otherwise
+        # than its forward's; a padded row adds nothing whatever its lse
+        # (its q, dO and delta are zero)
+        lse = jnp.pad(lse[:, :, :T], ((0, 0), (0, 0), (0, Tp - T)))
     assert lse.shape == (BH, 1, Tp), (lse.shape, Tp)
     own_delta = o is None
     rows = [lse]
@@ -1275,6 +1305,8 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     """Flash attention on (B, H, T, D) tensors; differentiable. v (and the
     output) may be of another width than q and k, (B, H, Tk, Dv): latent
     attention's heads of 192 and 128 (the scale then defaults to q's).
+    The backward of a long head wider than one lane tile walks blocks of
+    at most 256 rows (the module's docstring, "Width").
 
     `select` (with `causal`; PR 34): int8 (B, T, T), not zero where query t
     keeps key t'; a key is then live where causal and kept, the same for
@@ -1329,12 +1361,16 @@ def flash_attention(q, k, v, causal: bool = False, scale=None,
     if select is not None and partitioned:
         raise MXNetError("flash attention: a selection is not partitioned "
                          "over a mesh axis yet")
+    schedule = _plan(False, B * H, T, Tk, D, q.dtype, bool(causal), bq, bk,
+                     window, select is not None, Dv).schedule \
+        if kernels else None
+    wide = _wide_blocks(D, bq, bk)
     _attn_ops.count_route(
         "flash_partitioned" if partitioned else
         "flash_select" if select is not None else
-        "flash" if window is None else "flash_window",
-        _plan(False, B * H, T, Tk, D, q.dtype, bool(causal), bq, bk,
-              window, select is not None, Dv).schedule if kernels else None)
+        "flash" if window is None else "flash_window", schedule,
+        f"{max(bq, bk)}->{max(wide)}"
+        if schedule == "tiled" and wide != (bq, bk) else None)
     if not kernels:
         # The fallback is differentiated by jax AS WRITTEN (no custom_vjp):
         # its gradient contract — matches the dense-softmax VJP at every

@@ -3,17 +3,21 @@
 
     chiprun -- python tools/flash_probe.py 72,8192,128,causal,512 48,8192,128,causal
 
-A case is `heads,T,d[,causal][,window][,bLIMIT]` (`b256`: the block limit
-handed to `flash_attention`; 512 unsaid). For each case the flash kernels'
-forward and the gradient of their sum run `--reps` times on bfloat16 operands
-under `jax.profiler.trace`; the Mosaic calls `mx_flash_fwd` and `mx_flash_bwd`
-are then read from the device's line of the xplane (`benchmark/chip/trace.py`)
+A case is `heads,T,d[,causal][,window][,bLIMIT][,vWIDTH]` (`b256`: the block
+limit handed to `flash_attention`, 512 unsaid; `v128`: v, the output and its
+gradient as wide as that, q's and k's `d` unsaid: `32,8192,192,causal,v128` is
+a latent layer's call). For each case the flash kernels' forward and the
+gradient of their sum run `--reps` times on bfloat16 operands under
+`jax.profiler.trace`; the Mosaic calls `mx_flash_fwd` and `mx_flash_bwd` are
+then read from the device's line of the xplane (`benchmark/chip/trace.py`)
 and one JSON line a case is printed: milliseconds a call (the median over the
-repetitions), the calls seen, and the schedule the plan names where the tree
-has one. `--tree DIR` probes another checkout's kernels (the parent's, unpacked
-in an ignored directory) with the same driver, so one chip call compares two
-trees. Nothing here is run by a cell or imported by the package; off the TPU
-it refuses, a time being the device's or nothing.
+repetitions), the calls seen, and the schedule and the blocks the plan
+names, forward and backward, where the tree has them (a wide head's
+backward walks smaller blocks than its forward). `--tree DIR` probes another
+checkout's kernels (the parent's, unpacked in an ignored directory) with the
+same driver, so one chip call compares two trees. Nothing here is run by a
+cell or imported by the package; off the TPU it refuses, a time being the
+device's or nothing.
 """
 import argparse
 import json
@@ -28,12 +32,14 @@ HERE = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def parse_case(text):
     heads, T, d, *rest = text.split(",")
     case = {"heads": int(heads), "T": int(T), "d": int(d), "causal": False,
-            "window": None, "block": 512}
+            "window": None, "block": 512, "dv": int(d)}
     for word in rest:
         if word == "causal":
             case["causal"] = True
         elif word.startswith("b"):
             case["block"] = int(word[1:])
+        elif word.startswith("v"):
+            case["dv"] = int(word[1:])
         else:
             case["window"] = int(word)
     return case
@@ -70,14 +76,14 @@ def main():
         sys.exit(f"flash_probe: needs the TPU, found {dev.platform}")
 
     for case in args.cases:
-        H, T, d = case["heads"], case["T"], case["d"]
+        H, T, d, dv = case["heads"], case["T"], case["d"], case["dv"]
         kw = {"causal": case["causal"], "block_q": case["block"],
               "block_k": case["block"]}
         if case["window"] is not None:
             kw["window"] = case["window"]
         rs = np.random.RandomState(H + T)
-        q, k, v = (jnp.asarray(rs.normal(0, 1, (1, H, T, d)), jnp.bfloat16)
-                   for _ in range(3))
+        q, k, v = (jnp.asarray(rs.normal(0, 1, (1, H, T, w)), jnp.bfloat16)
+                   for w in (d, d, dv))
         fwd = jax.jit(lambda q, k, v: fa.flash_attention(q, k, v, **kw))
         grad = jax.jit(jax.grad(lambda q, k, v: jnp.sum(fa.flash_attention(
             q, k, v, **kw).astype(jnp.float32)), argnums=(0, 1, 2)))
@@ -104,10 +110,10 @@ def main():
         plan = getattr(fa, "_plan", None)
         try:
             blk = fa._block(T, case["block"])
-            line["schedule"] = [plan(back, H, T, T, d, jnp.bfloat16,
-                                     case["causal"], blk, blk,
-                                     case["window"]).schedule
-                                for back in (False, True)]
+            plans = [plan(back, H, T, T, d, jnp.bfloat16, case["causal"], blk,
+                          blk, case["window"], Dv=dv) for back in (False, True)]
+            line["schedule"] = [p.schedule for p in plans]
+            line["blocks"] = [p.bk for p in plans]
         except (TypeError, AttributeError):     # a tree from before the names
             pass
         print(json.dumps(line), flush=True)
